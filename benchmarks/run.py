@@ -236,8 +236,8 @@ def sweep_scaling() -> None:
 
     Times ``evaluate(method="batched")`` over 10 apps x V generated variants
     for V in {3, 100, 1k, 10k} (cells/second) on all THREE kernel backends
-    (NumPy eager vs JAX jitted vs the fused Pallas kernel -- interpreter
-    mode when no TPU is attached), then the batched-vs-scalar speedup at
+    (NumPy eager vs JAX jitted vs the fused Pallas kernel -- interpret
+    mode where jax runs on the CPU), then the batched-vs-scalar speedup at
     V=1000 -- PR 1's >=50x acceptance gate.
     """
     from repro.core.sweep import shard_sweep
@@ -904,4 +904,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -33,6 +33,7 @@ from benchmarks import common  # noqa: E402
 from repro.core.kernels_xp import validate_backend_arg as validate_backend  # noqa: E402
 from repro.core.machine import TPU_V5E, VARIANTS  # noqa: E402
 from repro.core.sweep import ParamSpace, run_sweep, shard_sweep  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -68,7 +69,7 @@ def main(argv=None) -> int:
                     help="kernel backend (default: $REPRO_SWEEP_BACKEND, "
                          "then numpy); 'jax' jits + device-places the "
                          "batched kernels, 'pallas' runs the fused TPU "
-                         "kernel (interpreter mode off-TPU); any "
+                         "kernel (interpret mode where jax runs on the CPU); any "
                          "register_backend() name is accepted")
     ap.add_argument("--shards", type=int, default=0, metavar="S",
                     help="score the population in S shards (shard_sweep): "
@@ -203,4 +204,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

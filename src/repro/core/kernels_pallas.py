@@ -25,10 +25,11 @@ equivalence tests pin ``pallas == numpy`` to ~1e-3 (f32 epsilon amplified
 by the Eq. 1 cancellation ``(alpha - beta) / (gamma - beta)``) instead of
 the ~1e-12 the x64 jax backend achieves.
 
-Interpreter fallback: on any non-TPU platform (CPU CI included) the kernel
-runs under ``pallas_call(interpret=True)`` -- slower, but the same tiling
-and the same f32 math, so CI pins the exact code path that ships to TPU.
-Override with ``REPRO_PALLAS_INTERPRET=1`` / ``=0``.
+Interpret mode: where jax's default backend is the CPU (the tests run
+with ``JAX_PLATFORMS=cpu``) the kernel runs under
+``pallas_call(interpret=True)`` -- slower, but the same tiling and the same
+f32 math.  On any other platform it is compiled; only an explicit
+``PallasBackend(interpret=True)`` asks for the interpreter there.
 
 Importing this module registers the backend; ``kernels_xp.get_backend``
 also lazily imports it on first ``backend="pallas"`` request, so callers
@@ -38,7 +39,6 @@ never need to import it explicitly.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Callable, Dict
 
 import numpy as np
@@ -55,13 +55,15 @@ from repro.core.kernels_xp import (
 )
 from repro.core.machine import IDEAL_EPS
 
-INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
-
-#: Variant-axis tile: one fused program scores (A, TILE_V) cells entirely
-#: in VMEM.  512 = 4 f32 sublane groups x 128 lanes; at 10 apps the full
-#: working set (7+8 input rows, 8 output rows x A) stays well under the
-#: ~16 MB VMEM budget.
+#: Largest variant-axis tile: one fused program scores (A, tile) cells
+#: entirely in VMEM.  512 = 4 f32 sublane groups x 128 lanes.
 TILE_V = 512
+
+#: Bytes the double-buffered ``(_OUT_ROWS, A, tile)`` f32 output block may
+#: take.  Half of v5e's 16 MiB scoped VMEM, leaving the rest to the input
+#: blocks and the kernel's temporaries; ``_variant_tile`` shrinks the tile
+#: as A grows so the block stays inside it (A <= 256 keeps 512).
+_OUT_BLOCK_BYTES = 8 << 20
 
 _P_ROWS = 7   # the 6 ProfileArrays fields + the (A,) beta target, stacked
 _M_ROWS = 8   # the 8 MachineArrays fields, stacked
@@ -72,6 +74,17 @@ _LANES = 128  # f32 lane width; the variant axis is padded to a multiple
 
 def _round_up(n: int, k: int) -> int:
     return ((n + k - 1) // k) * k
+
+
+def _variant_tile(a: int, v: int, tile_max: int = TILE_V) -> int:
+    """Variant tile for ``a`` apps and ``v`` variants: a lane multiple, at
+    most ``tile_max``, no wider than the padded population, and small
+    enough that the double-buffered output block fits ``_OUT_BLOCK_BYTES``.
+    The floor is one lane group, which overflows the 16 MiB of scoped VMEM
+    past about 2,000 apps: there the app axis itself needs tiling."""
+    per_lane = 2 * _OUT_ROWS * _round_up(max(a, 1), 8) * 4
+    fit = max(_LANES, (_OUT_BLOCK_BYTES // per_lane) // _LANES * _LANES)
+    return min(tile_max, fit, _round_up(max(v, 1), _LANES))
 
 
 def _profile_rows(p_ref) -> ProfileArrays:
@@ -119,11 +132,12 @@ def _default_beta_body(jnp, p_ref, m_ref, out_ref):
 class PallasBackend(Backend):
     """Fused f32 Pallas evaluation, tiled over the variant axis.
 
-    ``interpret=None`` (the default) auto-selects: compiled on TPU,
-    interpreter mode everywhere else, overridable via
-    ``$REPRO_PALLAS_INTERPRET``.  ``tile_v`` is the variant tile per fused
-    program (clamped down for small populations; the variant axis is padded
-    with benign 1.0 columns to a tile multiple and sliced on the way out).
+    ``interpret=None`` (the default) selects interpreter mode only where
+    jax's default backend is the CPU and compiles everywhere else.
+    ``tile_v`` caps the variant tile per fused program (``_variant_tile``
+    derives the actual tile from the app count and population; the
+    variant axis is padded with benign 1.0 columns to a tile multiple and
+    sliced on the way out).
     """
 
     name = "pallas"
@@ -136,11 +150,7 @@ class PallasBackend(Backend):
 
         self._jax, self._jnp, self._pl = jax, jnp, pl
         if interpret is None:
-            env = os.environ.get(INTERPRET_ENV, "")
-            if env:
-                interpret = env.lower() not in ("0", "false", "no")
-            else:
-                interpret = jax.default_backend() != "tpu"
+            interpret = jax.default_backend() == "cpu"
         self.interpret = bool(interpret)
         self.tile_v = int(tile_v)
         self._jit_cache: Dict[str, Callable] = {}
@@ -160,7 +170,7 @@ class PallasBackend(Backend):
         rows = list(p) + ([] if beta is None else [beta])
         return np.stack([np.asarray(r, dtype=np.float32) for r in rows])
 
-    def _machine_stack(self, m: MachineArrays):
+    def _machine_stack(self, m: MachineArrays, a: int):
         """``(_M_ROWS, V_pad)`` f32 stack, padded to a tile multiple.
 
         Pad columns are all-1.0 machines: every rate and scale is positive,
@@ -169,7 +179,7 @@ class PallasBackend(Backend):
         """
         stack = np.stack([np.asarray(f, dtype=np.float32) for f in m])
         v = stack.shape[1]
-        tile = min(self.tile_v, _round_up(max(v, 1), _LANES))
+        tile = _variant_tile(a, v, self.tile_v)
         v_pad = _round_up(max(v, 1), tile)
         if v_pad != v:
             pad = np.ones((_M_ROWS, v_pad - v), dtype=np.float32)
@@ -215,16 +225,41 @@ class PallasBackend(Backend):
             interpret=self.interpret,
         )(p_stack, m_stack)
 
-    def step_time(self, p, m, timing_model="serial"):
-        m_stack, tile, v = self._machine_stack(m)
-        fn = self._jitted(
+    def _step_time_fn(self) -> Callable:
+        return self._jitted(
             "step_time",
             lambda p_stack, m_stack, timing_model, tile: self._tiled_call(
                 functools.partial(_step_time_body, self._jnp, timing_model),
                 p_stack, m_stack, tile, 0),
             ("timing_model", "tile"))
-        out = fn(self.asarray(self._profile_stack(p)), self.asarray(m_stack),
-                 timing_model=timing_model, tile=tile)
+
+    def _default_beta_fn(self) -> Callable:
+        return self._jitted(
+            "default_beta",
+            lambda p_stack, m_stack: self._pl.pallas_call(
+                functools.partial(_default_beta_body, self._jnp),
+                out_shape=self._jax.ShapeDtypeStruct(
+                    (1, p_stack.shape[1]), self._jnp.float32),
+                interpret=self.interpret,
+            )(p_stack, m_stack),
+            ())
+
+    def _congruence_fn(self) -> Callable:
+        return self._jitted(
+            "congruence",
+            lambda p_stack, m_stack, timing_model, eps, clamp, tile:
+                self._tiled_call(
+                    functools.partial(_congruence_body, self._jnp,
+                                      timing_model, eps, clamp),
+                    p_stack, m_stack, tile, _OUT_ROWS),
+            ("timing_model", "eps", "clamp", "tile"))
+
+    def step_time(self, p, m, timing_model="serial"):
+        p_stack = self._profile_stack(p)
+        m_stack, tile, v = self._machine_stack(m, p_stack.shape[1])
+        out = self._step_time_fn()(
+            self.asarray(p_stack), self.asarray(m_stack),
+            timing_model=timing_model, tile=tile)
         return self.to_numpy(out)[:, :v]
 
     def default_beta(self, p, m_ref):
@@ -233,35 +268,18 @@ class PallasBackend(Backend):
         The reference is a single variant, so there is nothing to tile --
         the whole (rows x 1) problem is one VMEM-resident program.
         """
-        pl = self._pl
         p_stack = self.asarray(self._profile_stack(p))
         m_stack = self.asarray(
             np.stack([np.asarray(f, dtype=np.float32) for f in m_ref]))
-        fn = self._jitted(
-            "default_beta",
-            lambda p_stack, m_stack: pl.pallas_call(
-                functools.partial(_default_beta_body, self._jnp),
-                out_shape=self._jax.ShapeDtypeStruct(
-                    (1, p_stack.shape[1]), self._jnp.float32),
-                interpret=self.interpret,
-            )(p_stack, m_stack),
-            ())
-        return self.to_numpy(fn(p_stack, m_stack))[0]
+        return self.to_numpy(self._default_beta_fn()(p_stack, m_stack))[0]
 
     def congruence(self, p, m, beta, timing_model="serial",
                    eps=IDEAL_EPS, clamp=False) -> CongruenceArrays:
-        m_stack, tile, v = self._machine_stack(m)
-        fn = self._jitted(
-            "congruence",
-            lambda p_stack, m_stack, timing_model, eps, clamp, tile:
-                self._tiled_call(
-                    functools.partial(_congruence_body, self._jnp,
-                                      timing_model, eps, clamp),
-                    p_stack, m_stack, tile, _OUT_ROWS),
-            ("timing_model", "eps", "clamp", "tile"))
-        out = fn(self.asarray(self._profile_stack(p, beta)),
-                 self.asarray(m_stack),
-                 timing_model=timing_model, eps=eps, clamp=clamp, tile=tile)
+        p_stack = self._profile_stack(p, beta)
+        m_stack, tile, v = self._machine_stack(m, p_stack.shape[1])
+        out = self._congruence_fn()(
+            self.asarray(p_stack), self.asarray(m_stack),
+            timing_model=timing_model, eps=eps, clamp=clamp, tile=tile)
         out = self.to_numpy(out)[:, :, :v]
         return CongruenceArrays(
             gamma=out[0],
@@ -291,17 +309,12 @@ class PallasBackend(Backend):
         device; the ``(A, V_local)`` score tile is never gathered.
 
         The host-side merge over the per-device ``(ndev, A)`` stacks picks
-        the first device attaining the min, and each device's argmin is the
-        first in its slice -- device order equals index order, so the
-        combined argmin is first-occurrence, matching the numpy reference.
+        the first row attaining the min, and each device's argmin is the
+        first in its slice.  Rows come back in mesh-axis position, which is
+        also slice order (``axis_index`` places each slice), whatever the
+        device ids -- so the combined argmin is first-occurrence, matching
+        the numpy reference.
         """
-        jax, jnp = self._jax, self._jnp
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # jax<0.5 keeps it under experimental
-            from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec
-
-        axis = mesh.axis_names[0]
         ndev = int(mesh.size)
         v = int(np.asarray(m.peak_flops).shape[0])
         if v == 0:
@@ -309,9 +322,9 @@ class PallasBackend(Backend):
 
         # Per-device slice width: cover max(v, pad_to) variants, rounded so
         # every device holds the same tile-aligned slice.
-        target = max(v, int(pad_to or 0))
-        local = -(-target // ndev)
-        tile = min(self.tile_v, _round_up(max(local, 1), _LANES))
+        p_stack = self._profile_stack(p, beta)
+        local = -(-max(v, int(pad_to or 0)) // ndev)
+        tile = _variant_tile(p_stack.shape[1], local, self.tile_v)
         local_pad = _round_up(max(local, 1), tile)
         v_pad = local_pad * ndev
 
@@ -319,50 +332,10 @@ class PallasBackend(Backend):
         if v_pad != v:
             pad = np.ones((_M_ROWS, v_pad - v), dtype=np.float32)
             m_stack = np.concatenate([m_stack, pad], axis=1)
-        p_stack = self._profile_stack(p, beta)
-        a = p_stack.shape[1]
 
-        mesh_key = (axis, tuple(int(d.id) for d in mesh.devices.flat))
-        key = (f"sharded/{a}/{v}/{local_pad}/{tile}/{timing_model}/"
-               f"{clamp}/{mesh_key}")
-        if key not in self._jit_cache:
-            body = functools.partial(_congruence_body, self._jnp,
-                                     timing_model, IDEAL_EPS, clamp)
-
-            def local_stats(p_s, m_local):
-                out = self._pl.pallas_call(
-                    body,
-                    out_shape=jax.ShapeDtypeStruct(
-                        (_OUT_ROWS, a, local_pad), jnp.float32),
-                    grid=(local_pad // tile,),
-                    in_specs=[
-                        self._pl.BlockSpec((_P_ROWS, a), lambda i: (0, 0)),
-                        self._pl.BlockSpec((_M_ROWS, tile), lambda i: (0, i)),
-                    ],
-                    out_specs=self._pl.BlockSpec(
-                        (_OUT_ROWS, a, tile), lambda i: (0, 0, i)),
-                    interpret=self.interpret,
-                )(p_s, m_local)
-                agg = out[_OUT_ROWS - 1]
-                lo = jax.lax.axis_index(axis) * local_pad
-                valid = (lo + jnp.arange(local_pad)) < v
-                masked = jnp.where(valid[None, :], agg, jnp.inf)
-                return (agg.mean(axis=0),
-                        masked.min(axis=1)[None, :],
-                        (masked.argmin(axis=1) + lo)[None, :])
-
-            fn = shard_map(
-                local_stats,
-                mesh=mesh,
-                in_specs=(PartitionSpec(), PartitionSpec(None, axis)),
-                out_specs=(PartitionSpec(axis), PartitionSpec(axis),
-                           PartitionSpec(axis)),
-                check_rep=False,
-            )
-            self._jit_cache[key] = self._jax.jit(fn)
-
-        agg, mins, idxs = self._jit_cache[key](
-            self.asarray(p_stack), self.asarray(m_stack))
+        fn = self._sharded_stats_fn(mesh, v, local_pad, tile, timing_model,
+                                    clamp)
+        agg, mins, idxs = fn(self.asarray(p_stack), self.asarray(m_stack))
         agg = np.asarray(agg)[:v].astype(np.float64)
         mins = np.asarray(mins)          # (ndev, A)
         idxs = np.asarray(idxs)          # (ndev, A) global-within-chunk
@@ -371,6 +344,42 @@ class PallasBackend(Backend):
         return (agg,
                 mins[dev, cols].astype(np.float64),
                 idxs[dev, cols].astype(np.int64))
+
+    def _sharded_stats_fn(self, mesh, v: int, local_pad: int, tile: int,
+                          timing_model: str, clamp: bool) -> Callable:
+        """The jitted ``shard_map`` program behind ``sharded_stats``: maps
+        ``(p_stack, m_stack)`` with ``m_stack`` of width ``local_pad`` per
+        device to the per-device means, minima and argmins."""
+        jax, jnp = self._jax, self._jnp
+        from jax.sharding import PartitionSpec
+
+        axis = mesh.axis_names[0]
+        mesh_key = (axis, tuple(int(d.id) for d in mesh.devices.flat))
+        key = f"sharded/{v}/{local_pad}/{tile}/{timing_model}/{clamp}/{mesh_key}"
+        if key not in self._jit_cache:
+            body = functools.partial(_congruence_body, jnp, timing_model,
+                                     IDEAL_EPS, clamp)
+
+            def local_stats(p_s, m_local):
+                agg = self._tiled_call(body, p_s, m_local, tile,
+                                       _OUT_ROWS)[_OUT_ROWS - 1]
+                lo = jax.lax.axis_index(axis) * local_pad
+                valid = (lo + jnp.arange(local_pad)) < v
+                masked = jnp.where(valid[None, :], agg, jnp.inf)
+                return (agg.mean(axis=0),
+                        masked.min(axis=1)[None, :],
+                        (masked.argmin(axis=1) + lo)[None, :])
+
+            self._jit_cache[key] = jax.jit(jax.shard_map(
+                local_stats,
+                mesh=mesh,
+                in_specs=(PartitionSpec(), PartitionSpec(None, axis)),
+                out_specs=(PartitionSpec(axis), PartitionSpec(axis),
+                           PartitionSpec(axis)),
+                # pallas_call's out_shape carries no varying-axes type
+                check_vma=False,
+            ))
+        return self._jit_cache[key]
 
 
 register_backend("pallas", PallasBackend)

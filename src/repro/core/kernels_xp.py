@@ -324,18 +324,17 @@ class JaxBackend(Backend):
     differentiable = True
 
     def __init__(self):
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax.experimental import enable_x64
-        except ImportError as exc:  # pragma: no cover - jax is baked in
-            raise RuntimeError(
-                "backend 'jax' requires jax; install it or use backend='numpy'"
-            ) from exc
+        import jax
+        import jax.numpy as jnp
+
         self._jax = jax
         self._jnp = jnp
-        self._x64 = enable_x64
         self._jit_cache: Dict[str, Callable] = {}
+
+    def _x64(self):
+        """Context manager every x64 call site (codesign, frontier, ...)
+        enters before tracing or placing arrays."""
+        return self._jax.enable_x64(True)
 
     def asarray(self, a):
         with self._x64():
@@ -368,17 +367,20 @@ class JaxBackend(Backend):
             return self.to_numpy(
                 fn(self.profile_arrays(p), self.machine_arrays(m_ref)))
 
+    def _congruence_fn(self) -> Callable:
+        return self._jitted(
+            "congruence",
+            lambda p, m, beta, timing_model, eps, clamp: congruence_kernel(
+                self._jnp, p, m, beta, timing_model, eps, clamp),
+            ("timing_model", "eps", "clamp"))
+
     def congruence(self, p, m, beta, timing_model="serial",
                    eps=IDEAL_EPS, clamp=False):
         with self._x64():
-            fn = self._jitted(
-                "congruence",
-                lambda p, m, beta, timing_model, eps, clamp: congruence_kernel(
-                    self._jnp, p, m, beta, timing_model, eps, clamp),
-                ("timing_model", "eps", "clamp"))
-            out = fn(self.profile_arrays(p), self.machine_arrays(m),
-                     self.asarray(beta), timing_model=timing_model,
-                     eps=eps, clamp=clamp)
+            out = self._congruence_fn()(
+                self.profile_arrays(p), self.machine_arrays(m),
+                self.asarray(beta), timing_model=timing_model,
+                eps=eps, clamp=clamp)
             return CongruenceArrays(*(self.to_numpy(f) for f in out))
 
     def sharded_stats(self, p, m, beta, mesh, timing_model="serial",
@@ -418,24 +420,30 @@ class JaxBackend(Backend):
                 *(jax.device_put(self.asarray(f), rep) for f in p))
             beta_dev = jax.device_put(self.asarray(beta), rep)
 
-            key = f"sharded_stats/{v}/{v_pad}"
-            if key not in self._jit_cache:
-                def stats(p, m, beta, timing_model, clamp):
-                    out = congruence_kernel(jnp, p, m, beta, timing_model,
-                                            clamp=clamp)
-                    masked = jnp.where(jnp.arange(v_pad)[None, :] < v,
-                                       out.aggregate, jnp.inf)
-                    return (out.aggregate.mean(axis=0),
-                            masked.min(axis=1),
-                            masked.argmin(axis=1))
-                self._jit_cache[key] = jax.jit(
-                    stats, static_argnames=("timing_model", "clamp"))
-            agg, app_min, app_idx = self._jit_cache[key](
+            agg, app_min, app_idx = self._sharded_stats_fn(v, v_pad)(
                 p_dev, m_dev, beta_dev, timing_model=timing_model,
                 clamp=clamp)
             return (np.asarray(agg)[:v],
                     np.asarray(app_min),
                     np.asarray(app_idx).astype(np.int64))
+
+    def _sharded_stats_fn(self, v: int, v_pad: int) -> Callable:
+        """The jitted reduction behind ``sharded_stats`` for a chunk of
+        ``v`` variants padded to ``v_pad``; the placement of its arguments
+        decides how it is partitioned."""
+        jnp = self._jnp
+
+        def stats(p, m, beta, timing_model, clamp):
+            out = congruence_kernel(jnp, p, m, beta, timing_model,
+                                    clamp=clamp)
+            masked = jnp.where(jnp.arange(v_pad)[None, :] < v,
+                               out.aggregate, jnp.inf)
+            return (out.aggregate.mean(axis=0),
+                    masked.min(axis=1),
+                    masked.argmin(axis=1))
+
+        return self._jitted(f"sharded_stats/{v}/{v_pad}", stats,
+                            ("timing_model", "clamp"))
 
 
 _BACKEND_FACTORIES: Dict[str, Callable[[], Backend]] = {

@@ -114,7 +114,7 @@ def calibrate_costs(cfg, shape, mesh, mesh_label, sc, *, multi_pod,
     def probe(depth):
         pcfg = _probe_cfg(cfg, depth)
         cell = input_specs(pcfg, shape, mesh, sc)
-        with MESH.use_mesh(mesh), CTX.use_rules(
+        with jax.set_mesh(mesh), CTX.use_rules(
                 SH.activation_rules(mesh, sc, kind=rules_kind)):
             compiled = jax.jit(
                 cell.step_fn, in_shardings=cell.in_shardings,
@@ -153,7 +153,7 @@ def run_cell(cfg, shape, mesh, mesh_label, variant, out_dir, *,
     t0 = time.time()
     rules_kind = shape.kind if sp else "decode"  # "decode" = no seq sharding
     cell = input_specs(cfg, shape, mesh, sc)
-    with MESH.use_mesh(mesh), CTX.use_rules(
+    with jax.set_mesh(mesh), CTX.use_rules(
             SH.activation_rules(mesh, sc, kind=rules_kind)):
         jitted = jax.jit(
             cell.step_fn,

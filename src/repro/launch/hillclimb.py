@@ -93,7 +93,7 @@ def _probe_hbm(cfg, shape, mesh, sc, seq_len: int, batch: int,
         pcfg = pcfg.replace(
             ssm=dataclasses.replace(pcfg.ssm, state_dim=state_dim))
     cell = input_specs(pcfg, pshape, mesh, sc)
-    with MESH.use_mesh(mesh), CTX.use_rules(
+    with jax.set_mesh(mesh), CTX.use_rules(
             SH.activation_rules(mesh, sc, kind=shape.kind)):
         compiled = jax.jit(
             cell.step_fn, in_shardings=cell.in_shardings,

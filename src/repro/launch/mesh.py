@@ -15,24 +15,16 @@ import jax
 DEVICES_PER_POD = 256  # 16 x 16
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    # jax.sharding.AxisType only exists on newer jax; older releases default
-    # every axis to Auto, which is exactly what we want anyway.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh with the same Auto axis types (tests use small ones)."""
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    """Arbitrary mesh with every axis ``Auto`` (tests use small ones)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_variant_mesh(num_devices: Optional[int] = None):
@@ -46,18 +38,6 @@ def make_variant_mesh(num_devices: Optional[int] = None):
     """
     ndev = int(num_devices or max(1, len(jax.devices())))
     return make_mesh((ndev,), ("variants",))
-
-
-def use_mesh(mesh):
-    """Context manager making ``mesh`` ambient, across jax versions.
-
-    Newer jax spells this ``jax.set_mesh``; on older releases the ``Mesh``
-    object itself is the context manager (legacy resource env).
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
 
 
 def mesh_name(mesh) -> str:

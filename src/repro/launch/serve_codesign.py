@@ -19,6 +19,7 @@ import time
 
 from repro.core import CodesignSpec, WorkloadProfile
 from repro.core.kernels_xp import validate_backend_arg
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.codesign_service import CodesignRequest, CodesignService
 
 
@@ -104,22 +105,31 @@ def main(argv=None) -> int:
     svc.drain()
     dt = time.perf_counter() - t0
 
-    for label, jid in ([(f"sweep[{i}]", j)
-                        for i, j in enumerate(sweep_jids)][:1]
-                       + [("mega_sweep", mega_jid),
-                          ("frontier", frontier_jid),
-                          ("frontier+warm", warm_jid)]):
+    jobs = ([(f"sweep[{i}]", j) for i, j in enumerate(sweep_jids)]
+            + [("mega_sweep", mega_jid), ("frontier", frontier_jid),
+               ("frontier+warm", warm_jid)])
+    failed = {}
+    for label, jid in jobs:
+        try:
+            svc.result(jid, timeout=5)
+        except Exception as exc:      # noqa: BLE001 -- reported, then rc 1
+            failed[label] = f"{type(exc).__name__}: {exc}"
+    for label, jid in jobs[:1] + jobs[len(sweep_jids):]:
+        if label in failed:
+            continue
         out = svc.render(jid, fmt=args.format, top_k=args.top_k, timeout=5)
         print(f"\n== {label} ({svc.poll(jid)['cache'] or 'cold'}) ==")
         print(out if args.format == "markdown"
               else json.dumps(out, indent=1, default=str)[:2000])
 
-    total = len(sweep_jids) + 3
-    print(f"\nserved {total} requests in {dt:.2f}s "
-          f"({total / dt:.1f} req/s); stats: {dict(svc.stats)}")
+    print(f"\nserved {len(jobs)} requests in {dt:.2f}s "
+          f"({len(jobs) / dt:.1f} req/s); stats: {dict(svc.stats)}")
+    for label, why in failed.items():
+        print(f"{label} did not finish: {why}", file=sys.stderr)
     svc.shutdown()
-    return 0
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -98,7 +98,7 @@ def main(argv=None) -> int:
 
     use_shardings = shardings if mesh.size > 1 else None
     trainer = Trainer(cfg, tc, dc, oc, shardings=use_shardings)
-    with MESH.use_mesh(mesh), CTX.use_rules(
+    with jax.set_mesh(mesh), CTX.use_rules(
             SH.activation_rules(mesh, sc, kind="train")):
         out = trainer.run()
     losses = [m["loss"] for m in out["metrics"]]

@@ -176,10 +176,9 @@ def test_registry_has_pallas():
     assert be.name == "pallas"
     assert not be.differentiable
     assert be is get_backend("pallas")  # cached like the others
-    # no TPU in CI: the interpreter fallback must have been auto-selected
+    # interpret mode exactly where jax runs on the CPU, compiled elsewhere
     import jax
-    if jax.default_backend() != "tpu":
-        assert be.interpret
+    assert be.interpret == (jax.default_backend() == "cpu")
 
 
 @pytest.mark.parametrize("timing_model", ["serial", "overlap"])
@@ -249,15 +248,37 @@ def test_run_sweep_pallas_4096_matches_numpy():
 
 
 def test_pallas_interpret_env_override(monkeypatch):
+    """No environment variable picks the mode: the default follows jax's
+    backend (interpreted on the CPU only), and only the explicit
+    ``interpret=`` argument overrides it."""
+    import jax
     from repro.core.kernels_pallas import PallasBackend
 
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert PallasBackend().interpret
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert not PallasBackend().interpret
+    on_cpu = jax.default_backend() == "cpu"
+    for env in ("1", "0"):
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", env)
+        assert PallasBackend().interpret == on_cpu
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
-    # explicit argument always wins
+    assert PallasBackend().interpret == on_cpu
     assert PallasBackend(interpret=True).interpret
+    assert not PallasBackend(interpret=False).interpret
+
+
+def test_pallas_variant_tile_fits_scoped_vmem():
+    """The variant tile shrinks with the app count so the double-buffered
+    (8, A, tile) f32 output block stays inside half of v5e's 16 MiB scoped
+    VMEM; small suites keep the full 512-lane tile."""
+    from repro.core.kernels_pallas import TILE_V, _variant_tile
+
+    for apps in (1, 6, 128):
+        assert _variant_tile(apps, 1 << 20) == TILE_V
+    assert _variant_tile(1000, 1 << 20) == 128
+    for apps in (1, 6, 128, 200, 513, 1000):
+        tile = _variant_tile(apps, 1 << 20)
+        assert tile % 128 == 0
+        assert 2 * 8 * (-(-apps // 8) * 8) * tile * 4 <= 8 << 20
+    # never wider than the lane-padded population
+    assert _variant_tile(6, 5) == 128 and _variant_tile(6, 300) == 384
 
 
 # --------------------------------------------------------------------------- #

@@ -91,7 +91,7 @@ def test_sharded_train_step_runs():
         data = SyntheticLM(cfg, DataConfig(seq_len=32, global_batch=4))
         batch = {k: jnp.asarray(v) for k, v in data.batch(0).items()}
         step = jax.jit(make_train_step(cfg, oc), donate_argnums=0)
-        with MESH.use_mesh(mesh), CTX.use_rules(
+        with jax.set_mesh(mesh), CTX.use_rules(
                 SH.activation_rules(mesh, sc, kind="train")):
             state, metrics = step(state, batch)
             l1 = float(metrics["loss"])
@@ -124,7 +124,7 @@ def test_sharded_matches_single_device():
         sc = SH.ShardingConfig(variant="tp")
         p_sh = SH.param_specs(params, axes, mesh, sc)
         params_sh = jax.tree.map(jax.device_put, params, p_sh)
-        with MESH.use_mesh(mesh), CTX.use_rules(
+        with jax.set_mesh(mesh), CTX.use_rules(
                 SH.activation_rules(mesh, sc, kind="train")):
             sharded, _ = jax.jit(lambda p, b: T.loss_fn(p, cfg, b))(params_sh, batch)
         assert abs(float(base) - float(sharded)) < 1e-3, (base, sharded)
@@ -178,7 +178,7 @@ def test_mini_dryrun_profile_extraction():
         shape = ShapeSpec("t", 32, 4, "train")
         sc = SH.ShardingConfig(variant="fsdp", multi_pod=True)
         cell = input_specs(cfg, shape, mesh, sc)
-        with MESH.use_mesh(mesh), CTX.use_rules(
+        with jax.set_mesh(mesh), CTX.use_rules(
                 SH.activation_rules(mesh, sc, kind="train")):
             compiled = jax.jit(cell.step_fn, in_shardings=cell.in_shardings,
                                out_shardings=cell.out_shardings,
