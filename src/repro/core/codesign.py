@@ -55,6 +55,7 @@ import numpy as np
 from repro.core import kernels_xp as K
 from repro.core.costmodel import DEFAULT_COST_MODEL, CostModel
 from repro.core.machine import MachineModel
+from repro.core.spans import span, spanned
 
 #: The machine constants the gradient may move, in theta column order.
 #: ``repro.core.constrained`` appends a 5th column, ``log(ici_links)``,
@@ -174,19 +175,22 @@ def backtracking_descent(
     f_cur = obj_j(theta, *obj_args)
     lr_v = jnp.broadcast_to(jnp.asarray(lr, dtype=theta.dtype),
                             (theta.shape[0],))
-    history = [np.asarray(f_cur)]
-    aux = [] if aux_j is None else [np.asarray(aux_j(theta))]
+    with span("descent.sync"):
+        history = [np.asarray(f_cur)]
+        aux = [] if aux_j is None else [np.asarray(aux_j(theta))]
     for _ in range(steps):
-        g = grad_j(theta, *obj_args)
-        cand = retract_j(theta - lr_v[:, None] * g, *retract_args)
-        f_new = obj_j(cand, *obj_args)
-        ok = f_new < f_cur
-        theta = jnp.where(ok[:, None], cand, theta)
-        f_cur = jnp.where(ok, f_new, f_cur)
-        lr_v = jnp.where(ok, lr_v * 1.2, lr_v * 0.5)
-        history.append(np.asarray(f_cur))
-        if aux_j is not None:
-            aux.append(np.asarray(aux_j(theta)))
+        with span("descent.step"):
+            g = grad_j(theta, *obj_args)
+            cand = retract_j(theta - lr_v[:, None] * g, *retract_args)
+            f_new = obj_j(cand, *obj_args)
+            ok = f_new < f_cur
+            theta = jnp.where(ok[:, None], cand, theta)
+            f_cur = jnp.where(ok, f_new, f_cur)
+            lr_v = jnp.where(ok, lr_v * 1.2, lr_v * 0.5)
+            with span("descent.sync"):
+                history.append(np.asarray(f_cur))
+                if aux_j is not None:
+                    aux.append(np.asarray(aux_j(theta)))
     return theta, f_cur, history, aux, lr_v
 
 
@@ -425,6 +429,7 @@ def scalarized_objective(
                                 w_area, w_power)
 
 
+@spanned("codesign")
 def grad_codesign(
     profiles,
     machines,

@@ -54,6 +54,7 @@ from repro.core.kernels_xp import (
     step_time_kernel,
 )
 from repro.core.machine import IDEAL_EPS
+from repro.core.spans import span
 
 #: Largest variant-axis tile: one fused program scores (A, tile) cells
 #: entirely in VMEM.  512 = 4 f32 sublane groups x 128 lanes.
@@ -193,8 +194,9 @@ class PallasBackend(Backend):
             self._jit_cache[key] = self._jax.jit(fn, static_argnames=static)
         return self._jit_cache[key]
 
-    def _tiled_call(self, body, p_stack, m_stack, tile: int, out_rows: int):
-        """One fused ``pallas_call`` over the variant grid.
+    def _tiled_call(self, body, p_stack, m_stack, tile: int, out_rows: int,
+                    name: str):
+        """One fused ``pallas_call`` named ``name`` over the variant grid.
 
         Shapes are static under jit, so the grid / specs are rebuilt only
         on retrace.  ``out_rows == 0`` means a 2-D ``(A, V)`` output (step
@@ -223,6 +225,7 @@ class PallasBackend(Backend):
             in_specs=in_specs,
             out_specs=out_specs,
             interpret=self.interpret,
+            name=name,
         )(p_stack, m_stack)
 
     def _step_time_fn(self) -> Callable:
@@ -230,7 +233,7 @@ class PallasBackend(Backend):
             "step_time",
             lambda p_stack, m_stack, timing_model, tile: self._tiled_call(
                 functools.partial(_step_time_body, self._jnp, timing_model),
-                p_stack, m_stack, tile, 0),
+                p_stack, m_stack, tile, 0, "step_time"),
             ("timing_model", "tile"))
 
     def _default_beta_fn(self) -> Callable:
@@ -241,6 +244,7 @@ class PallasBackend(Backend):
                 out_shape=self._jax.ShapeDtypeStruct(
                     (1, p_stack.shape[1]), self._jnp.float32),
                 interpret=self.interpret,
+                name="default_beta",
             )(p_stack, m_stack),
             ())
 
@@ -251,16 +255,18 @@ class PallasBackend(Backend):
                 self._tiled_call(
                     functools.partial(_congruence_body, self._jnp,
                                       timing_model, eps, clamp),
-                    p_stack, m_stack, tile, _OUT_ROWS),
+                    p_stack, m_stack, tile, _OUT_ROWS, "congruence"),
             ("timing_model", "eps", "clamp", "tile"))
 
     def step_time(self, p, m, timing_model="serial"):
-        p_stack = self._profile_stack(p)
-        m_stack, tile, v = self._machine_stack(m, p_stack.shape[1])
-        out = self._step_time_fn()(
-            self.asarray(p_stack), self.asarray(m_stack),
-            timing_model=timing_model, tile=tile)
-        return self.to_numpy(out)[:, :v]
+        with span("stage"):
+            p_stack = self._profile_stack(p)
+            m_stack, tile, v = self._machine_stack(m, p_stack.shape[1])
+            p_dev, m_dev = self.asarray(p_stack), self.asarray(m_stack)
+        out = self._step_time_fn()(p_dev, m_dev, timing_model=timing_model,
+                                   tile=tile)
+        with span("fetch"):
+            return self.to_numpy(out)[:, :v]
 
     def default_beta(self, p, m_ref):
         """Per-app beta via the same shared kernel, one ungridded call.
@@ -268,19 +274,25 @@ class PallasBackend(Backend):
         The reference is a single variant, so there is nothing to tile --
         the whole (rows x 1) problem is one VMEM-resident program.
         """
-        p_stack = self.asarray(self._profile_stack(p))
-        m_stack = self.asarray(
-            np.stack([np.asarray(f, dtype=np.float32) for f in m_ref]))
-        return self.to_numpy(self._default_beta_fn()(p_stack, m_stack))[0]
+        with span("stage"):
+            p_stack = self.asarray(self._profile_stack(p))
+            m_stack = self.asarray(
+                np.stack([np.asarray(f, dtype=np.float32) for f in m_ref]))
+        out = self._default_beta_fn()(p_stack, m_stack)
+        with span("fetch"):
+            return self.to_numpy(out)[0]
 
     def congruence(self, p, m, beta, timing_model="serial",
                    eps=IDEAL_EPS, clamp=False) -> CongruenceArrays:
-        p_stack = self._profile_stack(p, beta)
-        m_stack, tile, v = self._machine_stack(m, p_stack.shape[1])
+        with span("stage"):
+            p_stack = self._profile_stack(p, beta)
+            m_stack, tile, v = self._machine_stack(m, p_stack.shape[1])
+            p_dev, m_dev = self.asarray(p_stack), self.asarray(m_stack)
         out = self._congruence_fn()(
-            self.asarray(p_stack), self.asarray(m_stack),
-            timing_model=timing_model, eps=eps, clamp=clamp, tile=tile)
-        out = self.to_numpy(out)[:, :, :v]
+            p_dev, m_dev, timing_model=timing_model, eps=eps, clamp=clamp,
+            tile=tile)
+        with span("fetch"):
+            out = self.to_numpy(out)[:, :, :v]
         return CongruenceArrays(
             gamma=out[0],
             beta=np.asarray(beta),
@@ -320,25 +332,28 @@ class PallasBackend(Backend):
         if v == 0:
             return None
 
-        # Per-device slice width: cover max(v, pad_to) variants, rounded so
-        # every device holds the same tile-aligned slice.
-        p_stack = self._profile_stack(p, beta)
-        local = -(-max(v, int(pad_to or 0)) // ndev)
-        tile = _variant_tile(p_stack.shape[1], local, self.tile_v)
-        local_pad = _round_up(max(local, 1), tile)
-        v_pad = local_pad * ndev
+        with span("stage"):
+            # Per-device slice width: cover max(v, pad_to) variants,
+            # rounded so every device holds the same tile-aligned slice.
+            p_stack = self._profile_stack(p, beta)
+            local = -(-max(v, int(pad_to or 0)) // ndev)
+            tile = _variant_tile(p_stack.shape[1], local, self.tile_v)
+            local_pad = _round_up(max(local, 1), tile)
+            v_pad = local_pad * ndev
 
-        m_stack = np.stack([np.asarray(f, dtype=np.float32) for f in m])
-        if v_pad != v:
-            pad = np.ones((_M_ROWS, v_pad - v), dtype=np.float32)
-            m_stack = np.concatenate([m_stack, pad], axis=1)
+            m_stack = np.stack([np.asarray(f, dtype=np.float32) for f in m])
+            if v_pad != v:
+                pad = np.ones((_M_ROWS, v_pad - v), dtype=np.float32)
+                m_stack = np.concatenate([m_stack, pad], axis=1)
+            p_dev, m_dev = self.asarray(p_stack), self.asarray(m_stack)
 
         fn = self._sharded_stats_fn(mesh, v, local_pad, tile, timing_model,
                                     clamp)
-        agg, mins, idxs = fn(self.asarray(p_stack), self.asarray(m_stack))
-        agg = np.asarray(agg)[:v].astype(np.float64)
-        mins = np.asarray(mins)          # (ndev, A)
-        idxs = np.asarray(idxs)          # (ndev, A) global-within-chunk
+        agg, mins, idxs = fn(p_dev, m_dev)
+        with span("fetch"):
+            agg = np.asarray(agg)[:v].astype(np.float64)
+            mins = np.asarray(mins)      # (ndev, A)
+            idxs = np.asarray(idxs)      # (ndev, A) global-within-chunk
         dev = np.argmin(mins, axis=0)    # first device attaining the min
         cols = np.arange(mins.shape[1])
         return (agg,
@@ -362,7 +377,7 @@ class PallasBackend(Backend):
 
             def local_stats(p_s, m_local):
                 agg = self._tiled_call(body, p_s, m_local, tile,
-                                       _OUT_ROWS)[_OUT_ROWS - 1]
+                                       _OUT_ROWS, "congruence")[_OUT_ROWS - 1]
                 lo = jax.lax.axis_index(axis) * local_pad
                 valid = (lo + jnp.arange(local_pad)) < v
                 masked = jnp.where(valid[None, :], agg, jnp.inf)

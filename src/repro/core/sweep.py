@@ -54,6 +54,7 @@ from repro.core.machine import (
     Subsystem,
     TPU_V5E,
 )
+from repro.core.spans import span, spanned
 
 # The machine-model constants a sweep may vary, in canonical order.
 SWEEP_PARAMS = (
@@ -563,6 +564,7 @@ def default_beta_batched(
         be.default_beta(pb.arrays(), mb.select(beta_ref).arrays()))
 
 
+@spanned("pareto")
 def pareto_front_indices(area, aggregate) -> List[int]:
     """Indices on the 2-D (area, aggregate) Pareto front, both minimized.
 
@@ -583,6 +585,7 @@ def pareto_front_indices(area, aggregate) -> List[int]:
     return front
 
 
+@spanned("pareto")
 def pareto_front_indices_3d(aggregate, area, power) -> List[int]:
     """Indices on the 3-D (aggregate, area, power) front, all minimized.
 
@@ -640,6 +643,7 @@ class SweepResult:
 
     # --------------------------- extractions -------------------------- #
 
+    @spanned("reduce")
     def best_fit_indices(self) -> np.ndarray:
         """Per-app argmin over variants (lowest aggregate = best fit)."""
         return np.argmin(self.aggregate, axis=1)
@@ -648,6 +652,7 @@ class SweepResult:
         return self.machines.names[int(
             np.argmin(self.aggregate[self.app_index(app)]))]
 
+    @spanned("reduce")
     def aggregate_mean(self) -> np.ndarray:
         """Suite-mean aggregate per variant (Table I bottom row), shape (V,)."""
         return self.aggregate.mean(axis=0)
@@ -864,6 +869,7 @@ class SweepResult:
         )
 
 
+@spanned("sweep")
 def batched_congruence(
     profiles,
     machines,
@@ -929,6 +935,7 @@ def batched_congruence(
     )
 
 
+@spanned("popgen")
 def _population(space: ParamSpace, n: int, mode: str, seed: int,
                 include_named: Sequence[MachineModel]) -> MachineBatch:
     """The population ``run_sweep`` and ``shard_sweep`` share.
@@ -1047,6 +1054,7 @@ class PopulationStream:
             return self.space.sample_at(idx, seed=self.seed)
         return self.space.grid_at(idx, self._grid_points)
 
+    @spanned("popgen")
     def batch(self, lo: int, hi: int) -> MachineBatch:
         """Contiguous ``[lo, hi)`` slice -- one shard of a streamed sweep."""
         k = self.num_named
@@ -1057,6 +1065,7 @@ class PopulationStream:
             parts.append(self._generated(np.arange(max(lo - k, 0), hi - k)))
         return parts[0] if len(parts) == 1 else MachineBatch.concat(*parts)
 
+    @spanned("popgen")
     def take(self, indices) -> MachineBatch:
         """Arbitrary rows by global index (the survivor re-score gather)."""
         idx = np.asarray(indices, dtype=np.int64)
@@ -1176,6 +1185,7 @@ def _resolve_beta(profiles: ProfileBatch, beta, beta_machine,
         np.asarray(beta, dtype=np.float64), (len(profiles),)).copy()
 
 
+@spanned("sweep")
 def run_sweep(
     profiles,
     *,
@@ -1381,6 +1391,7 @@ def _sweep_signature(pop_tag: str, v: int, num_shards: int, backend_name: str,
     return h.hexdigest()
 
 
+@spanned("shard_sweep")
 def shard_sweep(
     profiles,
     *,
@@ -1549,57 +1560,61 @@ def shard_sweep(
     for s, (lo, hi) in enumerate(bounds):
         if s < start_shard:
             continue
-        mb = shard_batch(lo, hi)
-        stats = None
-        if mesh is not None and distributed:
-            stats = be.sharded_stats(pb.arrays(), mb.arrays(), beta_vec,
-                                     mesh, timing_model=timing_model,
-                                     clamp=clamp, pad_to=pad_to)
-        if stats is None:
-            out = be.congruence(pb.arrays(), mb.arrays(), beta_vec,
-                                timing_model=timing_model, clamp=clamp)
-            agg = be.to_numpy(out.aggregate)
-            agg_mean_s = agg.mean(axis=0)
-            local_idx = np.argmin(agg, axis=1)
-            local_min = agg[np.arange(len(pb)), local_idx]
-        else:
-            agg_mean_s, local_min, local_idx = stats
-        # strict < keeps the first-occurrence argmin across shards in
-        # index order, matching a single global argmin
-        better = local_min < app_min
-        app_min = np.where(better, local_min, app_min)
-        app_idx = np.where(better, local_idx + lo, app_idx)
+        with span("shard"):
+            mb = shard_batch(lo, hi)
+            stats = None
+            if mesh is not None and distributed:
+                stats = be.sharded_stats(pb.arrays(), mb.arrays(), beta_vec,
+                                         mesh, timing_model=timing_model,
+                                         clamp=clamp, pad_to=pad_to)
+            if stats is None:
+                out = be.congruence(pb.arrays(), mb.arrays(), beta_vec,
+                                    timing_model=timing_model, clamp=clamp)
+                with span("reduce"):
+                    agg = be.to_numpy(out.aggregate)
+                    agg_mean_s = agg.mean(axis=0)
+                    local_idx = np.argmin(agg, axis=1)
+                    local_min = agg[np.arange(len(pb)), local_idx]
+            else:
+                agg_mean_s, local_min, local_idx = stats
+            with span("reduce"):
+                # strict < keeps the first-occurrence argmin across shards
+                # in index order, matching a single global argmin
+                better = local_min < app_min
+                app_min = np.where(better, local_min, app_min)
+                app_idx = np.where(better, local_idx + lo, app_idx)
+                order = np.argsort(agg_mean_s, kind="stable")[:keep_top]
+                survivors.update(int(lo + i) for i in order)
+                area_s = np.asarray(cost_model.area(mb))
+                power_s = np.asarray(cost_model.power(mb))
+            survivors.update(
+                lo + i for i in pareto_front_indices(area_s, agg_mean_s))
+            survivors.update(
+                lo + i for i in pareto_front_indices_3d(agg_mean_s, area_s,
+                                                        power_s))
 
-        area_s = np.asarray(cost_model.area(mb))
-        power_s = np.asarray(cost_model.power(mb))
-        survivors.update(
-            lo + i for i in pareto_front_indices(area_s, agg_mean_s))
-        survivors.update(
-            lo + i for i in pareto_front_indices_3d(agg_mean_s, area_s,
-                                                    power_s))
-        order = np.argsort(agg_mean_s, kind="stable")[:keep_top]
-        survivors.update(int(lo + i) for i in order)
-
-        if checkpoint_dir is not None:
-            ckpt.save(
-                checkpoint_dir, s + 1,
-                {"app_idx": app_idx, "app_min": app_min,
-                 "survivors": np.array(sorted(survivors), dtype=np.int64)},
-                extra={"config": config_sig, "completed_shards": s + 1,
-                       "num_shards": num_shards, "num_variants": v})
-            ckpt.retain(checkpoint_dir, keep=checkpoint_keep)
-        if progress is not None:
-            progress(s, num_shards, lo, hi)
+            if checkpoint_dir is not None:
+                ckpt.save(
+                    checkpoint_dir, s + 1,
+                    {"app_idx": app_idx, "app_min": app_min,
+                     "survivors": np.array(sorted(survivors),
+                                           dtype=np.int64)},
+                    extra={"config": config_sig, "completed_shards": s + 1,
+                           "num_shards": num_shards, "num_variants": v})
+                ckpt.retain(checkpoint_dir, keep=checkpoint_keep)
+            if progress is not None:
+                progress(s, num_shards, lo, hi)
 
     # ---- re-score the survivor union into a full (front-complete) result
-    candidate_set = set(survivors)
-    candidate_set.update(int(i) for i in app_idx)
-    candidates = np.array(sorted(candidate_set), dtype=np.int64)
-    cand_batch = (src.take(candidates) if src is not None
-                  else pop.take(candidates))
-    result = batched_congruence(
-        pb, cand_batch, beta=beta_vec, timing_model=timing_model,
-        clamp=clamp, backend=be)
+    with span("rescore"):
+        candidate_set = set(survivors)
+        candidate_set.update(int(i) for i in app_idx)
+        candidates = np.array(sorted(candidate_set), dtype=np.int64)
+        cand_batch = (src.take(candidates) if src is not None
+                      else pop.take(candidates))
+        result = batched_congruence(
+            pb, cand_batch, beta=beta_vec, timing_model=timing_model,
+            clamp=clamp, backend=be)
     cand_pos = {int(g): j for j, g in enumerate(candidates)}
     return ShardedSweepResult(
         result=result,

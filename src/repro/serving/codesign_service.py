@@ -14,15 +14,15 @@ door over the SAME kernels:
     are app-rowwise independent, so each scattered result is
     byte-identical to a direct ``run_sweep`` for that request alone
     (pinned in tests/test_serving.py).
-  * **Compile/artifact caching** -- populations are cached by
-    (space, n, mode, seed, named-seed) signature in a byte-bounded LRU
-    (``pop_cache_bytes``) so repeat queries skip generation without a
-    mega-request pinning unbounded RAM; artifact keys
-    ``(population shape, backend, constraint
-    signature)`` are tracked so same-shape queries reuse the backend's
-    jitted kernels instead of re-tracing; byte-identical repeat requests
-    hit a result memo and skip everything.  Frontier queries warm-start
-    from cached continuation state at the nearest already-solved budget
+  * **Caching** -- populations are cached by (space, n, mode, seed,
+    named-seed) signature in a byte-bounded LRU (``pop_cache_bytes``) so
+    repeat queries skip generation without a mega-request pinning
+    unbounded RAM; same-shape queries reuse the backends' jitted kernels,
+    and ``stats`` reports the process's jit retraces, compiles and
+    persistent-cache hits (``repro.core.spans.counters``), so a query
+    that traces anew shows there; byte-identical repeat requests hit a
+    result memo and skip everything.  Frontier queries warm-start from
+    cached continuation state at the nearest already-solved budget
     (``frontier_codesign(warm_theta=...)``).
   * **Async job queue** -- bounded worker threads behind a thread-safe
     submit/poll/stream API.  Overload is a 429-style
@@ -52,6 +52,7 @@ from typing import Any, Dict, Iterator, List, Mapping, Optional, Sequence
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.costmodel import DEFAULT_COST_MODEL
 from repro.core.machine import VARIANTS
 from repro.core.spec import CodesignSpec
@@ -282,9 +283,8 @@ class CodesignService:
         self._pop_bytes = 0
         self._memo: Dict[str, Any] = {}
         self._frontier_state: Dict[str, dict] = {}
-        self._artifacts: Dict[str, int] = {}
         # accounting -------------------------------------------------------
-        self.stats = collections.Counter()
+        self._counts = collections.Counter()
         # workers ----------------------------------------------------------
         self._threads: List[threading.Thread] = []
         if auto_start and workers > 0:
@@ -293,6 +293,16 @@ class CodesignService:
                                      name=f"codesign-worker-{i}", daemon=True)
                 t.start()
                 self._threads.append(t)
+
+    @property
+    def stats(self) -> collections.Counter:
+        """The service's accounting (submissions, memo and population
+        cache hits, batching, job outcomes) plus the process's jit
+        counters ``retrace``, ``compile`` and ``cache_hit``."""
+        with self._cond:
+            out = collections.Counter(self._counts)
+        out.update(spans.counters())
+        return out
 
     # ------------------------------ client API ------------------------- #
 
@@ -306,7 +316,7 @@ class CodesignService:
             if self._stop:
                 raise RuntimeError("service is shut down")
             if len(self._queue) >= self.max_pending:
-                self.stats["rejected"] += 1
+                self._counts["rejected"] += 1
                 raise ServiceOverloadError(
                     f"pending queue full ({self.max_pending}); retry later")
             self._next_id += 1
@@ -314,7 +324,7 @@ class CodesignService:
                       submitted_at=time.monotonic())
             self._jobs[job.jid] = job
             self._queue.append(job)
-            self.stats["submitted"] += 1
+            self._counts["submitted"] += 1
             self._cond.notify_all()
             return job.jid
 
@@ -451,7 +461,7 @@ class CodesignService:
         job.result = result
         job.error = error
         job.finished_at = time.monotonic()
-        self.stats[state] += 1
+        self._counts[state] += 1
         self._cond.notify_all()
 
     def _complete(self, job: Job, result) -> None:
@@ -478,12 +488,12 @@ class CodesignService:
         memo_key = req.memo_key()
         with self._cond:
             if memo_key in self._memo:
-                self.stats["memo_hits"] += 1
+                self._counts["memo_hits"] += 1
                 job.cache = "memo"
                 job.events.append({"event": "cached", "jid": job.jid})
                 self._finish(job, DONE, result=self._memo[memo_key])
                 return
-            self.stats["memo_misses"] += 1
+            self._counts["memo_misses"] += 1
             riders = (self._claim_riders(job)
                       if req.kind == "sweep" else [])
         group = [job] + riders
@@ -542,9 +552,9 @@ class CodesignService:
             pop = self._populations.get(key)
             if pop is not None:
                 self._populations.move_to_end(key)
-                self.stats["pop_hits"] += 1
+                self._counts["pop_hits"] += 1
                 return pop
-            self.stats["pop_misses"] += 1
+            self._counts["pop_misses"] += 1
         pop = _population(space, n, mode, seed, list(include_named))
         with self._cond:
             cached = self._populations.get(key)
@@ -559,21 +569,10 @@ class CodesignService:
                        and len(self._populations) > 1):
                     _, old = self._populations.popitem(last=False)
                     self._pop_bytes -= self._pop_nbytes(old)
-                    self.stats["pop_evictions"] += 1
+                    self._counts["pop_evictions"] += 1
             else:
-                self.stats["pop_uncacheable"] += 1
+                self._counts["pop_uncacheable"] += 1
             return pop
-
-    def _note_artifact(self, kind: str, shape, backend, constraint_sig) -> None:
-        """Track the (population shape, backend, constraint signature)
-        artifact key: a repeat key means the backend's jitted kernels (or
-        the descent trace at that shape) are reused rather than re-traced."""
-        key = _sig("artifact", kind, tuple(shape), str(backend),
-                   constraint_sig)
-        with self._cond:
-            seen = self._artifacts.get(key, 0)
-            self._artifacts[key] = seen + 1
-            self.stats["artifact_hits" if seen else "artifact_misses"] += 1
 
     def _run_sweep_group(self, group: List[Job]) -> None:
         """ONE SoA pass for every job in ``group``: concatenate suites,
@@ -593,9 +592,6 @@ class CodesignService:
                               include_named, space, p["backend"])
                 for pb, j in zip(pbs, group)]
             suite = ProfileBatch.concat(*pbs) if len(pbs) > 1 else pbs[0]
-            self._note_artifact(
-                "sweep", (len(suite), len(pop)), p["backend"],
-                _sig(p["timing_model"], p["clamp"]))
             full = run_sweep(
                 suite, space=space, n=p["n"], mode=p["mode"], seed=p["seed"],
                 include_named=include_named, beta=np.concatenate(betas),
@@ -607,8 +603,8 @@ class CodesignService:
                 self._fail(job, exc)
             return
         if len(group) > 1:
-            self.stats["batched_groups"] += 1
-            self.stats["batched_requests"] += len(group)
+            self._counts["batched_groups"] += 1
+            self._counts["batched_requests"] += len(group)
         lo = 0
         for job, pb in zip(group, pbs):
             hi = lo + len(pb)
@@ -638,9 +634,6 @@ class CodesignService:
                 self._cond.notify_all()
 
         pb = _as_profile_batch(req.profiles)
-        self._note_artifact("mega_sweep", (len(pb), p["n"]), p["backend"],
-                            _sig(p["timing_model"], p["clamp"],
-                                 req.num_shards, req.keep_top, req.stream))
         return shard_sweep(
             pb, space=space, n=p["n"], mode=p["mode"], seed=p["seed"],
             include_named=list(req.include_named), beta=spec.beta,
@@ -667,8 +660,6 @@ class CodesignService:
 
         req = job.request
         seeds = self._seeds(req)
-        self._note_artifact("constrained", (len(seeds),), "jax",
-                            self._constraint_sig(req.spec))
         return constrained_codesign(req.profiles, seeds, spec=req.spec)
 
     def _run_joint(self, job: Job):
@@ -676,8 +667,6 @@ class CodesignService:
 
         req = job.request
         seeds = self._seeds(req)
-        self._note_artifact("joint", (len(seeds),), "jax",
-                            self._constraint_sig(req.spec))
         return joint_codesign(req.profiles, seeds, spec=req.spec)
 
     def _run_pack(self, job: Job):
@@ -686,9 +675,6 @@ class CodesignService:
         req = job.request
         seeds = self._seeds(req)
         spec = req.spec
-        self._note_artifact(
-            "pack", (len(seeds), spec.num_machines or 4), "jax",
-            self._constraint_sig(spec))
         # ``PackingResult`` joins the response path purely through the
         # uniform markdown/to_json protocol -- render_result needs no
         # isinstance knowledge of it.
@@ -703,10 +689,6 @@ class CodesignService:
         if spec.total_budget is None:
             raise ValueError("kind='bilevel' needs spec.total_budget "
                              "(the budget split across area and power)")
-        self._note_artifact(
-            "bilevel", (len(seeds),), "jax",
-            _sig(spec.total_budget, spec.split0, spec.outer_steps,
-                 spec.area_envelope, spec.projection))
         # ``BilevelResult`` joins the response path purely through the
         # uniform markdown/to_json protocol, like pack does.
         return bilevel_codesign(req.profiles, seeds, spec=spec)
@@ -738,12 +720,10 @@ class CodesignService:
                 pick = min(ge) if ge else max(solved)
                 warm_theta = entry["thetas"][pick]
                 warm_lr = entry["lr"]
-                self.stats["frontier_warm_hits"] += 1
+                self._counts["frontier_warm_hits"] += 1
                 job.cache = "warm"
             else:
-                self.stats["frontier_warm_misses"] += 1
-        self._note_artifact("frontier", (len(seeds),), "jax",
-                            self._constraint_sig(spec))
+                self._counts["frontier_warm_misses"] += 1
         res = frontier_codesign(req.profiles, seeds, spec=spec,
                                 warm_theta=warm_theta, warm_lr=warm_lr,
                                 keep_state=True)

@@ -57,6 +57,12 @@ def sweep_req(tag, k=2, **kw):
                            spec=SPEC32, **kw)
 
 
+def jax_sweep_req(tag, k=2):
+    """A sweep on the jax backend, whose kernels trace per shape."""
+    return CodesignRequest(kind="sweep", profiles=suite(tag, k),
+                           spec=CodesignSpec(n=32, seed=0, backend="jax"))
+
+
 # --------------------------------------------------------------------------- #
 # Micro-batching equality pins
 # --------------------------------------------------------------------------- #
@@ -117,21 +123,23 @@ def test_incompatible_sweeps_do_not_batch():
 
 def test_single_sweep_matches_direct_and_population_cache_hits():
     svc = CodesignService(auto_start=False)
-    j1 = svc.submit(sweep_req("solo"))
+    j1 = svc.submit(jax_sweep_req("solo"))
     svc.drain()
     assert svc.stats["pop_misses"] == 1
-    j2 = svc.submit(sweep_req("other", k=3))   # same space/n/seed, new suite
+    retraces = svc.stats["retrace"]
+    j2 = svc.submit(jax_sweep_req("other", k=3))  # same space/n/seed, new A
     svc.drain()
     assert svc.stats["pop_hits"] == 1          # population regenerated 0x
-    assert svc.stats["artifact_hits"] == 0     # different A -> new shapes
+    assert svc.stats["retrace"] > retraces     # different A -> new shapes
     assert_sweep_equal(svc.result(j1, timeout=5),
-                       run_sweep(suite("solo"), n=32, seed=0))
+                       run_sweep(suite("solo"), n=32, seed=0, backend="jax"))
     assert_sweep_equal(svc.result(j2, timeout=5),
-                       run_sweep(suite("other", 3), n=32, seed=0))
+                       run_sweep(suite("other", 3), n=32, seed=0,
+                                 backend="jax"))
 
 
 # --------------------------------------------------------------------------- #
-# Result memo + artifact accounting
+# Result memo + jit accounting
 # --------------------------------------------------------------------------- #
 
 
@@ -245,13 +253,17 @@ def test_frontier_warm_start_from_cached_continuation():
 
 
 def test_artifact_cache_accounting_same_shape_hits():
+    """A same-shape query reuses the jax backend's compiled kernels: the
+    jit counters the service reports show no retrace."""
     svc = CodesignService(auto_start=False)
-    svc.submit(sweep_req("art1", k=2))
+    svc.submit(jax_sweep_req("art1", k=2))
     svc.drain()
-    svc.submit(sweep_req("art2", k=2))     # same (A, V, backend, constraints)
+    retraces, compiles = svc.stats["retrace"], svc.stats["compile"]
+    svc.submit(jax_sweep_req("art2", k=2))  # same (A, V, backend, config)
     svc.drain()
-    assert svc.stats["artifact_misses"] == 1
-    assert svc.stats["artifact_hits"] == 1
+    assert svc.stats["memo_misses"] == 2       # both ran the kernels
+    assert svc.stats["retrace"] == retraces
+    assert svc.stats["compile"] == compiles
 
 
 # --------------------------------------------------------------------------- #
