@@ -22,7 +22,9 @@ from repro.core import (
 )
 from repro.core.congruence import default_beta
 from repro.core.dse import DseTable, LazyDseTable, evaluate
+from repro.core import sweep as sweep_module
 from repro.core.sweep import (
+    _DOMINANCE_BLOCK,
     Dim,
     MachineBatch,
     ParamSpace,
@@ -30,6 +32,8 @@ from repro.core.sweep import (
     batched_congruence,
     batched_step_time,
     halton,
+    pareto_front_indices,
+    pareto_front_indices_3d,
     run_sweep,
 )
 from repro.core.timing import step_time, subsystem_times
@@ -359,6 +363,95 @@ def test_pareto_front_3d_has_no_dominated_point():
         assert dominated.any(), f"non-front point {i} is non-dominated"
 
 
+
+# The Python-loop Pareto extractions the vectorized ones replaced, kept
+# verbatim as oracles: the vectorized functions must return the very same
+# indices in the very same order.
+
+
+def loop_pareto_front_indices(area, aggregate):
+    area = np.asarray(area)
+    aggregate = np.asarray(aggregate)
+    order = sorted(range(len(area)), key=lambda i: (area[i], aggregate[i]))
+    front = []
+    best = np.inf
+    for i in order:
+        if aggregate[i] < best:
+            front.append(i)
+            best = aggregate[i]
+    return front
+
+
+def loop_pareto_front_indices_3d(aggregate, area, power):
+    aggregate = np.asarray(aggregate)
+    area = np.asarray(area)
+    power = np.asarray(power)
+    order = sorted(range(len(area)),
+                   key=lambda i: (area[i], power[i], aggregate[i]))
+    front = []
+    for i in order:
+        dominated = any(
+            area[j] <= area[i] and power[j] <= power[i]
+            and aggregate[j] <= aggregate[i]
+            and (area[j] < area[i] or power[j] < power[i]
+                 or aggregate[j] < aggregate[i])
+            for j in front)
+        if not dominated:
+            front.append(i)
+    return front
+
+
+def _pareto_points(n, kind, seed):
+    """``(area, power, aggregate)`` rows of ``n`` points.
+
+    ``uniform``: continuous, no ties.  ``quantized``: four levels per axis,
+    so exact ties and duplicate points abound.  ``inf``: a tenth of the
+    entries ``+inf``.  ``earlier_block``: a chain of mutually non-dominated
+    points longer than one dominance pass, screened by neither extreme,
+    and one point whose only dominator lies in the first pass."""
+    rng = np.random.default_rng(seed)
+    if kind == "earlier_block":
+        t = np.arange(n - 3, dtype=np.float64)
+        chain = np.stack([1.0 + t, 9.0 - 8.0 * t / n, 5.0 + t / n])
+        extremes = np.array([[0.0, 0.5], [0.0, 10.0], [10.0, 0.0]])
+        late = np.array([[n + 1.0], [chain[1, 0]], [chain[2, 0] + 0.5 / n]])
+        pts = np.concatenate([chain, extremes, late], axis=1)
+        return pts[:, rng.permutation(n)]
+    pts = rng.random((3, n))
+    if kind == "quantized":
+        pts = np.floor(pts * 4.0) / 4.0
+    elif kind == "inf":
+        pts[rng.random((3, n)) < 0.1] = np.inf
+    return pts
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("kind,n", [
+    *((kind, n) for kind in ("uniform", "quantized", "inf")
+      for n in (0, 1, 2, 17, _DOMINANCE_BLOCK - 1, _DOMINANCE_BLOCK + 1,
+                5000)),
+    ("earlier_block", _DOMINANCE_BLOCK + 8),
+])
+def test_pareto_extraction_equals_loop_oracle(kind, n, dtype):
+    """The vectorized 2-D and 3-D extractions return exactly the indices
+    of the Python loops, in order, as Python ints."""
+    area, power, aggregate = _pareto_points(n, kind, seed=n).astype(dtype)
+    for got, want in (
+            (pareto_front_indices(area, aggregate),
+             loop_pareto_front_indices(area, aggregate)),
+            (pareto_front_indices_3d(aggregate, area, power),
+             loop_pareto_front_indices_3d(aggregate, area, power))):
+        assert type(got) is list
+        assert all(type(i) is int for i in got)
+        assert got == want
+    if n == 1 and kind == "uniform":
+        assert pareto_front_indices(area, aggregate) == [0]
+        assert pareto_front_indices_3d(aggregate, area, power) == [0]
+    if kind == "earlier_block":
+        late = int(np.argmax(area))
+        assert late not in pareto_front_indices_3d(aggregate, area, power)
+
+
 def test_sweep_result_reports():
     profiles = random_profiles(3, seed=25)
     res = run_sweep(profiles, n=20, include_named=VARIANTS)
@@ -542,6 +635,37 @@ def test_shard_sweep_custom_cost_model_front_complete():
     assert [sharded.result.machines.names[i]
             for i in sharded.pareto_front_3d()] == ref3
     assert sharded.cost_model is cm
+
+
+
+def test_shard_sweep_prefilter_equals_loop_oracles(monkeypatch):
+    """The per-shard pre-filter keeps the same survivors, and the sweep
+    the same fronts, as with the Python-loop extractions patched in; both
+    are called through the module's attributes."""
+    from repro.core.sweep import shard_sweep
+
+    profiles = random_profiles(4, seed=13)
+    kwargs = dict(n=3000, num_shards=6, include_named=VARIANTS)
+    vectorized = shard_sweep(profiles, **kwargs)
+    calls = []
+
+    def counted(fn):
+        def inner(*args):
+            calls.append(fn.__name__)
+            return fn(*args)
+        return inner
+
+    monkeypatch.setattr(sweep_module, "pareto_front_indices",
+                        counted(loop_pareto_front_indices))
+    monkeypatch.setattr(sweep_module, "pareto_front_indices_3d",
+                        counted(loop_pareto_front_indices_3d))
+    looped = shard_sweep(profiles, **kwargs)
+    front2, front3 = looped.pareto_front(), looped.pareto_front_3d()
+    assert len(calls) == 2 * looped.num_shards + 2
+    assert (vectorized.candidate_indices.tolist()
+            == looped.candidate_indices.tolist())
+    assert vectorized.pareto_front() == front2
+    assert vectorized.pareto_front_3d() == front3
 
 
 def test_shard_sweep_multidevice_pad_masking():
