@@ -25,7 +25,7 @@ SMOKE = False
 # Two-suite split for Table I / Fig. 3 analogues (DESIGN.md §2):
 # dense transformers (Koios-like homogeneous compute) vs structured archs.
 DENSE_SUITE = ("chatglm3-6b", "qwen3-32b", "qwen1.5-4b", "deepseek-67b")
-STRUCTURED_SUITE = ("whisper-medium", "recurrentgemma-9b", "grok-1-314b",
+STRUCTURED_SUITE = ("whisper-medium", "jamba2-mini", "grok-1-314b",
                     "qwen2-moe-a2.7b", "paligemma-3b", "falcon-mamba-7b")
 
 

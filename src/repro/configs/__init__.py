@@ -13,6 +13,7 @@ from repro.configs import (
     deepseek_67b,
     falcon_mamba_7b,
     grok1_314b,
+    jamba2_mini,
     paligemma_3b,
     qwen15_4b,
     qwen2_moe_a27b,
@@ -34,6 +35,7 @@ _MODULES = (
     qwen2_moe_a27b,
     paligemma_3b,
     falcon_mamba_7b,
+    jamba2_mini,
 )
 
 REGISTRY: Dict[str, ModelConfig] = {m.CONFIG.name: m.CONFIG for m in _MODULES}

@@ -1,9 +1,10 @@
-"""Assigned input shapes (identical across the 10 LM-family architectures).
+"""Assigned input shapes (identical across the 11 LM-family architectures).
 
 ``decode_*`` / ``long_*`` lower ``serve_step`` (one new token with a KV cache
 of seq_len), not ``train_step``.  ``long_500k`` requires sub-quadratic
-sequence mixing: it runs for the SSM/hybrid archs and is skipped (with the
-reason recorded) for pure full-attention archs -- see DESIGN.md §5.
+sequence mixing: it runs for the SSM arch and the hybrid whose attention is
+windowed, and is skipped (with the reason recorded) for archs with
+full-attention layers -- see DESIGN.md §5.
 """
 
 from __future__ import annotations
@@ -35,7 +36,7 @@ SHAPES: Dict[str, ShapeSpec] = {
 # The zoo suite (core/model_zoo.py) profiles every registry config under
 # three serving scenarios.  Each scenario maps to a step kind plus a small
 # (seq_len, global_batch) grid; the full grid gives
-# 10 archs x 3 scenarios x 4 shapes = 120 cells, the smoke grid one tiny
+# 11 archs x 3 scenarios x 4 shapes = 132 cells, the smoke grid one tiny
 # single-device shape per scenario so the fast CI tier can recompile it.
 
 ZOO_SCENARIOS: Tuple[str, ...] = ("train", "serve-prefill", "serve-decode")
@@ -102,9 +103,9 @@ def applicable(cfg: ModelConfig, shape: ShapeSpec) -> Tuple[bool, Optional[str]]
     """Whether this (arch, shape) cell is runnable, else the skip reason."""
     if shape.name == "long_500k" and not cfg.supports_long_context:
         return False, (
-            "full-attention architecture: 500k dense-KV decode is "
+            "full-attention layers: 500k dense-KV decode is "
             "O(seq) per token with an unbounded window; assigned-shape rules "
-            "direct skipping pure full-attention archs"
+            "direct skipping archs with full-attention layers"
         )
     return True, None
 

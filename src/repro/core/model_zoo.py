@@ -18,7 +18,9 @@ shape, extraction version):
     checked in under ``src/repro/core/zoo_cache/`` and doubling as the
     golden files for ``tests/test_model_zoo.py``;
   * full suite (published configs, 16x16 pod mesh, needs the dry-run's
-    fake host devices) -- ``benchmarks/artifacts/zoo/``, regenerated via
+    fake host devices) -- read first from the cells checked in under
+    ``src/repro/core/zoo_cache/full/`` (jamba2-mini's twelve), then from
+    ``benchmarks/artifacts/zoo/``, regenerated via
     ``python -m repro.core.model_zoo``.
 
 The calibration layer (``calibration_report``) cross-checks the two
@@ -36,7 +38,7 @@ import hashlib
 import json
 import math
 import os
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -57,12 +59,22 @@ from repro.core.sweep import MachineBatch, ProfileBatch, batched_step_time
 #: detected by fingerprint mismatch and re-extracted (or rejected).
 ZOO_EXTRACTION_VERSION = 1
 
+#: The same, for math that only the full suite's pod meshes see; a term of
+#: full-suite fingerprints alone, so the smoke goldens keep theirs.  2: scan
+#: FLOPs counted per device (they were one global count added to per-device
+#: FLOPs), routed-expert FLOPs counted analytically.
+ZOO_FULL_EXTRACTION_VERSION = 2
+
 #: Smoke suite: one arch per major family branch (dense attention, SSM),
 #: small enough that the fast CI tier recompiles them from scratch.
 SMOKE_ARCHS: Tuple[str, ...] = ("chatglm3-6b", "falcon-mamba-7b")
 
 #: Checked-in smoke cache (module-relative: importable from any cwd).
 SMOKE_CACHE_DIR = os.path.join(os.path.dirname(__file__), "zoo_cache")
+
+#: Full-suite cells checked in with the program, read before the artifact
+#: tree below.
+FULL_CHECKED_IN_DIR = os.path.join(SMOKE_CACHE_DIR, "full")
 
 #: Default full-suite cache under the repo's benchmark-artifact tree
 #: (anchored to the source tree, like ``benchmarks.common.ART_DIR``, so
@@ -131,17 +143,17 @@ def cell_fingerprint(cell: ZooCell) -> str:
     deterministic), the shape, the scenario and the extraction version --
     so a cached profile is provably stale the moment any input changes.
     """
-    payload = json.dumps(
-        {
-            "version": ZOO_EXTRACTION_VERSION,
-            "arch": cell.arch,
-            "scenario": cell.scenario,
-            "smoke": cell.smoke,
-            "config": repr(cell.config),
-            "shape": dataclasses.asdict(cell.shape),
-        },
-        sort_keys=True,
-    )
+    inputs = {
+        "version": ZOO_EXTRACTION_VERSION,
+        "arch": cell.arch,
+        "scenario": cell.scenario,
+        "smoke": cell.smoke,
+        "config": repr(cell.config),
+        "shape": dataclasses.asdict(cell.shape),
+    }
+    if not cell.smoke:
+        inputs["full_version"] = ZOO_FULL_EXTRACTION_VERSION
+    payload = json.dumps(inputs, sort_keys=True)
     return hashlib.sha256(payload.encode()).hexdigest()[:16]
 
 
@@ -168,8 +180,11 @@ def cache_path(cell: ZooCell, cache_dir: str) -> str:
     return os.path.join(cache_dir, cell.cache_key + ".json")
 
 
-def default_cache_dir(smoke: bool) -> str:
-    return SMOKE_CACHE_DIR if smoke else FULL_CACHE_DIR
+def default_cache_dirs(smoke: bool) -> Tuple[str, ...]:
+    """Where a suite's cells are looked up, in order; extraction writes to
+    the last."""
+    return (SMOKE_CACHE_DIR,) if smoke else (FULL_CHECKED_IN_DIR,
+                                              FULL_CACHE_DIR)
 
 
 # --------------------------------------------------------------------------- #
@@ -236,26 +251,33 @@ def profiles_from_configs(
     makes missing/stale cells a hard error instead -- the cache-only mode
     CI and the CLIs use so a sweep never triggers a surprise zoo compile.
     """
-    cache_dir = cache_dir or default_cache_dir(smoke)
+    dirs = (cache_dir,) if cache_dir else default_cache_dirs(smoke)
     cells = zoo_cells(archs, scenarios, smoke=smoke)
     if max_cells is not None:
         cells = cells[:max_cells]
     out: List[WorkloadProfile] = []
     for cell in cells:
-        path = cache_path(cell, cache_dir)
-        if not refresh and os.path.exists(path):
-            profile = WorkloadProfile.load(path)
-            if profile.meta.get("fingerprint") == cell_fingerprint(cell):
-                out.append(profile)
-                continue
-            if not extract_missing:
-                raise RuntimeError(
-                    f"zoo cache entry {path} is stale (config/shape/"
-                    f"extraction-version changed since it was written); "
-                    f"regenerate with: PYTHONPATH=src python -m "
-                    f"repro.core.model_zoo {'--smoke ' if smoke else ''}"
-                    f"--refresh")
-        elif not refresh and not extract_missing:
+        path = cache_path(cell, dirs[-1])
+        found = stale = None
+        for d in () if refresh else dirs:
+            p = cache_path(cell, d)
+            if os.path.exists(p):
+                profile = WorkloadProfile.load(p)
+                if profile.meta.get("fingerprint") == cell_fingerprint(cell):
+                    found = profile
+                    break
+                stale = stale or p
+        if found is not None:
+            out.append(found)
+            continue
+        if stale and not extract_missing:
+            raise RuntimeError(
+                f"zoo cache entry {stale} is stale (config/shape/"
+                f"extraction-version changed since it was written); "
+                f"regenerate with: PYTHONPATH=src python -m "
+                f"repro.core.model_zoo {'--smoke ' if smoke else ''}"
+                f"--refresh")
+        if not refresh and not extract_missing:
             raise RuntimeError(
                 f"zoo cache entry {path} is missing; extract the suite "
                 f"first: PYTHONPATH=src python -m repro.core.model_zoo"
@@ -268,7 +290,7 @@ def profiles_from_configs(
             print(f"== zoo extract {cell.name} [{cell.scenario}] ==",
                   flush=True)
         profile = extract_profile(cell, calibrate=calibrate, verbose=verbose)
-        os.makedirs(cache_dir, exist_ok=True)
+        os.makedirs(dirs[-1], exist_ok=True)
         with open(path, "wb") as f:
             f.write(canonical_profile_bytes(profile))
         out.append(WorkloadProfile.from_json(canonical_profile_dict(profile)))
@@ -282,22 +304,33 @@ def profiles_from_configs(
 SUITE_BASES = ("zoo", "zoo-smoke")
 
 
-def parse_suite(suite: str) -> Tuple[bool, Optional[str]]:
-    """``zoo[:scenario]`` | ``zoo-smoke[:scenario]`` -> (smoke, scenario)."""
+class ZooSuite(NamedTuple):
+    smoke: bool
+    scenario: Optional[str]
+    arch: Optional[str]      # one registry architecture, or all of them
+
+
+def parse_suite(suite: str) -> ZooSuite:
+    """``zoo[@arch][:scenario]`` | ``zoo-smoke[@arch][:scenario]``."""
     if not isinstance(suite, str):
         raise ValueError(f"suite must be a string, got {type(suite).__name__}")
-    base, sep, scenario = suite.partition(":")
+    head, sep, scenario = suite.partition(":")
+    base, at, arch = head.partition("@")
     if base not in SUITE_BASES:
         raise ValueError(
             f"unknown suite {suite!r}; expected "
-            f"{' | '.join(SUITE_BASES)} with an optional "
-            f":scenario of {ZOO_SCENARIOS}, or a generated suite "
-            f"gen:<count>[:seed=<int>][:mode=halton|rng]")
+            f"{' | '.join(SUITE_BASES)} with an optional @arch of the "
+            f"registry and an optional :scenario of {ZOO_SCENARIOS}, or a "
+            f"generated suite gen:<count>[:seed=<int>][:mode=halton|rng]")
+    if at and arch not in ARCH_IDS:
+        raise ValueError(f"unknown architecture {arch!r} in suite "
+                         f"{suite!r}; have {ARCH_IDS}")
     if sep and scenario not in ZOO_SCENARIOS:
         raise ValueError(
             f"unknown zoo scenario {scenario!r} in suite {suite!r}; "
             f"have {ZOO_SCENARIOS}")
-    return base == "zoo-smoke", (scenario if sep else None)
+    return ZooSuite(base == "zoo-smoke", scenario if sep else None,
+                    arch if at else None)
 
 
 def validate_suite_name(suite: Optional[str]) -> None:
@@ -334,10 +367,11 @@ def resolve_suite(
     from repro.core.genload import is_gen_suite, resolve_gen_suite
     if is_gen_suite(suite):
         return resolve_gen_suite(suite)
-    smoke, scenario = parse_suite(suite)
+    smoke, scenario, arch = parse_suite(suite)
     if extract_missing is None:
         extract_missing = smoke
     return profiles_from_configs(
+        archs=(arch,) if arch else None,
         scenarios=(scenario,) if scenario else None,
         smoke=smoke, cache_dir=cache_dir, extract_missing=extract_missing)
 

@@ -36,12 +36,19 @@ def default_variant(cfg) -> str:
 # XLA's cost_analysis counts a while-loop body ONCE, so a scan-over-layers
 # model under-reports FLOPs/bytes/collectives by ~n_layers.  Per-layer costs
 # are exactly linear in depth for homogeneous stacks, so we compile two (or
-# three, for the heterogeneous hybrid) UNROLLED probes at reduced depth and
+# three, for a hybrid with a tail) UNROLLED probes at reduced depth and
 # full width/batch/mesh, and extrapolate:  total(L) = c(a) + (L-a)*body where
-# body = (c(b)-c(a))/(b-a).  The full-depth scanned artifact is still what we
+# body = (c(b)-c(a))/(b-a); a hybrid stack probes one and two periods of its
+# pattern (plus the tail).  The full-depth scanned artifact is still what we
 # ship (memory_analysis comes from it); only the cost terms are calibrated.
 # Sequential SSM/LRU elementwise scans stay loops even in probes; their FLOPs
 # are added analytically (DESIGN.md §2 note; < ~5% of model FLOPs).
+#
+# A routed expert's grouped matmul (``lax.ragged_dot``, which the CPU lowers
+# to one dot over every expert held here) is counted for every routed row:
+# n_experts times the rows those experts receive under balanced routing.
+# Its FLOPs are linear in the expert width, so one more probe at half the
+# width measures them, and the calibration keeps 1/n_experts of them.
 # --------------------------------------------------------------------------- #
 
 
@@ -87,58 +94,107 @@ def _probe_cfg(cfg, depth):
     return c
 
 
-def _analytic_scan_flops(cfg, shape) -> float:
-    """FLOPs of the sequential elementwise recurrences (uncountable loops)."""
+def _analytic_scan_flops(cfg, shape, n_devices: int) -> float:
+    """Per-device FLOPs of the sequential elementwise recurrences (the
+    loops cost_analysis counts once): 8 per Mamba state element and 10 per
+    RG-LRU channel, per token and layer, split evenly over the mesh."""
     mult = 3.0 if shape.kind == "train" else 1.0  # fwd(+bwd recompute)
     if shape.kind == "decode":
         tokens = shape.global_batch
     else:
         tokens = shape.global_batch * shape.seq_len
     if cfg.family == Family.SSM:
-        d_in = cfg.ssm.expand * cfg.d_model
-        per_tok_layer = d_in * cfg.ssm.state_dim * 8.0
-        return mult * tokens * cfg.n_layers * per_tok_layer
+        mixers = ["mamba"] * cfg.n_layers
+    elif cfg.family == Family.HYBRID:
+        h = cfg.hybrid
+        mixers = [h.mixer(h.pattern[i % len(h.pattern)])
+                  for i in range(cfg.n_layers)]
+    else:
+        return 0.0
+    per_token = {
+        "mamba": (cfg.ssm.expand * cfg.d_model * cfg.ssm.state_dim * 8.0
+                  if cfg.ssm is not None else 0.0),
+        "rec": (cfg.hybrid.lru_width or cfg.d_model) * 10.0
+        if cfg.hybrid is not None else 0.0,
+    }
+    return mult * tokens * sum(per_token.get(m, 0.0)
+                               for m in mixers) / n_devices
+
+
+def _probe_costs(cfg, shape, mesh, sc, depth, *, multi_pod=False,
+                 rules_kind=None) -> dict:
+    """Costs of ``cfg`` cut to ``depth`` blocks, compiled unrolled."""
+    dpp = MESH.DEVICES_PER_POD if multi_pod else 0
+    cell = input_specs(_probe_cfg(cfg, depth), shape, mesh, sc)
+    with jax.set_mesh(mesh), CTX.use_rules(
+            SH.activation_rules(mesh, sc, kind=rules_kind or shape.kind)):
+        compiled = jax.jit(
+            cell.step_fn, in_shardings=cell.in_shardings,
+            out_shardings=cell.out_shardings,
+            donate_argnums=cell.donate_argnums,
+        ).lower(*cell.args).compile()
+    return _cost_dict(compiled, dpp)
+
+
+def _n_moe_blocks(cfg, depth: int) -> int:
+    """MoE blocks among the first ``depth`` blocks of the stack."""
     if cfg.family == Family.HYBRID:
-        w = cfg.hybrid.lru_width or cfg.d_model
-        n_rec = sum(1 for i in range(cfg.n_layers)
-                    if cfg.hybrid.pattern[i % len(cfg.hybrid.pattern)] == "rec")
-        return mult * tokens * n_rec * w * 10.0
-    return 0.0
+        p = cfg.hybrid.pattern
+        return sum(cfg.hybrid.is_moe(p[i % len(p)]) for i in range(depth))
+    return depth if cfg.family == Family.MOE else 0
+
+
+def _routed_excess(cfg, shape, mesh, sc, depth, c_depth, **kw) -> float:
+    """Dot FLOPs, per device at full depth, that the compiled program counts
+    beyond the routed experts' expected work: the probe at ``depth`` (costs
+    ``c_depth``) against the same probe at half the expert width gives half
+    the routed dot FLOPs counted, of which all but 1/n_experts are excess.
+    (The elementwise work on the routed rows is done for every row, and
+    stays.)"""
+    m = cfg.moe
+    if m is None or m.impl != "gmm" or not _n_moe_blocks(cfg, depth):
+        return 0.0
+    half = cfg.replace(moe=dataclasses.replace(
+        m, d_ff_expert=m.d_ff_expert // 2))
+    c_half = _probe_costs(half, shape, mesh, sc, depth, **kw)
+    return (2.0 * (1.0 - 1.0 / m.n_experts)
+            * _n_moe_blocks(cfg, cfg.n_layers) / _n_moe_blocks(cfg, depth)
+            * (c_depth["dot_flops"] - c_half["dot_flops"]))
 
 
 def calibrate_costs(cfg, shape, mesh, mesh_label, sc, *, multi_pod,
                     verbose=True, rules_kind=None) -> dict:
-    dpp = MESH.DEVICES_PER_POD if multi_pod else 0
-    rules_kind = rules_kind or shape.kind
-
     def probe(depth):
-        pcfg = _probe_cfg(cfg, depth)
-        cell = input_specs(pcfg, shape, mesh, sc)
-        with jax.set_mesh(mesh), CTX.use_rules(
-                SH.activation_rules(mesh, sc, kind=rules_kind)):
-            compiled = jax.jit(
-                cell.step_fn, in_shardings=cell.in_shardings,
-                out_shardings=cell.out_shardings,
-                donate_argnums=cell.donate_argnums,
-            ).lower(*cell.args).compile()
-        return _cost_dict(compiled, dpp)
+        return _probe_costs(cfg, shape, mesh, sc, depth, multi_pod=multi_pod,
+                            rules_kind=rules_kind)
 
     t0 = time.time()
     if cfg.family == Family.HYBRID:
         from repro.models.transformer import hybrid_layout
-        c3, c4, c6 = probe(3), probe(4), probe(6)
+        period = len(cfg.hybrid.pattern)
         n_groups, n_tail = hybrid_layout(cfg)
-        # rec body = c4-c3; group body (2 rec + 1 att) = c6-c3
-        total = _lincomb((1.0, c3), (float(n_groups - 1), c6),
-                         (-float(n_groups - 1), c3),
-                         (float(n_tail), c4), (-float(n_tail), c3))
+        # one period's body = c(2P) - c(P); the tail's = c(P + tail) - c(P)
+        a = period
+        ca = probe(a)
+        terms = [(1.0, ca)]
+        if n_groups > 1:
+            c2 = probe(2 * period)
+            terms += [(float(n_groups - 1), c2), (-float(n_groups - 1), ca)]
+        if n_tail:
+            terms += [(1.0, probe(period + n_tail)), (-1.0, ca)]
+        total = _lincomb(*terms)
     else:
         a, b = 2, 4
         ca, cb = probe(a), probe(b)
         L = cfg.n_layers
         scale = (L - a) / (b - a)
         total = _lincomb((1.0, ca), (scale, cb), (-scale, ca))
-    total["flops"] += _analytic_scan_flops(cfg, shape)
+    excess = _routed_excess(cfg, shape, mesh, sc, a, ca, multi_pod=multi_pod,
+                            rules_kind=rules_kind)
+    total["flops"] -= excess
+    total["dot_flops"] -= excess
+    total["routed_excess_flops"] = excess
+    total["flops"] += _analytic_scan_flops(cfg, shape, mesh.size)
     total["probe_seconds"] = time.time() - t0
     if verbose:
         print(f"  probes done in {total['probe_seconds']:.1f}s "
@@ -207,6 +263,8 @@ def run_cell(cfg, shape, mesh, mesh_label, variant, out_dir, *,
         profile.pod_collective_bytes = cal["pod_coll"]
         profile.meta["raw_uncalibrated"] = raw
         profile.meta["probe_seconds"] = cal["probe_seconds"]
+        if cal["routed_excess_flops"]:
+            profile.meta["routed_excess_flops"] = cal["routed_excess_flops"]
     if verbose:
         print(f"  memory_analysis: {mem}")
         print("  cost_analysis:", {k: v for k, v in (cost or {}).items()

@@ -2,7 +2,8 @@
 
 One ``ModelConfig`` describes any of: dense decoder LMs (llama/qwen/chatglm/
 deepseek), MoE decoders (grok/qwen2-moe), SSM stacks (falcon-mamba), hybrid
-recurrent/local-attention stacks (recurrentgemma), encoder-decoder audio
+stacks built from a period of mixed blocks (recurrentgemma's RG-LRU and
+local attention, Jamba's Mamba, attention and MoE), encoder-decoder audio
 models (whisper) and vision-prefixed LMs (paligemma).
 """
 
@@ -33,6 +34,7 @@ class MoEConfig:
     impl: str = "gmm"   # gmm (sort+ragged_dot) | dense (all experts) | capacity
     capacity_factor: float = 1.25  # capacity impl: C = Tg*k*cf/E (drops beyond)
     aux_loss_weight: float = 0.01
+    renormalize: bool = True       # top-k gates rescaled to sum to 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,12 +46,37 @@ class SSMConfig:
     scan_chunk: int = 256          # chunked-scan length (memory/compile knob)
 
 
+#: Sequence mixers a hybrid block may hold: RG-LRU, softmax attention and
+#: Mamba-1.
+HYBRID_MIXERS = ("rec", "att", "mamba")
+
+
 @dataclasses.dataclass(frozen=True)
 class HybridConfig:
-    # Block pattern period, e.g. ("rec", "rec", "att") for RecurrentGemma 1:2.
+    # One period of blocks, e.g. ("rec", "rec", "att") for RecurrentGemma's
+    # 2:1.  Each block is "<mixer>" (dense FFN) or "<mixer>+moe" (MoE FFN),
+    # with the mixer one of HYBRID_MIXERS; Mamba blocks take their sizes
+    # from ModelConfig.ssm (and carry Jamba's learned RMSNorms on dt, B and
+    # C), MoE blocks from ModelConfig.moe.
     pattern: Tuple[str, ...] = ("rec", "rec", "att")
     lru_width: int = 0             # 0 = d_model
     conv_width: int = 4
+
+    def __post_init__(self):
+        for kind in self.pattern:
+            mixer, _, ffn = kind.partition("+")
+            if mixer not in HYBRID_MIXERS or ffn not in ("", "moe"):
+                raise ValueError(
+                    f"hybrid block {kind!r}: expected <mixer>[+moe] with a "
+                    f"mixer of {HYBRID_MIXERS}")
+
+    @staticmethod
+    def mixer(kind: str) -> str:
+        return kind.partition("+")[0]
+
+    @staticmethod
+    def is_moe(kind: str) -> bool:
+        return kind.endswith("+moe")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,8 +142,13 @@ class ModelConfig:
 
     @property
     def supports_long_context(self) -> bool:
-        """Sub-quadratic sequence mixing -> long_500k decode is runnable."""
-        return self.family in (Family.SSM, Family.HYBRID)
+        """Sub-quadratic sequence mixing -> long_500k decode is runnable.
+        A hybrid qualifies only where its attention is windowed."""
+        if self.family == Family.HYBRID:
+            return (self.attn_window is not None
+                    or "att" not in map(HybridConfig.mixer,
+                                        self.hybrid.pattern))
+        return self.family == Family.SSM
 
     @property
     def has_encoder(self) -> bool:
@@ -185,22 +217,40 @@ class ModelConfig:
         elif self.family == Family.HYBRID:
             h = self.hybrid
             assert h is not None
-            w = h.lru_width or d
-            rec_layer = (
-                2 * d * w                      # in_proj x + gate branches
-                + h.conv_width * w             # temporal conv
-                + 2 * w * w // 8               # RG-LRU input/recurrence gates (block-diag, 8 heads)
-                + w                            # LRU decay params
-                + w * d                        # out_proj
-            )
-            att_layer = attn_params()
-            n_rec = sum(1 for i in range(self.n_layers)
-                        if h.pattern[i % len(h.pattern)] == "rec")
-            n_att = self.n_layers - n_rec
-            per_mlp = mlp_params(self.d_ff) + norms
-            total = active = (
-                n_rec * (rec_layer + per_mlp) + n_att * (att_layer + per_mlp)
-            )
+
+            def mixer_params(mixer: str) -> float:
+                if mixer == "att":
+                    return attn_params()
+                if mixer == "rec":
+                    w = h.lru_width or d
+                    return (2 * d * w                  # in_proj x + gate branches
+                            + h.conv_width * w + w     # temporal conv + bias
+                            + 2 * w * w // 8           # RG-LRU gates (block-diag, 8 heads)
+                            + w                        # LRU decay params
+                            + w * d)                   # out_proj
+                s = self.ssm
+                d_in = s.expand * d
+                dt_rank = s.dt_rank or -(-d // 16)
+                return (d * 2 * d_in                     # in_proj (x and gate)
+                        + (s.conv_width + 1) * d_in      # depthwise conv + bias
+                        + d_in * (dt_rank + 2 * s.state_dim)  # x -> dt,B,C
+                        + (dt_rank + 1) * d_in           # dt_proj + bias
+                        + d_in * s.state_dim + d_in      # A, D
+                        + d_in * d                       # out_proj
+                        + dt_rank + 2 * s.state_dim)     # dt/B/C norms
+
+            def ffn_params(moe: bool, active: bool) -> float:
+                if not moe:
+                    return mlp_params(self.d_ff)
+                m = self.moe
+                n = m.top_k if active else m.n_experts
+                return d * m.n_experts + n * mlp_params(m.d_ff_expert)
+
+            for i in range(self.n_layers):
+                kind = h.pattern[i % len(h.pattern)]
+                block = mixer_params(h.mixer(kind)) + norms
+                total += block + ffn_params(h.is_moe(kind), False)
+                active += block + ffn_params(h.is_moe(kind), True)
         else:  # pragma: no cover
             raise ValueError(self.family)
 

@@ -65,7 +65,8 @@ def norm_apply(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 
 def rms_head_norm(scale: jax.Array, x: jax.Array, eps: float) -> jax.Array:
-    """Per-head RMS norm over the trailing head_dim (qwen3 qk_norm)."""
+    """RMS norm with a learned scale over the trailing dim (qwen3's per-head
+    qk_norm; Jamba's dt, B and C norms)."""
     xf = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     return (xf * lax.rsqrt(ms + eps) * scale.astype(jnp.float32)).astype(x.dtype)
@@ -458,7 +459,8 @@ def moe_apply(
     """Token-choice top-k MoE.  x: (B, S, D) -> (y, aux_loss).
 
     impl="gmm": sort tokens by expert and run grouped matmuls via
-    ``lax.ragged_dot`` (the TPU megablox-style dataflow).
+    ``lax.ragged_dot`` (the TPU megablox-style dataflow), expert-parallel
+    under a mesh whose ``model`` axis divides the experts (``_moe_gmm``).
     impl="dense": run every expert on every token (tiny smoke tests only).
     """
     m = cfg.moe
@@ -472,7 +474,8 @@ def moe_apply(
     logits = jnp.einsum("td,de->te", xt, p["router"].astype(cd)).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = lax.top_k(probs, k)                     # (T, k)
-    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if m.renormalize:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
 
     # load-balancing aux loss (Switch-style)
     density = jnp.mean(jax.nn.one_hot(idx[:, 0], E, dtype=jnp.float32), axis=0)
@@ -494,17 +497,7 @@ def moe_apply(
     elif m.impl == "capacity":
         y = _moe_capacity(p, cfg, xt, gates, idx, act)
     else:
-        flat_e = idx.reshape(-1)                          # (T*k,)
-        order = jnp.argsort(flat_e)                       # stable
-        token_of = order // k
-        xs = jnp.take(xt, token_of, axis=0)               # (T*k, D) grouped
-        group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
-        h_g = lax.ragged_dot(xs, p["w_gate"].astype(cd), group_sizes)
-        h_u = lax.ragged_dot(xs, p["w_up"].astype(cd), group_sizes)
-        h = act(h_g) * h_u
-        out = lax.ragged_dot(h, p["w_down"].astype(cd), group_sizes)  # (T*k, D)
-        w = jnp.take(gates.reshape(-1), order, axis=0).astype(cd)[:, None]
-        y = jnp.zeros((T, D), cd).at[token_of].add(out * w)
+        y = _moe_gmm(p, xt, gates, idx, act)
 
     if m.n_shared_experts:
         sh = p["shared"]
@@ -517,6 +510,67 @@ def moe_apply(
         y = y + ys * sg
 
     return y.reshape(B, S, D), aux
+
+
+def _gmm_rows(xt, gates, idx, w_gate, w_up, w_down, act, e0=0):
+    """Grouped matmuls (``lax.ragged_dot``) over the experts held here,
+    ``e0`` onward: each (token, choice) routed to one of them is a row,
+    sorted by expert; the rows of experts held elsewhere sort last and add
+    nothing.  -> (T, D), the part of the layer these experts give."""
+    T, D = xt.shape
+    n_held, k = w_gate.shape[0], idx.shape[1]
+    cd = xt.dtype
+    local = idx.reshape(-1) - e0                          # (T*k,)
+    held = (local >= 0) & (local < n_held)
+    order = jnp.argsort(jnp.where(held, local, n_held))   # stable
+    token_of = order // k
+    xs = jnp.take(xt, token_of, axis=0)                   # (T*k, D) grouped
+    group_sizes = jnp.bincount(jnp.where(held, local, n_held),
+                               length=n_held + 1)[:n_held].astype(jnp.int32)
+    h_g = lax.ragged_dot(xs, w_gate.astype(cd), group_sizes)
+    h_u = lax.ragged_dot(xs, w_up.astype(cd), group_sizes)
+    out = lax.ragged_dot(act(h_g) * h_u, w_down.astype(cd), group_sizes)
+    w = jnp.take(jnp.where(held, gates.reshape(-1), 0.0), order, axis=0)
+    out = jnp.where(jnp.take(held, order)[:, None], out * w.astype(cd)[:, None],
+                    0)
+    return jnp.zeros((T, D), cd).at[token_of].add(out)
+
+
+def _moe_gmm(p: Params, xt: jax.Array, gates: jax.Array, idx: jax.Array,
+             act) -> jax.Array:
+    """Token-choice MoE on grouped matmuls.
+
+    Under a launcher's mesh whose ``model`` axis divides the experts, the
+    layer is expert-parallel: each device holds its experts' weights,
+    routes its data shard's tokens over all experts, computes what its own
+    experts give, and one psum over ``model`` adds the parts (the
+    partitioner would otherwise gather every expert's weights onto every
+    device).  Elsewhere every expert is local."""
+    from repro.distributed import ctx as _ctx
+
+    E = p["w_gate"].shape[0]
+    shmap = _ctx.shmap_info()
+    if shmap is not None:
+        dp_axes, tp_axis, mesh = shmap
+        n_dp = math.prod(mesh.shape[a] for a in dp_axes)
+        if E % mesh.shape[tp_axis] == 0 and xt.shape[0] % n_dp == 0:
+            from jax.sharding import PartitionSpec as P
+
+            dp = dp_axes if len(dp_axes) > 1 else dp_axes[0]
+
+            def kernel(x, g, i, w1, w2, w3):
+                e0 = lax.axis_index(tp_axis) * w1.shape[0]
+                return lax.psum(_gmm_rows(x, g, i, w1, w2, w3, act, e0),
+                                tp_axis)
+
+            tokens, experts = P(dp, None), P(tp_axis, None, None)
+            return jax.shard_map(
+                kernel, mesh=mesh,
+                in_specs=(tokens, tokens, tokens, experts, experts, experts),
+                out_specs=tokens, check_vma=False,
+            )(xt, gates, idx, p["w_gate"], p["w_up"], p["w_down"])
+    return _gmm_rows(xt, gates, idx, p["w_gate"], p["w_up"], p["w_down"],
+                     act)
 
 
 def _moe_capacity(p: Params, cfg: ModelConfig, xt: jax.Array,
@@ -736,8 +790,13 @@ def rglru_apply(
 
 
 # --------------------------------------------------------------------------- #
-# Mamba-1 block (falcon-mamba)
+# Mamba-1 block (falcon-mamba; Jamba's adds learned RMSNorms on dt, B and C)
 # --------------------------------------------------------------------------- #
+
+
+def _dbc_norm(cfg: ModelConfig) -> bool:
+    """A hybrid's Mamba blocks carry the learned dt/B/C norms."""
+    return cfg.hybrid is not None
 
 
 def mamba_init(key, cfg: ModelConfig) -> Tuple[Params, Axes]:
@@ -774,6 +833,13 @@ def mamba_init(key, cfg: ModelConfig) -> Tuple[Params, Axes]:
         "D": ("mlp",),
         "w_out": ("mlp", "embed"),
     }
+    if _dbc_norm(cfg):
+        p["dt_norm"] = jnp.ones((dt_rank,), dt)
+        p["b_norm"] = jnp.ones((n,), dt)
+        p["c_norm"] = jnp.ones((n,), dt)
+        a["dt_norm"] = (None,)
+        a["b_norm"] = ("state",)
+        a["c_norm"] = ("state",)
     return p, a
 
 
@@ -860,6 +926,10 @@ def mamba_apply(
 
     dbc = jnp.einsum("bse,en->bsn", xi, p["w_xdbc"].astype(cd))
     dt_in, Bm, Cm = jnp.split(dbc, [dt_rank, dt_rank + n], axis=-1)
+    if _dbc_norm(cfg):
+        dt_in = rms_head_norm(p["dt_norm"], dt_in, cfg.norm_eps)
+        Bm = rms_head_norm(p["b_norm"], Bm, cfg.norm_eps)
+        Cm = rms_head_norm(p["c_norm"], Cm, cfg.norm_eps)
 
     A = -jnp.exp(p["A_log"].astype(jnp.float32))          # (Din, N)
     h0 = state["ssm"] if state is not None else jnp.zeros((B, d_in, n), jnp.float32)

@@ -17,6 +17,8 @@ Layers are stacked along a leading "layers" axis and iterated with
 
 from __future__ import annotations
 
+import collections
+import dataclasses
 import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -148,25 +150,91 @@ def _ssm_block_apply(bp, cfg, x, *, state=None):
     return constrain(x + h, "acts"), new_state, 0.0
 
 
-def _rec_block_init(key, cfg: ModelConfig):
+#: Param key of each hybrid mixer, and its named scope.
+_MIXER_KEY = {"rec": "rec", "att": "attn", "mamba": "mamba"}
+_MIXER_SCOPE = {"rec": "rglru", "att": "attention", "mamba": "mamba"}
+
+
+def _hybrid_block_init(key, cfg: ModelConfig, kind: str):
+    """One hybrid block: ``kind`` is "<mixer>" or "<mixer>+moe"."""
+    h = cfg.hybrid
+    mixer = h.mixer(kind)
     ks = jax.random.split(key, 4)
-    rp, ra = L.rglru_init(ks[0], cfg)
-    mp, ma = L.mlp_init(ks[1], cfg)
+    mixer_init = {"rec": L.rglru_init, "att": L.attn_init,
+                  "mamba": L.mamba_init}[mixer]
+    xp, xa = mixer_init(ks[0], cfg)
+    ffn = "moe" if h.is_moe(kind) else "mlp"
+    fp, fa = (L.moe_init if ffn == "moe" else L.mlp_init)(ks[1], cfg)
     n1p, n1a = L.norm_init(cfg)
     n2p, n2a = L.norm_init(cfg)
     return (
-        {"rec": rp, "mlp": mp, "ln1": n1p, "ln2": n2p},
-        {"rec": ra, "mlp": ma, "ln1": n1a, "ln2": n2a},
+        {_MIXER_KEY[mixer]: xp, ffn: fp, "ln1": n1p, "ln2": n2p},
+        {_MIXER_KEY[mixer]: xa, ffn: fa, "ln1": n1a, "ln2": n2a},
     )
 
 
-def _rec_block_apply(bp, cfg, x, *, state=None):
-    h, new_state = L.rglru_apply(
-        bp["rec"], cfg, L.norm_apply(bp["ln1"], cfg, x), state=state
-    )
-    x = constrain(x + h, "acts")
-    y = L.mlp_apply(bp["mlp"], cfg, L.norm_apply(bp["ln2"], cfg, x))
-    return constrain(x + y, "acts"), new_state, 0.0
+@dataclasses.dataclass(frozen=True)
+class _AttnCtx:
+    """What a hybrid attention block needs besides its params and state.
+
+    ``index`` is None for a full sequence (forward, or prefill when a cache
+    is given) and the cache slot to write for a decode step."""
+
+    rope: Any
+    mask: Any
+    q_pos: jax.Array
+    k_pos: jax.Array
+    index: Any = None
+
+
+def _hybrid_block_apply(bp, cfg: ModelConfig, kind: str, x, state,
+                        ctx: _AttnCtx):
+    """Pre-norm residual block -> (x, new_state, aux).  ``state`` is the
+    mixer's cache entry (None without a cache)."""
+    h = cfg.hybrid
+    mixer = h.mixer(kind)
+    xn = L.norm_apply(bp["ln1"], cfg, x)
+    with jax.named_scope(_MIXER_SCOPE[mixer]):
+        if mixer == "rec":
+            y, new_state = L.rglru_apply(bp["rec"], cfg, xn, state=state)
+        elif mixer == "mamba":
+            y, new_state = L.mamba_apply(bp["mamba"], cfg, xn, state=state,
+                                         scan_chunk=cfg.ssm.scan_chunk)
+        elif ctx.index is not None:   # decode: attend over the cache
+            y, new_state = L.attn_apply(
+                bp["attn"], cfg, xn, rope=ctx.rope, mask=ctx.mask,
+                q_pos=ctx.q_pos, k_pos=ctx.k_pos, cache=state,
+                cache_index=ctx.index)
+        else:
+            y, _ = L.attn_apply(bp["attn"], cfg, xn, rope=ctx.rope,
+                                mask=ctx.mask, q_pos=ctx.q_pos,
+                                k_pos=ctx.q_pos)
+            new_state = None if state is None else _ring_write(
+                bp["attn"], cfg, xn, state, ctx.rope)
+    x = constrain(x + y, "acts")
+    xn = L.norm_apply(bp["ln2"], cfg, x)
+    if h.is_moe(kind):
+        with jax.named_scope("moe"):
+            y, aux = L.moe_apply(bp["moe"], cfg, xn)
+    else:
+        with jax.named_scope("mlp"):
+            y, aux = L.mlp_apply(bp["mlp"], cfg, xn), 0.0
+    return constrain(x + y, "acts"), new_state, aux
+
+
+def _ring_write(p, cfg: ModelConfig, xn, kv, rope):
+    """Prefill: write the last W positions' k/v into a (B, W) cache, slot
+    ``position % W`` (a ring when W is the attention window)."""
+    cd = L.dtype_of(cfg.compute_dtype)
+    k = jnp.einsum("bsd,dhk->bshk", xn.astype(cd), p["wk"].astype(cd))
+    v = jnp.einsum("bsd,dhk->bshk", xn.astype(cd), p["wv"].astype(cd))
+    if rope is not None:
+        k = L.apply_rope(k, *rope, cfg.rope_style)
+    W, S = kv["k"].shape[1], k.shape[1]
+    take = min(W, S)
+    slots = (S - take + jnp.arange(take)) % W
+    return {"k": kv["k"].at[:, slots].set(k[:, -take:].astype(kv["k"].dtype)),
+            "v": kv["v"].at[:, slots].set(v[:, -take:].astype(kv["v"].dtype))}
 
 
 def _xattn_block_init(key, cfg: ModelConfig):
@@ -218,10 +286,48 @@ _BLOCK_INIT = {
 
 
 def hybrid_layout(cfg: ModelConfig) -> Tuple[int, int]:
-    """(n_groups, n_tail_rec) for the hybrid pattern scan."""
-    period = len(cfg.hybrid.pattern)
-    n_groups = cfg.n_layers // period
-    return n_groups, cfg.n_layers - n_groups * period
+    """(n_groups, n_tail) of the hybrid stack: whole periods of the
+    pattern, then the first ``n_tail`` blocks of one more, which must all
+    be of one kind."""
+    pattern = cfg.hybrid.pattern
+    n_groups, n_tail = divmod(cfg.n_layers, len(pattern))
+    if len(set(pattern[:n_tail])) > 1:
+        raise ValueError(f"{cfg.name}: the {n_tail} blocks past the last "
+                         f"whole period mix kinds {pattern[:n_tail]}")
+    return n_groups, n_tail
+
+
+def _hybrid_runs(pattern) -> Tuple[Tuple[str, str, int], ...]:
+    """(key, kind, length) of each run of equal consecutive blocks in the
+    period.  A run is held under its kind when the kind forms one run, else
+    under ``<kind>.<r>`` for its r-th run."""
+    runs = []
+    for kind in pattern:
+        if runs and runs[-1][1] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, kind, 1])
+    n_runs = collections.Counter(kind for _, kind, _ in runs)
+    seen = collections.Counter()
+    for run in runs:
+        if n_runs[run[1]] > 1:
+            run[0] = f"{run[1]}.{seen[run[1]]}"
+        seen[run[1]] += 1
+    return tuple(map(tuple, runs))
+
+
+def _hybrid_group_init(key, cfg: ModelConfig):
+    """One period: each run's blocks stacked (a run of one block is held
+    unstacked), so that no block is ever sliced out of a stack."""
+    runs = _hybrid_runs(cfg.hybrid.pattern)
+    params, axes = {}, {}
+    for k, (name, kind, n) in zip(jax.random.split(key, len(runs)), runs):
+        init = functools.partial(_hybrid_block_init, cfg=cfg, kind=kind)
+        if n == 1:
+            params[name], axes[name] = init(k)
+        else:
+            params[name], axes[name] = _stack_init(init, k, n, abstract=False)
+    return params, axes
 
 
 def init_model(key, cfg: ModelConfig, abstract: bool = False):
@@ -241,21 +347,14 @@ def init_model(key, cfg: ModelConfig, abstract: bool = False):
         )
     elif cfg.family == Family.HYBRID:
         n_groups, n_tail = hybrid_layout(cfg)
-
-        def group_init(k):
-            k1, k2 = jax.random.split(k)
-            rec_p, rec_a = _stack_init(
-                lambda kk: _rec_block_init(kk, cfg), k1, 2, abstract=False
-            )
-            att_p, att_a = _dense_block_init(k2, cfg)
-            return {"rec": rec_p, "att": att_p}, {"rec": rec_a, "att": att_a}
-
         params["groups"], axes["groups"] = _stack_init(
-            group_init, ks[2], n_groups, abstract
+            lambda k: _hybrid_group_init(k, cfg), ks[2], n_groups, abstract
         )
         if n_tail:
+            kind = cfg.hybrid.pattern[0]
             params["tail"], axes["tail"] = _stack_init(
-                lambda k: _rec_block_init(k, cfg), ks[3], n_tail, abstract
+                lambda k: _hybrid_block_init(k, cfg, kind), ks[3], n_tail,
+                abstract
             )
     elif cfg.family == Family.AUDIO:
         params["enc_layers"], axes["enc_layers"] = _stack_init(
@@ -355,6 +454,64 @@ def _scan_blocks_cached(cfg: ModelConfig, stacked, cache, x, body):
     return x, aux, new_cache
 
 
+def _hybrid_group_apply(gp, cfg: ModelConfig, x, cache, ctx: _AttnCtx):
+    """One period of blocks in pattern order -> (x, new_cache, aux); a run
+    of equal blocks is a scan over its stack."""
+    inner = cfg.replace(remat="none")
+    aux, new_cache = 0.0, {}
+    for name, kind, n in _hybrid_runs(cfg.hybrid.pattern):
+        c = None if cache is None else cache[name]
+        if n == 1:
+            x, new_cache[name], a = _hybrid_block_apply(gp[name], cfg, kind,
+                                                        x, c, ctx)
+        else:
+            body = functools.partial(
+                lambda bp, xx, cc, kind: _hybrid_block_apply(
+                    bp, cfg, kind, xx, cc, ctx), kind=kind)
+            x, a, new_cache[name] = _scan_blocks_cached(inner, gp[name], c,
+                                                        x, body)
+        aux = aux + a
+    return x, None if cache is None else new_cache, aux
+
+
+def _hybrid_stack(params, cfg: ModelConfig, x, cache, ctx: _AttnCtx):
+    """The whole hybrid stack: a scan over periods, then the tail.
+    Returns (x, aux, new_cache); the cache is None without one."""
+    body = lambda gp, xx, c: _hybrid_group_apply(gp, cfg, xx, c, ctx)
+    x, aux, groups = _scan_blocks_cached(
+        cfg, params["groups"], None if cache is None else cache["groups"],
+        x, body)
+    new_cache = None if cache is None else {"groups": groups}
+    if "tail" in params:
+        kind = cfg.hybrid.pattern[0]
+        body = lambda bp, xx, c: _hybrid_block_apply(bp, cfg, kind, xx, c, ctx)
+        x, tail_aux, tail = _scan_blocks_cached(
+            cfg, params["tail"], None if cache is None else cache["tail"],
+            x, body)
+        aux = aux + tail_aux
+        if cache is not None:
+            new_cache["tail"] = tail
+    return x, aux, new_cache
+
+
+def _decode_ctx(cfg: ModelConfig, cache, rope, positions, index) -> _AttnCtx:
+    """Key positions and the cache slot of a hybrid decode step: a ring of
+    the last W positions under a window, else one slot per position."""
+    mask = L.MaskSpec(causal=True, window=cfg.attn_window)
+    att = [name for name, kind, _ in _hybrid_runs(cfg.hybrid.pattern)
+           if cfg.hybrid.mixer(kind) == "att"]
+    if not att:
+        return _AttnCtx(rope, mask, positions, None, index)
+    B = positions.shape[0]
+    W = cache["groups"][att[0]]["k"].shape[-3]
+    k_pos = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[None], (B, W))
+    if cfg.attn_window and W <= cfg.attn_window:
+        # slot i holds the latest position p <= index with p % W == i
+        return _AttnCtx(rope, mask, positions,
+                        positions - ((positions - k_pos) % W), index % W)
+    return _AttnCtx(rope, mask, positions, k_pos, index)
+
+
 def forward(
     params: Params,
     cfg: ModelConfig,
@@ -411,65 +568,9 @@ def forward(
             x, aux = _scan_blocks(cfg, params["layers"], x, body)
 
     elif cfg.family == Family.HYBRID:
-        window = cfg.attn_window
-        mask = L.MaskSpec(causal=True, window=window)
-
-        if cache is not None:
-            W = cache["groups"]["att"]["k"].shape[2]
-
-            def group_body(gp, xx, c):
-                def rec_body(bp, xxx, st):
-                    return _rec_block_apply(bp, cfg, xxx, state=st)
-
-                xx, _, new_rec = _scan_blocks_cached(
-                    cfg.replace(remat="none"), gp["rec"], c["rec"], xx, rec_body)
-                # local attention + ring-buffer write of the last W positions
-                xx2, _, _ = _dense_block_apply(gp["att"], cfg, xx,
-                                               rope=rope, mask=mask,
-                                               q_pos=q_pos, k_pos=q_pos)
-                cd = L.dtype_of(cfg.compute_dtype)
-                xn = L.norm_apply(gp["att"]["ln1"], cfg, xx)
-                k = jnp.einsum("bsd,dhk->bshk", xn.astype(cd),
-                               gp["att"]["attn"]["wk"].astype(cd))
-                v = jnp.einsum("bsd,dhk->bshk", xn.astype(cd),
-                               gp["att"]["attn"]["wv"].astype(cd))
-                if rope is not None:
-                    k = L.apply_rope(k, *rope, cfg.rope_style)
-                SS = k.shape[1]
-                take = min(W, SS)
-                pos0 = SS - take
-                slots = (pos0 + jnp.arange(take)) % W
-                new_k = c["att"]["k"].at[:, slots].set(
-                    k[:, -take:].astype(c["att"]["k"].dtype))
-                new_v = c["att"]["v"].at[:, slots].set(
-                    v[:, -take:].astype(c["att"]["v"].dtype))
-                return xx2, {"rec": new_rec,
-                             "att": {"k": new_k, "v": new_v}}, 0.0
-
-            x, aux, new_groups = _scan_blocks_cached(
-                cfg, params["groups"], cache["groups"], x, group_body)
-            new_cache = {"groups": new_groups}
-            if "tail" in params:
-                body = lambda bp, xx, st: _rec_block_apply(bp, cfg, xx, state=st)
-                x, _, new_tail = _scan_blocks_cached(
-                    cfg, params["tail"], cache["tail"], x, body)
-                new_cache["tail"] = new_tail
-        else:
-            def group_body2(gp, xx):
-                def rec_body(bp, xxx):
-                    return _rec_block_apply(bp, cfg, xxx)[::2]
-                xx, _ = _scan_blocks(cfg.replace(remat="none"), gp["rec"],
-                                     xx, rec_body)
-                xx, _, _ = _dense_block_apply(gp["att"], cfg, xx,
-                                              rope=rope, mask=mask,
-                                              q_pos=q_pos, k_pos=q_pos)
-                return xx, 0.0
-
-            x, aux = _scan_blocks(cfg, params["groups"], x, group_body2)
-            if "tail" in params:
-                body = lambda bp, xx: _rec_block_apply(bp, cfg, xx)[::2]
-                x, tail_aux = _scan_blocks(cfg, params["tail"], x, body)
-                aux = aux + tail_aux
+        ctx = _AttnCtx(rope, L.MaskSpec(causal=True, window=cfg.attn_window),
+                       q_pos, q_pos)
+        x, aux, new_cache = _hybrid_stack(params, cfg, x, cache, ctx)
 
     elif cfg.family == Family.AUDIO:
         if "dec_pos" in params:
@@ -578,6 +679,30 @@ _KV_AXES = {"k": ("layers", "batch", None, "kv_heads", "head_dim"),
             "v": ("layers", "batch", None, "kv_heads", "head_dim")}
 
 
+def _hybrid_state(cfg: ModelConfig, kind: str, B: int, W: int, lead):
+    """(cache, axes) of one hybrid block kind, stacked over ``lead``."""
+    h = cfg.hybrid
+    cd = L.dtype_of(cfg.compute_dtype)
+    extra = ("layers",) + (None,) * (len(lead) - 1)
+    mixer = h.mixer(kind)
+    if mixer == "att":
+        shape = lead + (B, W, cfg.n_kv_heads, cfg.head_dim_)
+        return ({"k": jnp.zeros(shape, cd), "v": jnp.zeros(shape, cd)},
+                {k: extra + a[1:] for k, a in _KV_AXES.items()})
+    if mixer == "rec":
+        w = h.lru_width or cfg.d_model
+        return ({"conv": jnp.zeros(lead + (B, h.conv_width - 1, w), cd),
+                 "lru": jnp.zeros(lead + (B, w), jnp.float32)},
+                {"conv": extra + ("batch", None, "mlp"),
+                 "lru": extra + ("batch", "mlp")})
+    s = cfg.ssm
+    d_in = s.expand * cfg.d_model
+    return ({"conv": jnp.zeros(lead + (B, s.conv_width - 1, d_in), cd),
+             "ssm": jnp.zeros(lead + (B, d_in, s.state_dim), jnp.float32)},
+            {"conv": extra + ("batch", None, "mlp"),
+             "ssm": extra + ("batch", "mlp", "state")})
+
+
 def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
                abstract: bool = False):
     """Decode cache + logical axes.  max_len = full context length."""
@@ -602,37 +727,16 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
             return cache, ax
         if cfg.family == Family.HYBRID:
             h = cfg.hybrid
-            w = h.lru_width or cfg.d_model
             n_groups, n_tail = hybrid_layout(cfg)
             W = min(max_len, cfg.attn_window or max_len)
-
-            def rec_state(n_outer, n_inner=None):
-                lead = (n_outer,) if n_inner is None else (n_outer, n_inner)
-                return {
-                    "conv": jnp.zeros(lead + (B, h.conv_width - 1, w),
-                                      L.dtype_of(cfg.compute_dtype)),
-                    "lru": jnp.zeros(lead + (B, w), jnp.float32),
-                }
-
-            def rec_axes(extra):
-                return {"conv": extra + ("batch", None, "mlp"),
-                        "lru": extra + ("batch", "mlp")}
-
-            cache = {
-                "groups": {
-                    "rec": rec_state(n_groups, 2),
-                    "att": _kv_cache_shape(cfg, n_groups, B, W),
-                },
-            }
-            ax = {
-                "groups": {
-                    "rec": rec_axes(("layers", None)),
-                    "att": dict(_KV_AXES),
-                },
-            }
+            groups = {name: _hybrid_state(cfg, kind, B, W, (n_groups,)
+                                          if n == 1 else (n_groups, n))
+                      for name, kind, n in _hybrid_runs(h.pattern)}
+            cache = {"groups": {k: v[0] for k, v in groups.items()}}
+            ax = {"groups": {k: v[1] for k, v in groups.items()}}
             if n_tail:
-                cache["tail"] = rec_state(n_tail)
-                ax["tail"] = rec_axes(("layers",))
+                cache["tail"], ax["tail"] = _hybrid_state(
+                    cfg, h.pattern[0], B, W, (n_tail,))
             return cache, ax
         if cfg.family == Family.AUDIO:
             cache = {
@@ -712,39 +816,8 @@ def decode_step(params: Params, cfg: ModelConfig, cache,
         x, new_cache = _scan_or_unroll(cfg, f, x, (params["layers"], cache))
 
     elif cfg.family == Family.HYBRID:
-        W = cache["groups"]["att"]["k"].shape[2]
-        slot = index % W
-        k_pos = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[None], (B, W))
-        k_pos = positions - ((positions - k_pos) % W)
-        mask = L.MaskSpec(causal=True, window=cfg.attn_window)
-
-        def group_f(xx, xs):
-            gp, c = xs
-
-            def rec_f(xxx, rs):
-                bp, st = rs
-                xxx, new_st, _ = _rec_block_apply(bp, cfg, xxx, state=st)
-                return xxx, new_st
-
-            xx, new_rec = _scan_or_unroll(cfg, rec_f, xx, (gp["rec"], c["rec"]))
-            xx, new_kv, _ = _dense_block_apply(
-                gp["att"], cfg, xx, rope=rope, mask=mask,
-                q_pos=q_pos, k_pos=k_pos,
-                cache=c["att"], index=slot)
-            return xx, {"rec": new_rec, "att": new_kv}
-
-        x, new_groups = _scan_or_unroll(
-            cfg, group_f, x, (params["groups"], cache["groups"]))
-        new_cache = {"groups": new_groups}
-        if "tail" in cache:
-            def rec_f(xx, rs):
-                bp, st = rs
-                xx, new_st, _ = _rec_block_apply(bp, cfg, xx, state=st)
-                return xx, new_st
-
-            x, new_tail = _scan_or_unroll(
-                cfg, rec_f, x, (params["tail"], cache["tail"]))
-            new_cache["tail"] = new_tail
+        x, _, new_cache = _hybrid_stack(
+            params, cfg, x, cache, _decode_ctx(cfg, cache, rope, positions, index))
 
     elif cfg.family == Family.AUDIO:
         if "dec_pos" in params:

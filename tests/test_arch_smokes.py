@@ -1,6 +1,6 @@
 """Per-assigned-architecture smoke tests (deliverable f).
 
-Each of the 10 architectures instantiates its REDUCED same-family config and
+Each of the 11 architectures instantiates its REDUCED same-family config and
 runs one forward + one train step on CPU, asserting output shapes + no NaNs.
 The FULL configs are exercised only via the dry-run (no allocation).
 """

@@ -70,11 +70,17 @@ def test_zoo_full_shapes_fit_production_mesh():
 
 
 def test_suite_name_grammar():
-    assert MZ.parse_suite("zoo") == (False, None)
-    assert MZ.parse_suite("zoo-smoke") == (True, None)
-    assert MZ.parse_suite("zoo:train") == (False, "train")
-    assert MZ.parse_suite("zoo-smoke:serve-decode") == (True, "serve-decode")
-    for bad in ("zoop", "zoo:", "zoo:bogus", "smoke", "zoo-smoke:train:x"):
+    assert MZ.parse_suite("zoo") == (False, None, None)
+    assert MZ.parse_suite("zoo-smoke") == (True, None, None)
+    assert MZ.parse_suite("zoo:train") == (False, "train", None)
+    assert MZ.parse_suite("zoo-smoke:serve-decode") == (
+        True, "serve-decode", None)
+    assert MZ.parse_suite("zoo@jamba2-mini") == (False, None, "jamba2-mini")
+    assert MZ.parse_suite("zoo-smoke@chatglm3-6b:train") == (
+        True, "train", "chatglm3-6b")
+    for bad in ("zoop", "zoo:", "zoo:bogus", "smoke", "zoo-smoke:train:x",
+                "zoo@", "zoo@bogus", "zoop@jamba2-mini",
+                "zoo@jamba2-mini:bogus"):
         with pytest.raises(ValueError):
             MZ.parse_suite(bad)
     # the ONE validation path: CodesignSpec.validate delegates here
@@ -93,6 +99,57 @@ def test_cell_fingerprint_tracks_inputs():
 def test_full_suite_is_cache_only(tmp_path):
     with pytest.raises(RuntimeError, match="model_zoo"):
         MZ.resolve_suite("zoo", cache_dir=str(tmp_path))
+
+
+def test_full_suite_entry_of_older_math_is_stale(tmp_path):
+    """A full-suite entry fingerprinted before ``ZOO_FULL_EXTRACTION_VERSION``
+    (falcon-mamba-7b's config is unchanged since, only its pod-scale scan
+    FLOPs moved) is refused as stale, while the smoke fingerprints keep no
+    such term."""
+    import hashlib
+
+    cell = MZ.zoo_cells(archs=("falcon-mamba-7b",))[0]
+    old_inputs = {
+        "version": MZ.ZOO_EXTRACTION_VERSION, "arch": cell.arch,
+        "scenario": cell.scenario, "smoke": False,
+        "config": repr(cell.config),
+        "shape": dataclasses.asdict(cell.shape)}
+    old = hashlib.sha256(json.dumps(old_inputs, sort_keys=True)
+                         .encode()).hexdigest()[:16]
+    assert old != MZ.cell_fingerprint(cell)
+    smoke = MZ.zoo_cells(archs=("falcon-mamba-7b",), smoke=True)[0]
+    golden = json.load(open(MZ.cache_path(smoke, MZ.SMOKE_CACHE_DIR)))
+    assert golden["meta"]["fingerprint"] == MZ.cell_fingerprint(smoke)
+    golden["meta"]["fingerprint"] = old
+    with open(MZ.cache_path(cell, str(tmp_path)), "w") as f:
+        json.dump(golden, f)
+    with pytest.raises(RuntimeError, match="stale"):
+        MZ.profiles_from_configs(
+            archs=(cell.arch,), scenarios=(cell.scenario,),
+            cache_dir=str(tmp_path), extract_missing=False, max_cells=1)
+
+
+def test_single_arch_suite_reads_checked_in_cells():
+    """``zoo@jamba2-mini`` resolves, cache-only, to the twelve published-
+    width cells checked in under ``zoo_cache/full/``, each fingerprint
+    matching the current config, shape and extraction version."""
+    profiles = MZ.resolve_suite("zoo@jamba2-mini", extract_missing=False)
+    cells = MZ.zoo_cells(archs=("jamba2-mini",))
+    assert len(profiles) == len(cells) == 12
+    for cell, p in zip(cells, profiles):
+        assert os.path.exists(MZ.cache_path(cell, MZ.FULL_CHECKED_IN_DIR))
+        assert p.meta["fingerprint"] == MZ.cell_fingerprint(cell)
+        assert (p.arch, p.shape, p.mesh) == (
+            "jamba2-mini", cell.shape.name, "pod16x16")
+        assert p.meta["variant"] == "fsdp" and p.num_devices == 256
+        assert p.flops > 0 and p.hbm_bytes > 0
+        assert sum(p.collective_bytes.values()) > 0
+    decode = MZ.resolve_suite("zoo@jamba2-mini:serve-decode",
+                              extract_missing=False)
+    assert [p.meta["scenario"] for p in decode] == ["serve-decode"] * 4
+    # the whole full suite still needs every other architecture's cells
+    with pytest.raises(RuntimeError, match="model_zoo"):
+        MZ.resolve_suite("zoo", extract_missing=False)
 
 
 # --------------------------------------------------------------------------- #

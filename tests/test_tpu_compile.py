@@ -73,7 +73,7 @@ def _kernel_ran(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("apps", [6, 128, 1000])
+@pytest.mark.parametrize("apps", [6, 12, 128, 1000])
 def test_pallas_congruence_compiles(one_chip, apps):
     be = PallasBackend(interpret=False)
     tile = _variant_tile(apps, V)
