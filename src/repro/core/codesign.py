@@ -48,6 +48,7 @@ descent and the ``ici_links`` integer relaxation live in
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -133,7 +134,7 @@ def backtracking_descent(
     jax, jnp, theta0, obj_fn: Callable, steps: int, lr: float,
     retract: Callable, aux_fn: Optional[Callable] = None,
     obj_args: Tuple = (), retract_args: Tuple = (),
-    cache: Optional[Dict[str, Callable]] = None,
+    cache: Optional[Dict[tuple, Callable]] = None,
 ) -> Tuple[object, object, List[np.ndarray], List[np.ndarray], object]:
     """Per-variant backtracking line search on ``obj_fn`` (shared by every
     co-design mode).
@@ -148,50 +149,74 @@ def backtracking_descent(
     loops) pass the previous round's adapted rates back in so restarts do
     not re-pay the warm-up.
 
-    ``obj_args`` are extra TRACED positional arguments forwarded to
-    ``obj_fn(theta, *obj_args)``; round-varying state (Lagrange
-    multipliers, selection weights, softmax temperature) belongs there,
-    not in a fresh closure per round.  ``retract_args`` do the same for
+    The whole descent -- the seed's retraction and objective, then
+    ``steps`` iterations of gradient, retraction, objective and the
+    accept/``lr`` update -- is ONE compiled program (a ``lax.scan``), and
+    the host fetches the stacked histories once, after it: no step
+    dispatches or syncs on its own.  ``obj_args`` are extra TRACED
+    positional arguments forwarded to ``obj_fn(theta, *obj_args)``;
+    round-varying state (Lagrange multipliers, selection weights, softmax
+    temperature, the suite itself) belongs there, not in a fresh closure
+    per call.  ``retract_args`` do the same for
     ``retract(theta, *retract_args)`` -- the budget-continuation frontier
     (``repro.core.frontier``) passes the active budget as a traced scalar
-    so ONE compiled projection serves the whole budget sweep.  With a
-    ``cache`` dict (reused across calls WITH THE SAME
-    ``obj_fn``/``retract``), the jitted obj/grad/retract compile once and
-    later rounds retrace only on shape changes.  Returns the final
-    ``theta``, final per-variant objective, the accepted-objective history
-    (seed included), the aux history and the adapted per-variant ``lr``.
+    so ONE compiled loop serves the whole budget sweep.  With a ``cache``
+    dict (reused across calls WITH THE SAME ``obj_fn``/``retract``), the
+    loop compiles once per ``steps`` and aux/no-aux, and later calls
+    retrace only on shape changes.  Returns the final ``theta``, final
+    per-variant objective, the accepted-objective history (seed included,
+    ``steps + 1`` rows of ``(V,)``), the aux history (as many rows, empty
+    without ``aux_fn``) and the adapted per-variant ``lr``.
     """
     cache = {} if cache is None else cache
-    if "obj" not in cache:
-        cache["obj"] = jax.jit(obj_fn)
-        cache["grad"] = jax.jit(jax.grad(
-            lambda th, *a: jnp.sum(obj_fn(th, *a))))
-        cache["retract"] = jax.jit(retract)
-        cache["aux"] = jax.jit(aux_fn) if aux_fn is not None else None
-    obj_j, grad_j = cache["obj"], cache["grad"]
-    retract_j, aux_j = cache["retract"], cache["aux"]
+    key = (steps, aux_fn is not None)
+    if key not in cache:
+        cache[key] = jax.jit(_descent_loop(
+            jax, jnp, obj_fn, retract, aux_fn, steps))
+    # One (V,) shape for every ``lr``, so a scalar and a carried-over
+    # per-variant rate share the compiled loop.
+    lr_v = np.broadcast_to(np.asarray(lr, dtype=theta0.dtype),
+                           (theta0.shape[0],))
+    with span("descent.step"):
+        theta, f_cur, hist, aux, lr_v = cache[key](
+            theta0, lr_v, obj_args, retract_args)
+        with span("descent.sync"):
+            hist, aux = jax.device_get((hist, aux))
+    return theta, f_cur, list(hist), [] if aux is None else list(aux), lr_v
 
-    theta = retract_j(theta0, *retract_args)
-    f_cur = obj_j(theta, *obj_args)
-    lr_v = jnp.broadcast_to(jnp.asarray(lr, dtype=theta.dtype),
-                            (theta.shape[0],))
-    with span("descent.sync"):
-        history = [np.asarray(f_cur)]
-        aux = [] if aux_j is None else [np.asarray(aux_j(theta))]
-    for _ in range(steps):
-        with span("descent.step"):
-            g = grad_j(theta, *obj_args)
-            cand = retract_j(theta - lr_v[:, None] * g, *retract_args)
-            f_new = obj_j(cand, *obj_args)
+
+def _descent_loop(jax, jnp, obj_fn, retract, aux_fn, steps: int) -> Callable:
+    """The traceable body of ``backtracking_descent``: a backtracking
+    step ``scan``ned ``steps`` times, emitting each step's accepted
+    objective (and ``aux_fn``) for the histories."""
+    grad = jax.grad(lambda th, *a: jnp.sum(obj_fn(th, *a)))
+
+    def loop(theta0, lr_v, obj_args, retract_args):
+        def record(theta, f_cur):
+            return f_cur if aux_fn is None else (f_cur, aux_fn(theta))
+
+        def step(carry, _):
+            theta, f_cur, lr_v = carry
+            g = grad(theta, *obj_args)
+            cand = retract(theta - lr_v[:, None] * g, *retract_args)
+            f_new = obj_fn(cand, *obj_args)
             ok = f_new < f_cur
             theta = jnp.where(ok[:, None], cand, theta)
             f_cur = jnp.where(ok, f_new, f_cur)
             lr_v = jnp.where(ok, lr_v * 1.2, lr_v * 0.5)
-            with span("descent.sync"):
-                history.append(np.asarray(f_cur))
-                if aux_j is not None:
-                    aux.append(np.asarray(aux_j(theta)))
-    return theta, f_cur, history, aux, lr_v
+            return (theta, f_cur, lr_v), record(theta, f_cur)
+
+        theta = retract(theta0, *retract_args)
+        f_cur = obj_fn(theta, *obj_args)
+        first = record(theta, f_cur)
+        (theta, f_cur, lr_v), rest = jax.lax.scan(
+            step, (theta, f_cur, lr_v), None, length=steps)
+        hist = jax.tree.map(lambda a, b: jnp.concatenate([a[None], b]),
+                            first, rest)
+        f_hist, aux_hist = (hist, None) if aux_fn is None else hist
+        return theta, f_cur, f_hist, aux_hist, lr_v
+
+    return loop
 
 
 @dataclasses.dataclass
@@ -429,6 +454,22 @@ def scalarized_objective(
                                 w_area, w_power)
 
 
+#: ``backtracking_descent`` caches of ``grad_codesign``, one per setting
+#: that is static in its loop.  The suite, the seeds' fixed machine
+#: constants, beta and the clip box are traced arguments, so a later solve
+#: on any suite of the same shapes reuses the compiled loop.
+_SOLVE_CACHES: Dict[tuple, dict] = {}
+
+
+def _suite_objective(xp, theta, p: K.ProfileArrays, fixed: K.MachineArrays,
+                     beta, *, timing_model: str, eps: float,
+                     cost_model: CostModel, w_area: float, w_power: float):
+    """J per variant at log-rates ``theta`` over the suite ``p``."""
+    m = machine_arrays_from_theta(xp, theta, fixed)
+    return _objective_terms(xp, p, m, beta, timing_model, eps, cost_model,
+                            w_area, w_power)
+
+
 @spanned("codesign")
 def grad_codesign(
     profiles,
@@ -490,22 +531,24 @@ def grad_codesign(
     beta_np = resolve_beta(pb, mb, beta, beta_ref)
     theta0, lo, hi = theta_box(mb, span)
 
+    # The CostModel holds dicts, so it keys by its repr.  The kernel
+    # function is in the key too: a replaced ``_objective_terms`` traces a
+    # loop of its own.
+    cache = _SOLVE_CACHES.setdefault(
+        (_objective_terms, timing_model, eps, repr(cost_model), w_area,
+         w_power), {})
+    objective = functools.partial(
+        _suite_objective, jnp, timing_model=timing_model, eps=eps,
+        cost_model=cost_model, w_area=w_area, w_power=w_power)
+    # float64 NumPy arrays: the compiled loop's call transfers them all.
+    host = K.get_backend("numpy")
+    suite = (host.profile_arrays(pb.arrays()), host.machine_arrays(fixed_np),
+             beta_np)
     with backend._x64():
-        p_arrays = backend.profile_arrays(pb.arrays())
-        fixed = backend.machine_arrays(fixed_np)
-        beta_j = backend.asarray(beta_np)
-        lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
-
-        def per_variant(theta):
-            m = machine_arrays_from_theta(jnp, theta, fixed)
-            return _objective_terms(jnp, p_arrays, m, beta_j, timing_model,
-                                    eps, cost_model, w_area, w_power)
-
         theta, f_cur, history, _, _ = backtracking_descent(
-            jax, jnp, backend.asarray(theta0), per_variant, steps, lr,
-            retract=lambda th: jnp.clip(th, lo_j, hi_j))
-        theta_np = backend.to_numpy(theta)
-        f_final = backend.to_numpy(f_cur)
+            jax, jnp, theta0, objective, steps, lr, retract=jnp.clip,
+            obj_args=suite, retract_args=(lo, hi), cache=cache)
+        theta_np, f_final = jax.device_get((theta, f_cur))
 
     final_m = machine_arrays_from_theta(np, theta_np, fixed_np)
     return CodesignResult(

@@ -20,9 +20,9 @@ run by warm-started continuation:
     projected step from the optimum under the next-looser budget;
   * the active budget enters the jitted retraction as a TRACED scalar
     (``backtracking_descent``'s ``retract_args``), so the whole sweep
-    shares one compiled objective/gradient/projection -- continuation
-    pays n small descents and ONE compile, where n cold runs would pay n
-    full descents.
+    shares the compiled descent loops (one for the full descent, one for
+    the refinements) -- continuation pays n small descents and two
+    compiles, where n cold runs would pay n full descents.
 
 Monotonicity is enforced BY CONSTRUCTION, not hoped for: the feasible
 sets are nested (``b <= b'`` implies ``S(b) ⊆ S(b')``), so any machine

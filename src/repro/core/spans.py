@@ -17,8 +17,9 @@ to the program's own layer boundaries:
   repro.pareto         the 2-D and 3-D Pareto filters
   repro.rescore        shard_sweep's re-score of the survivors
   repro.codesign       one grad_codesign solve
-  repro.descent.step   one backtracking step (every co-design mode);
-  repro.descent.sync   the host syncs of that step
+  repro.descent.step   one compiled descent loop, all its steps, and the
+                       fetch of its histories (every co-design mode)
+  repro.descent.sync   that fetch, the descent's one host sync
 
 One ``jax.monitoring`` listener, installed once the process has loaded
 jax, counts jax's own tracing and compilation events and marks each with

@@ -2,6 +2,8 @@
 improve the scalarized (congruence, area, power) objective from the named
 variant seeds on the synthetic profile suite (the ISSUE acceptance gate)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -180,6 +182,183 @@ def test_grad_codesign_reports_final_silicon(result):
     assert result.feasible is None and result.violation_trace is None
     assert result.feasibility_report() == {
         "constrained": False, "mode": "unconstrained"}
+
+
+# --------------------------------------------------------------------------- #
+# The compiled descent against the step-by-step loop it replaced
+# --------------------------------------------------------------------------- #
+
+
+def eager_descent(jax, jnp, theta0, obj_fn, steps, lr, retract, aux_fn=None,
+                  obj_args=(), retract_args=()):
+    """The descent as one host loop: three jitted calls, the ``where``
+    updates and one sync a step -- the reference for the compiled loop."""
+    obj_j = jax.jit(obj_fn)
+    grad_j = jax.jit(jax.grad(lambda th, *a: jnp.sum(obj_fn(th, *a))))
+    retract_j = jax.jit(retract)
+    aux_j = jax.jit(aux_fn) if aux_fn is not None else None
+    theta = retract_j(theta0, *retract_args)
+    f_cur = obj_j(theta, *obj_args)
+    lr_v = jnp.broadcast_to(jnp.asarray(lr, dtype=theta.dtype),
+                            (theta.shape[0],))
+    history = [np.asarray(f_cur)]
+    aux = [] if aux_j is None else [np.asarray(aux_j(theta))]
+    for _ in range(steps):
+        g = grad_j(theta, *obj_args)
+        cand = retract_j(theta - lr_v[:, None] * g, *retract_args)
+        f_new = obj_j(cand, *obj_args)
+        ok = f_new < f_cur
+        theta = jnp.where(ok[:, None], cand, theta)
+        f_cur = jnp.where(ok, f_new, f_cur)
+        lr_v = jnp.where(ok, lr_v * 1.2, lr_v * 0.5)
+        history.append(np.asarray(f_cur))
+        if aux_j is not None:
+            aux.append(np.asarray(aux_j(theta)))
+    return theta, f_cur, history, aux, lr_v
+
+
+def descent_problem(mode):
+    """The inputs ``grad_codesign`` descends (``"clip"``: the span box, a
+    scalar ``lr``), or a projected budget descent (``"projected"``: the
+    budget a traced retraction argument, the violation as ``aux_fn``, a
+    ``(V,)`` ``lr``)."""
+    from repro.core.codesign import (
+        _as_batches,
+        _suite_objective,
+        machine_arrays_from_theta,
+        resolve_beta,
+        theta_box,
+    )
+    from repro.core.constrained import budget_violation, project_to_budgets
+    from repro.core.costmodel import DEFAULT_COST_MODEL
+    from repro.core.kernels_xp import IDEAL_EPS, get_backend
+
+    backend = get_backend("jax")
+    jax, jnp = backend._jax, backend._jnp
+    pb, mb = _as_batches(random_profiles(5, seed=61),
+                         MachineBatch.from_models(VARIANTS))
+    theta0, lo, hi = theta_box(mb, 16.0)
+    with backend._x64():
+        fixed = backend.machine_arrays(mb.arrays())
+        lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
+        suite = (backend.profile_arrays(pb.arrays()), fixed,
+                 backend.asarray(resolve_beta(pb, mb, None, 0)))
+        objective = functools.partial(
+            _suite_objective, jnp, timing_model="serial", eps=IDEAL_EPS,
+            cost_model=DEFAULT_COST_MODEL, w_area=0.1, w_power=0.05)
+        if mode == "clip":
+            return dict(theta0=backend.asarray(theta0), obj_fn=objective,
+                        lr=0.1, retract=jnp.clip, obj_args=suite,
+                        retract_args=(lo_j, hi_j))
+
+        def project(theta, budget):
+            out, _ = project_to_budgets(jnp, theta, lo_j, hi_j, fixed,
+                                        DEFAULT_COST_MODEL, budget, None)
+            return out
+
+        def violation(theta):
+            m = machine_arrays_from_theta(jnp, theta, fixed)
+            return budget_violation(jnp, m, DEFAULT_COST_MODEL, 1.5, None)
+
+        return dict(theta0=backend.asarray(theta0), obj_fn=objective,
+                    lr=backend.asarray(np.linspace(0.05, 0.2, len(mb))),
+                    retract=project, aux_fn=violation, obj_args=suite,
+                    retract_args=(backend.asarray(1.5),))
+
+
+@pytest.mark.parametrize("mode,steps", [("clip", 40), ("projected", 40),
+                                        ("clip", 0), ("projected", 0)])
+def test_compiled_descent_matches_the_step_loop(mode, steps):
+    """The compiled descent accepts the same steps as the step-by-step
+    loop over the first 10, and its histories agree to 1e-12 relative;
+    ``steps=0`` returns the seed's row alone."""
+    from repro.core.codesign import backtracking_descent
+    from repro.core.kernels_xp import get_backend
+
+    backend = get_backend("jax")
+    jax, jnp = backend._jax, backend._jnp
+    problem = descent_problem(mode)
+    with backend._x64():
+        theta0 = problem.pop("theta0")
+        obj_fn = problem.pop("obj_fn")
+        got = backtracking_descent(jax, jnp, theta0, obj_fn, steps, **problem)
+        want = eager_descent(jax, jnp, theta0, obj_fn, steps, **problem)
+    v = theta0.shape[0]
+    hist, want_hist = np.stack(got[2]), np.stack(want[2])
+    assert hist.shape == (steps + 1, v)
+    aux_rows = steps + 1 if mode == "projected" else 0
+    assert len(got[3]) == len(want[3]) == aux_rows
+    if aux_rows:
+        assert np.stack(got[3]).shape == (steps + 1, v)
+        np.testing.assert_allclose(np.stack(got[3]), np.stack(want[3]),
+                                   rtol=1e-12, atol=0)
+    np.testing.assert_array_equal(np.diff(hist[:11], axis=0) < 0,
+                                  np.diff(want_hist[:11], axis=0) < 0)
+    np.testing.assert_allclose(hist, want_hist, rtol=1e-12, atol=0)
+    for a, b in ((got[0], want[0]), (got[1], want[1]), (got[4], want[4])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-12)
+    if steps:
+        assert np.any(np.diff(hist, axis=0) < 0)   # the descent did move
+
+
+def test_grad_codesign_matches_the_step_loop():
+    """``grad_codesign``'s trajectory is the step-by-step loop's on the
+    objective it descended before the suite became an argument: a closure
+    over the suite and the clip box."""
+    from repro.core.codesign import (
+        _as_batches,
+        _objective_terms,
+        machine_arrays_from_theta,
+        resolve_beta,
+        theta_box,
+    )
+    from repro.core.costmodel import DEFAULT_COST_MODEL
+    from repro.core.kernels_xp import IDEAL_EPS, get_backend
+
+    profiles = random_profiles(5, seed=67)
+    seeds = MachineBatch.from_models(VARIANTS)
+    res = grad_codesign(profiles, seeds, steps=30)
+    backend = get_backend("jax")
+    jax, jnp = backend._jax, backend._jnp
+    pb, mb = _as_batches(profiles, seeds)
+    theta0, lo, hi = theta_box(mb, 16.0)
+    with backend._x64():
+        p_arrays = backend.profile_arrays(pb.arrays())
+        fixed = backend.machine_arrays(mb.arrays())
+        beta_j = backend.asarray(resolve_beta(pb, mb, None, 0))
+        lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
+
+        def per_variant(theta):
+            m = machine_arrays_from_theta(jnp, theta, fixed)
+            return _objective_terms(jnp, p_arrays, m, beta_j, "serial",
+                                    IDEAL_EPS, DEFAULT_COST_MODEL, 0.1, 0.05)
+
+        _, f_cur, history, _, _ = eager_descent(
+            jax, jnp, backend.asarray(theta0), per_variant, 30, 0.1,
+            retract=lambda th: jnp.clip(th, lo_j, hi_j))
+    want = np.stack(history)
+    np.testing.assert_array_equal(np.diff(res.trajectory[:11], axis=0) < 0,
+                                  np.diff(want[:11], axis=0) < 0)
+    np.testing.assert_allclose(res.trajectory, want, rtol=1e-12, atol=0)
+    np.testing.assert_allclose(res.objective_final, np.asarray(f_cur),
+                               rtol=1e-12)
+
+
+def test_second_solve_on_a_new_suite_traces_nothing():
+    """The suite is an argument of the compiled descent, not a constant in
+    it: a solve on a new generated suite of the same size reuses the first
+    solve's program and traces nothing."""
+    from repro.core import spans
+    from repro.core.model_zoo import resolve_suite
+
+    seeds = MachineBatch.from_models(VARIANTS)
+    first, second = (list(resolve_suite(f"gen:16:seed={s}:mode=halton"))
+                     for s in (5, 6))
+    a = grad_codesign(first, seeds, steps=8)
+    before = spans.counters()["retrace"]
+    b = grad_codesign(second, seeds, steps=8)
+    assert spans.counters()["retrace"] == before
+    assert not np.allclose(a.trajectory, b.trajectory)
 
 
 # --------------------------------------------------------------------------- #
