@@ -39,17 +39,20 @@ Entry points:
                           ``CodesignResult`` with per-variant trajectories
                           and the optimized ``MachineModel`` designs.
 
-Constrained descent (area/power budgets), joint machine+sharding-variant
-descent and the ``ici_links`` integer relaxation live in
-``repro.core.constrained`` and reuse this module's descent machinery;
-``docs/codesign.md`` is the worked optimization guide.
+Every co-design mode -- this one, the budgeted, joint, frontier, packing
+and implicit modes in ``repro.core.constrained``, ``frontier``,
+``packing`` and ``implicit`` -- builds its problem with ``descent_suite``,
+descends ``_suite_objective`` (or a module-level function over it) and
+runs the one compiled loop, ``backtracking_descent``, drawing on the
+module-level cache of its static setting; ``docs/codesign.md`` is the
+worked optimization guide.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import TYPE_CHECKING, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -58,15 +61,13 @@ from repro.core.costmodel import DEFAULT_COST_MODEL, CostModel
 from repro.core.machine import MachineModel
 from repro.core.spans import span, spanned
 
+if TYPE_CHECKING:
+    from repro.core.sweep import MachineBatch, ProfileBatch
+
 #: The machine constants the gradient may move, in theta column order.
 #: ``repro.core.constrained`` appends a 5th column, ``log(ici_links)``,
 #: when the integer relaxation is enabled.
 OPT_FIELDS = ("peak_flops", "hbm_bw", "ici_bw", "inter_pod_bw")
-
-
-def _as_batches(profiles, machines):
-    from repro.core.sweep import _as_machine_batch, _as_profile_batch
-    return _as_profile_batch(profiles), _as_machine_batch(machines)
 
 
 def machine_arrays_from_theta(xp, theta, fixed: K.MachineArrays) -> K.MachineArrays:
@@ -143,8 +144,9 @@ def backtracking_descent(
     (the span-clip box for unconstrained descent, the budget projection of
     ``repro.core.constrained`` for projected-gradient mode); it is applied
     AFTER the gradient step, so accepted iterates are always feasible.
-    ``aux_fn(theta) -> (V,)`` optionally records a per-step diagnostic
-    (the constraint-violation trace).  ``lr`` may be a scalar or a ``(V,)``
+    ``aux_fn(theta, *retract_args) -> (V,)`` optionally records a
+    per-step diagnostic of the set the retraction enforces (the
+    constraint-violation trace).  ``lr`` may be a scalar or a ``(V,)``
     per-variant array -- multi-round callers (the joint/Lagrangian outer
     loops) pass the previous round's adapted rates back in so restarts do
     not re-pay the warm-up.
@@ -160,15 +162,19 @@ def backtracking_descent(
     per call.  ``retract_args`` do the same for
     ``retract(theta, *retract_args)`` -- the budget-continuation frontier
     (``repro.core.frontier``) passes the active budget as a traced scalar
-    so ONE compiled loop serves the whole budget sweep.  With a ``cache``
-    dict (reused across calls WITH THE SAME ``obj_fn``/``retract``), the
-    loop compiles once per ``steps`` and aux/no-aux, and later calls
-    retrace only on shape changes.  Returns the final ``theta``, final
-    per-variant objective, the accepted-objective history (seed included,
-    ``steps + 1`` rows of ``(V,)``), the aux history (as many rows, empty
-    without ``aux_fn``) and the adapted per-variant ``lr``.
+    so ONE compiled loop serves the whole budget sweep.  The loop compiles
+    once per ``steps`` and aux/no-aux into ``cache``, by default the
+    module-level cache of the functions' static setting (``_static_key``):
+    given module-level functions, or partials of them that bind only
+    static settings, a later solve on a new suite of the same shapes
+    traces nothing.  Returns the final ``theta``, final per-variant
+    objective, the accepted-objective history (seed included, ``steps +
+    1`` rows of ``(V,)``), the aux history (as many rows, empty without
+    ``aux_fn``) and the adapted per-variant ``lr``.
     """
-    cache = {} if cache is None else cache
+    if cache is None:
+        cache = _SOLVE_CACHES.setdefault(
+            _static_key(obj_fn, retract, aux_fn), {})
     key = (steps, aux_fn is not None)
     if key not in cache:
         cache[key] = jax.jit(_descent_loop(
@@ -193,7 +199,9 @@ def _descent_loop(jax, jnp, obj_fn, retract, aux_fn, steps: int) -> Callable:
 
     def loop(theta0, lr_v, obj_args, retract_args):
         def record(theta, f_cur):
-            return f_cur if aux_fn is None else (f_cur, aux_fn(theta))
+            if aux_fn is None:
+                return f_cur
+            return f_cur, aux_fn(theta, *retract_args)
 
         def step(carry, _):
             theta, f_cur, lr_v = carry
@@ -416,17 +424,6 @@ def params_of_theta(theta_row: np.ndarray, fixed_np: K.MachineArrays,
     return d
 
 
-def resolve_beta(pb, mb, beta, beta_ref: int) -> np.ndarray:
-    """The codesign beta convention: per-app default derived from variant
-    ``beta_ref`` (frozen during descent -- the paper's beta is a user
-    target, not a design variable), or an explicit scalar/(A,) target."""
-    if beta is None:
-        return K.get_backend("numpy").default_beta(
-            pb.arrays(), mb.select(beta_ref).arrays())
-    return np.broadcast_to(
-        np.asarray(beta, dtype=np.float64), (len(pb),)).copy()
-
-
 def scalarized_objective(
     profiles,
     machines,
@@ -444,30 +441,143 @@ def scalarized_objective(
     Uses the same default-beta convention as ``batched_congruence``: when
     ``beta`` is None the per-app target derives from variant ``beta_ref``.
     """
-    pb, mb = _as_batches(profiles, machines)
-    beta = np.broadcast_to(
-        np.asarray(resolve_beta(pb, mb, beta, beta_ref), dtype=np.float64),
-        (len(pb),))
+    s = descent_suite(profiles, machines, beta=beta, beta_ref=beta_ref)
     with np.errstate(divide="ignore", invalid="ignore"):
-        return _objective_terms(np, pb.arrays(), mb.arrays(), beta,
-                                timing_model, eps, cost_model,
-                                w_area, w_power)
+        return _objective_terms(np, s.p, s.fixed, s.beta, timing_model, eps,
+                                cost_model, w_area, w_power)
 
 
-#: ``backtracking_descent`` caches of ``grad_codesign``, one per setting
-#: that is static in its loop.  The suite, the seeds' fixed machine
-#: constants, beta and the clip box are traced arguments, so a later solve
-#: on any suite of the same shapes reuses the compiled loop.
-_SOLVE_CACHES: Dict[tuple, dict] = {}
+# --------------------------------------------------------------------------- #
+# The descent seam: one suite, one objective, one cache per static setting
+# --------------------------------------------------------------------------- #
+
+
+def _jax_x64():
+    """``(jax, jnp, x64)``: the modules every descent mode traces with and
+    ``x64()``, the float64 scope it runs in -- the one reach into the jax
+    backend outside ``kernels_xp``."""
+    backend = K.get_backend("jax")
+    return backend._jax, backend._jnp, backend._x64
+
+
+@dataclasses.dataclass(frozen=True)
+class DescentSuite:
+    """What a co-design mode descends, as ``descent_suite`` packs it.
+
+    ``args`` -- ``(p, fixed, beta)`` in float64 host arrays -- is the suite
+    every compiled loop takes as traced arguments (its call moves them to
+    the device); ``theta0``/``lo``/``hi`` are the seeds' log-rates and the
+    span box; ``profiles``/``machines`` the packed batches that result
+    assembly reads.
+    """
+
+    profiles: "ProfileBatch"
+    machines: "MachineBatch"
+    p: K.ProfileArrays
+    fixed: K.MachineArrays
+    beta: np.ndarray
+    theta0: np.ndarray
+    lo: np.ndarray
+    hi: np.ndarray
+
+    @property
+    def args(self) -> Tuple[K.ProfileArrays, K.MachineArrays, np.ndarray]:
+        return self.p, self.fixed, self.beta
+
+    def take(self, rows) -> "DescentSuite":
+        """The suite over machine rows ``rows`` (a fleet's instances, a
+        frontier's winners), with the same apps and beta."""
+        rows = np.asarray(rows, dtype=np.int64)
+        return dataclasses.replace(
+            self, machines=self.machines.take(rows),
+            fixed=K.MachineArrays(*(f[rows] for f in self.fixed)),
+            theta0=self.theta0[rows], lo=self.lo[rows], hi=self.hi[rows])
+
+
+def descent_suite(profiles, machines, *, beta=None, beta_ref: int = 0,
+                  span: float = 16.0, optimize_links: bool = False,
+                  ) -> DescentSuite:
+    """Pack ``profiles`` x ``machines`` into the suite every mode descends.
+
+    ``beta`` is a scalar or ``(A,)`` target, or None for the per-app
+    default derived from variant ``beta_ref`` -- frozen during descent:
+    the paper's beta is a user target, not a design variable.  The box is
+    ``theta_box``'s.
+    """
+    from repro.core.sweep import _as_machine_batch, _as_profile_batch
+
+    pb, mb = _as_profile_batch(profiles), _as_machine_batch(machines)
+    host = K.get_backend("numpy")
+    if beta is None:
+        beta = host.default_beta(pb.arrays(), mb.select(beta_ref).arrays())
+    theta0, lo, hi = theta_box(mb, span, optimize_links=optimize_links)
+    return DescentSuite(
+        profiles=pb, machines=mb, p=host.profile_arrays(pb.arrays()),
+        fixed=host.machine_arrays(mb.arrays()),
+        beta=np.broadcast_to(np.asarray(beta, dtype=np.float64),
+                             (len(pb),)).copy(),
+        theta0=theta0, lo=lo, hi=hi)
 
 
 def _suite_objective(xp, theta, p: K.ProfileArrays, fixed: K.MachineArrays,
-                     beta, *, timing_model: str, eps: float,
-                     cost_model: CostModel, w_area: float, w_power: float):
-    """J per variant at log-rates ``theta`` over the suite ``p``."""
+                     beta, app_weights=None, *, timing_model: str,
+                     eps: float, cost_model: CostModel, w_area: float,
+                     w_power: float):
+    """J per variant at log-rates ``theta`` over the suite: the objective
+    every mode descends.  ``app_weights`` (traced, ``(A, V)``) replaces the
+    mean over apps -- the joint and packing modes' selection."""
     m = machine_arrays_from_theta(xp, theta, fixed)
     return _objective_terms(xp, p, m, beta, timing_model, eps, cost_model,
-                            w_area, w_power)
+                            w_area, w_power, app_weights=app_weights)
+
+
+def _suite_aggregate(xp, theta, p: K.ProfileArrays, fixed: K.MachineArrays,
+                     beta, *, timing_model: str, eps: float):
+    """The ``(A, V)`` aggregate congruence at ``theta``: what the joint and
+    packing modes select by."""
+    m = machine_arrays_from_theta(xp, theta, fixed)
+    return K.congruence_kernel(xp, p, m, beta, timing_model, eps,
+                               clamp=False).aggregate
+
+
+#: Compiled programs of every mode, one dict per setting that is static in
+#: them (``_static_key``): the descent loops of ``backtracking_descent`` and
+#: the ``_compiled`` functions.  The suite, box, budgets and round state
+#: are traced arguments, so a later solve of any mode on a suite of the
+#: same shapes reuses them.  It grows by one entry per static setting (and
+#: per closure a caller passes, which keys by identity).
+_SOLVE_CACHES: Dict[tuple, dict] = {}
+
+
+def _static_key(*fns) -> tuple:
+    """The cache key of the programs over ``fns``: module-level functions
+    (or None), or partials of them binding only static settings (the
+    array namespace, timing model, eps, cost model, weights, projection
+    method).  ``_objective_terms`` joins it (a replaced kernel compiles
+    programs of its own); a CostModel keys by its repr, as it holds
+    dicts."""
+    key = [_objective_terms]
+    for fn in fns:
+        if isinstance(fn, functools.partial):
+            key += [fn.func, fn.args, tuple(sorted(
+                (k, repr(v) if isinstance(v, CostModel) else v)
+                for k, v in fn.keywords.items()))]
+        else:
+            key.append(fn)
+    return tuple(key)
+
+
+def _compiled(fn, grad: bool = False) -> Callable:
+    """``jax.jit(fn)`` -- or, with ``grad``, of the gradient of
+    ``sum(fn(theta, *args))`` in ``theta`` -- kept per static setting, for
+    what a mode evaluates outside its loop (a seed's or a final
+    projection, a sensitivity report's gradient)."""
+    cache = _SOLVE_CACHES.setdefault(_static_key(fn), {})
+    if grad not in cache:
+        jax, jnp, _ = _jax_x64()
+        cache[grad] = jax.jit(
+            jax.grad(lambda th, *a: jnp.sum(fn(th, *a))) if grad else fn)
+    return cache[grad]
 
 
 @spanned("codesign")
@@ -523,47 +633,44 @@ def grad_codesign(
     >>> cd.mode
     'unconstrained'
     """
-    backend = K.get_backend("jax")
-    jax, jnp = backend._jax, backend._jnp
-
-    pb, mb = _as_batches(profiles, machines)
-    fixed_np = mb.arrays()
-    beta_np = resolve_beta(pb, mb, beta, beta_ref)
-    theta0, lo, hi = theta_box(mb, span)
-
-    # The CostModel holds dicts, so it keys by its repr.  The kernel
-    # function is in the key too: a replaced ``_objective_terms`` traces a
-    # loop of its own.
-    cache = _SOLVE_CACHES.setdefault(
-        (_objective_terms, timing_model, eps, repr(cost_model), w_area,
-         w_power), {})
+    jax, jnp, x64 = _jax_x64()
+    s = descent_suite(profiles, machines, beta=beta, beta_ref=beta_ref,
+                      span=span)
     objective = functools.partial(
         _suite_objective, jnp, timing_model=timing_model, eps=eps,
         cost_model=cost_model, w_area=w_area, w_power=w_power)
-    # float64 NumPy arrays: the compiled loop's call transfers them all.
-    host = K.get_backend("numpy")
-    suite = (host.profile_arrays(pb.arrays()), host.machine_arrays(fixed_np),
-             beta_np)
-    with backend._x64():
+    with x64():
         theta, f_cur, history, _, _ = backtracking_descent(
-            jax, jnp, theta0, objective, steps, lr, retract=jnp.clip,
-            obj_args=suite, retract_args=(lo, hi), cache=cache)
+            jax, jnp, s.theta0, objective, steps, lr, retract=jnp.clip,
+            obj_args=s.args, retract_args=(s.lo, s.hi))
         theta_np, f_final = jax.device_get((theta, f_cur))
 
-    final_m = machine_arrays_from_theta(np, theta_np, fixed_np)
+    return _codesign_result(s, theta_np, history, f_final, steps, cost_model,
+                            w_area, w_power)
+
+
+def _codesign_result(s: DescentSuite, theta_np, history, f_final, steps,
+                     cost_model: CostModel, w_area, w_power,
+                     violation_trace=None, feasible=None,
+                     **fields) -> CodesignResult:
+    """The ``CodesignResult`` of a descent over ``s`` that ended at
+    ``theta_np`` (``fields``: the mode, suffix, budgets and reports)."""
+    final_m = machine_arrays_from_theta(np, theta_np, s.fixed)
+    rows = range(len(s.machines))
     return CodesignResult(
-        names=list(mb.names),
+        names=list(s.machines.names),
         objective_seed=np.asarray(history[0]),
         objective_final=np.asarray(f_final),
-        seed_params=[params_of_theta(theta0[i], fixed_np, i)
-                     for i in range(len(mb))],
-        final_params=[params_of_theta(theta_np[i], fixed_np, i)
-                      for i in range(len(mb))],
+        seed_params=[params_of_theta(s.theta0[i], s.fixed, i) for i in rows],
+        final_params=[params_of_theta(theta_np[i], s.fixed, i) for i in rows],
         trajectory=np.stack(history, axis=0),
         steps=steps,
         w_area=w_area,
         w_power=w_power,
-        mode="unconstrained",
         area_final=np.asarray(cost_model.area(final_m)),
         power_final=np.asarray(cost_model.power(final_m)),
+        feasible=None if feasible is None else np.asarray(feasible, bool),
+        violation_trace=(None if violation_trace is None
+                         else np.stack(violation_trace, axis=0)),
+        **fields,
     )

@@ -56,16 +56,20 @@ heterogeneous-FPGA exploration treats as first-class:
     and it commutes with the span clip exactly like the uniform shift --
     both operator laws are pinned in tests/test_constrained.py.
 
-All modes reuse the one descent loop and the one traceable objective in
-``repro.core.codesign`` -- the same ``kernels_xp`` math every sweep scores
-with -- and return the same ``CodesignResult`` (with the feasibility
-report populated).  ``docs/codesign.md`` is the worked guide;
+All modes build their problem with ``codesign.descent_suite``, descend the
+one traceable objective (``codesign._suite_objective``, or a module-level
+function over it) through the one compiled loop -- the same ``kernels_xp``
+math every sweep scores with -- with every array, budget and round state
+a traced argument, and return the same ``CodesignResult`` (with the
+feasibility report populated).  ``docs/codesign.md`` is the worked guide;
 ``repro.core.frontier`` traces whole budget *sweeps* over this module by
 warm-started continuation (``docs/frontier.md``).
 """
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
@@ -74,13 +78,14 @@ from repro.core import kernels_xp as K
 from repro.core.codesign import (
     OPT_FIELDS,
     CodesignResult,
-    _as_batches,
-    _objective_terms,
+    _codesign_result,
+    _compiled,
+    _jax_x64,
+    _suite_aggregate,
+    _suite_objective,
     backtracking_descent,
+    descent_suite,
     machine_arrays_from_theta,
-    params_of_theta,
-    resolve_beta,
-    theta_box,
 )
 from repro.core.costmodel import DEFAULT_COST_MODEL, RATE_FIELDS, CostModel
 
@@ -222,6 +227,19 @@ def _iterate(xp, body, init, iters: int):
     return state
 
 
+def _bisect(xp, ok, hi, iters: int):
+    """The feasible end of the bracket ``[0, hi]`` after ``iters``
+    bisections on ``ok``, a predicate False below its root and True above
+    (the budget projections' shifts and multipliers)."""
+    def step(_, bracket):
+        b_lo, b_hi = bracket
+        mid = 0.5 * (b_lo + b_hi)
+        okm = ok(mid)
+        return xp.where(okm, b_lo, mid), xp.where(okm, mid, b_hi)
+
+    return _iterate(xp, step, (xp.zeros_like(hi), hi), iters)[1]
+
+
 def project_to_budgets(
     xp,
     theta,
@@ -301,19 +319,10 @@ def project_to_budgets(
     # Largest useful shift: every masked column at its floor.
     t_floor = xp.max(xp.where(shift_mask[None, :] > 0, th - lo,
                               xp.zeros_like(th)), axis=1)
-    ok_floor = feasible_at(t_floor)
-
-    def bisect_step(_, bracket):
-        t_lo, t_hi = bracket
-        mid = 0.5 * (t_lo + t_hi)
-        okm = feasible_at(mid)
-        return (xp.where(okm, t_lo, mid), xp.where(okm, mid, t_hi))
-
-    t_lo, t_hi = _iterate(xp, bisect_step, (zero, t_floor), iters)
-    # Return the feasible endpoint of the bracket; untouched where already
+    # The feasible endpoint of the bracket; untouched where already
     # feasible (exact idempotence), floor where nothing is feasible.
-    t_star = xp.where(ok0, zero, t_hi)
-    return at_shift(t_star), ok0 | ok_floor
+    t_star = xp.where(ok0, zero, _bisect(xp, feasible_at, t_floor, iters))
+    return at_shift(t_star), ok0 | feasible_at(t_floor)
 
 
 # --------------------------------------------------------------------------- #
@@ -391,22 +400,14 @@ def _project_posynomial(xp, th, lo, hi, coeff, expo, budget, iters):
 
     ok0 = g_of(th) <= budget
 
-    def grow(_, nu):
-        return xp.where(g_of(t_of(nu)) <= budget, nu, nu * 8.0)
+    def ok(nu):
+        return g_of(t_of(nu)) <= budget
 
-    nu_hi = _iterate(xp, grow, 1e-6 * xp.ones_like(th[:, 0]), BRACKET_ITERS)
-
-    def bisect(_, bracket):
-        nu_lo, nu_up = bracket
-        mid = 0.5 * (nu_lo + nu_up)
-        okm = g_of(t_of(mid)) <= budget
-        return (xp.where(okm, nu_lo, mid), xp.where(okm, mid, nu_up))
-
-    _, nu_star = _iterate(
-        xp, bisect, (xp.zeros_like(nu_hi), nu_hi), iters)
+    nu_hi = _iterate(xp, lambda _, nu: xp.where(ok(nu), nu, nu * 8.0),
+                     1e-6 * xp.ones_like(th[:, 0]), BRACKET_ITERS)
     # Feasible bracket endpoint; bit-exact pass-through when already
     # feasible (idempotence).
-    return xp.where(ok0[:, None], th, t_of(nu_star))
+    return xp.where(ok0[:, None], th, t_of(_bisect(xp, ok, nu_hi, iters)))
 
 
 def _project_euclidean(xp, th, lo, hi, fixed, cost_model, area_budget,
@@ -438,12 +439,9 @@ def _project_euclidean(xp, th, lo, hi, fixed, cost_model, area_budget,
             "inter_pod_bw": lambda b: xp.log(b * ref.inter_pod_bw)
             + xp.zeros_like(th[:, 0]),
         }
-        col = {f: j for j, f in
-               enumerate(("peak_flops", "hbm_bw", "ici_bw_total",
-                          "inter_pod_bw"))}
         cap_mat = xp.full_like(th, xp.inf)
         for field in sorted(area_envelope):
-            j = col[field]
+            j = RATE_FIELDS.index(field)        # theta's column order
             cap_col = caps[field](area_envelope[field])
             cap_mat = _set_column(xp, cap_mat, j,
                                   xp.minimum(cap_mat[:, j], cap_col))
@@ -496,6 +494,84 @@ def _set_column(xp, a, j: int, col):
 
 
 # --------------------------------------------------------------------------- #
+# The descent's functions of the constraint set: every array an argument
+# --------------------------------------------------------------------------- #
+
+
+def _budget_args(area_budget, power_budget, area_envelope) -> tuple:
+    """``(area, power, envelope)`` as traced retraction arguments: float64
+    arrays (an envelope a dict of them); an absent budget stays None, a
+    static choice of the program."""
+    def f64(b):
+        return None if b is None else np.asarray(b, dtype=np.float64)
+
+    envelope = ({f: f64(b) for f, b in area_envelope.items()}
+                if area_envelope else None)
+    return f64(area_budget), f64(power_budget), envelope
+
+
+def _budget_retraction(xp, theta, lo, hi, fixed, area_budget, power_budget,
+                       area_envelope, *, cost_model: CostModel,
+                       method: str = "shift"):
+    """``project_to_budgets`` as a descent retraction: the box, the fixed
+    machine constants and the budgets are its arguments."""
+    out, _ = project_to_budgets(xp, theta, lo, hi, fixed, cost_model,
+                                area_budget, power_budget,
+                                area_envelope=area_envelope, method=method)
+    return out
+
+
+def _violations(xp, theta, lo, hi, fixed, area_budget, power_budget,
+                area_envelope, *, cost_model: CostModel):
+    """``budget_violations_vector`` at ``theta``, over the retraction's
+    arguments."""
+    m = machine_arrays_from_theta(xp, theta, fixed)
+    return budget_violations_vector(xp, m, cost_model, area_budget,
+                                    power_budget, area_envelope)
+
+
+def _worst_violation(xp, theta, *retract_args, cost_model: CostModel):
+    """The worst relative violation per variant: the descent's aux trace."""
+    return xp.max(_violations(xp, theta, *retract_args,
+                              cost_model=cost_model), axis=1)
+
+
+def _augmented_objective(xp, theta, p, fixed, beta, lam, mu, area_budget,
+                         power_budget, area_envelope, **settings):
+    """The augmented Lagrangian per variant: J plus
+    ``(1/2mu) * sum_c (relu(lam_c + mu * g_c)^2 - lam_c^2)`` over the
+    relative violations ``g`` (``settings`` are ``_suite_objective``'s)."""
+    g = budget_violations_vector(
+        xp, machine_arrays_from_theta(xp, theta, fixed),
+        settings["cost_model"], area_budget, power_budget, area_envelope)
+    pen = 0.5 / mu * xp.sum(
+        xp.maximum(lam + mu[:, None] * g, 0.0) ** 2 - lam ** 2, axis=1)
+    return _suite_objective(xp, theta, p, fixed, beta, **settings) + pen
+
+
+def _softmax_objective(xp, params, p, fixed, beta, member, temp,
+                       **settings):
+    """The joint softmax mode's J over ``params = [theta | logits]``: each
+    group's members weighted by a softmax of their logits at temperature
+    ``temp`` (``member`` is the ``(A, G)`` group membership)."""
+    n_rates = params.shape[1] - member.shape[0]
+    e = xp.exp(params[:, n_rates:].T / temp)            # (A, V)
+    denom = member @ (member.T @ e)                     # (A, V) per group
+    weights = e / denom / member.shape[1]
+    return _suite_objective(xp, params[:, :n_rates], p, fixed, beta,
+                            weights, **settings)
+
+
+def _params_retraction(xp, params, lo, hi, *args, cost_model: CostModel):
+    """``_budget_retraction`` on the rate columns of ``[theta | logits]``;
+    the logits pass through."""
+    n_rates = lo.shape[1]
+    theta = _budget_retraction(xp, params[:, :n_rates], lo, hi, *args,
+                               cost_model=cost_model)
+    return xp.concatenate([theta, params[:, n_rates:]], axis=1)
+
+
+# --------------------------------------------------------------------------- #
 # Constrained descent: projected gradient + augmented Lagrangian
 # --------------------------------------------------------------------------- #
 
@@ -511,40 +587,6 @@ def _validate_budgets(area_budget, power_budget, area_envelope=None):
         if b is not None and not b > 0.0:
             raise ValueError(f"{name} must be positive, got {b!r}")
     return validate_area_envelope(area_envelope)
-
-
-def _finalize(mb, fixed_np, theta0, theta_np, history, steps, w_area, w_power,
-              cost_model, mode, suffix, area_budget, power_budget,
-              violation_trace, feasible, objective_final,
-              selection_names=None, area_envelope=None, multipliers=None,
-              constraint_names=None) -> CodesignResult:
-    final_m = machine_arrays_from_theta(np, theta_np, fixed_np)
-    return CodesignResult(
-        names=list(mb.names),
-        objective_seed=np.asarray(history[0]),
-        objective_final=np.asarray(objective_final),
-        seed_params=[params_of_theta(theta0[i], fixed_np, i)
-                     for i in range(len(mb))],
-        final_params=[params_of_theta(theta_np[i], fixed_np, i)
-                      for i in range(len(mb))],
-        trajectory=np.stack(history, axis=0),
-        steps=steps,
-        w_area=w_area,
-        w_power=w_power,
-        mode=mode,
-        suffix=suffix,
-        area_budget=area_budget,
-        power_budget=power_budget,
-        area_envelope=area_envelope,
-        area_final=np.asarray(cost_model.area(final_m)),
-        power_final=np.asarray(cost_model.power(final_m)),
-        feasible=np.asarray(feasible, dtype=bool),
-        violation_trace=(np.stack(violation_trace, axis=0)
-                         if violation_trace is not None else None),
-        selection_names=selection_names,
-        multipliers=multipliers,
-        constraint_names=constraint_names,
-    )
 
 
 def _round_links_with_repair(theta_np, lo, hi, fixed_np, cost_model,
@@ -712,52 +754,30 @@ def constrained_codesign(
             "projection='euclidean' does not compose with optimize_links "
             "(the links column needs the masked shift repair); use the "
             "default projection='shift'")
-    backend = K.get_backend("jax")
-    jax, jnp = backend._jax, backend._jnp
-
-    pb, mb = _as_batches(profiles, machines)
-    fixed_np = mb.arrays()
-    beta_np = resolve_beta(pb, mb, beta, beta_ref)
-    theta0, lo, hi = theta_box(mb, span, optimize_links=optimize_links)
+    jax, jnp, x64 = _jax_x64()
+    s = descent_suite(profiles, machines, beta=beta, beta_ref=beta_ref,
+                      span=span, optimize_links=optimize_links)
     suffix = {"projected": "+proj", "lagrangian": "+lagr"}[mode]
+    settings = dict(timing_model=timing_model, eps=eps,
+                    cost_model=cost_model, w_area=w_area, w_power=w_power)
+    objective = functools.partial(_suite_objective, jnp, **settings)
+    project = functools.partial(_budget_retraction, jnp,
+                                cost_model=cost_model, method=projection)
+    violation = functools.partial(_worst_violation, jnp,
+                                  cost_model=cost_model)
+    rargs = (s.lo, s.hi, s.fixed) + _budget_args(area_budget, power_budget,
+                                                 area_envelope)
 
-    with backend._x64():
-        p_arrays = backend.profile_arrays(pb.arrays())
-        fixed = backend.machine_arrays(fixed_np)
-        beta_j = backend.asarray(beta_np)
-        lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
-
-        def objective(theta):
-            m = machine_arrays_from_theta(jnp, theta, fixed)
-            return _objective_terms(jnp, p_arrays, m, beta_j, timing_model,
-                                    eps, cost_model, w_area, w_power)
-
-        def violation(theta):
-            m = machine_arrays_from_theta(jnp, theta, fixed)
-            return budget_violation(jnp, m, cost_model, area_budget,
-                                    power_budget, area_envelope)
-
-        def violations_vec(theta):
-            m = machine_arrays_from_theta(jnp, theta, fixed)
-            return budget_violations_vector(jnp, m, cost_model, area_budget,
-                                            power_budget, area_envelope)
-
-        def project(theta):
-            out, _ = project_to_budgets(jnp, theta, lo_j, hi_j, fixed,
-                                        cost_model, area_budget, power_budget,
-                                        area_envelope=area_envelope,
-                                        method=projection)
-            return out
-
+    with x64():
         multipliers = constraint_names = None
         if mode == "projected":
             theta, f_cur, history, vtrace, _ = backtracking_descent(
-                jax, jnp, backend.asarray(theta0), objective, steps, lr,
-                retract=project, aux_fn=violation)
+                jax, jnp, s.theta0, objective, steps, lr, retract=project,
+                aux_fn=violation, obj_args=s.args, retract_args=rargs)
         else:
             theta, history, vtrace, lam_rel = _lagrangian_descent(
-                jax, jnp, backend, theta0, lo_j, hi_j, objective, violation,
-                violations_vec, steps, lr, outer_iters, mu0, mu_growth)
+                jax, jnp, s, rargs, settings, steps, lr, outer_iters, mu0,
+                mu_growth)
             # The dual iterates multiply RELATIVE violations
             # (value / budget - 1); report them as ABSOLUTE shadow prices
             # (lam_abs = lam_rel / budget) so they are directly comparable
@@ -775,87 +795,82 @@ def constrained_codesign(
             # outside; project the final design so the returned machines
             # honour the budget to FEASIBLE_RTOL exactly like projected
             # mode does.
-            theta = project(theta)
-            vtrace.append(np.asarray(violation(theta)))
-            history.append(np.asarray(objective(theta)))
+            theta = _compiled(project)(theta, *rargs)
+            vtrace.append(np.asarray(violation(theta, *rargs)))
+            history.append(np.asarray(objective(theta, *s.args)))
 
-        theta_np = backend.to_numpy(theta)
+        theta_np = np.asarray(theta)
         f_final = np.asarray(history[-1])
 
     feasible = budget_feasible(
-        np, machine_arrays_from_theta(np, theta_np, fixed_np), cost_model,
+        np, machine_arrays_from_theta(np, theta_np, s.fixed), cost_model,
         area_budget, power_budget, area_envelope=area_envelope)
 
     if optimize_links:
         def obj_np(th):
-            m = machine_arrays_from_theta(np, th, fixed_np)
             with np.errstate(divide="ignore", invalid="ignore"):
-                return _objective_terms(np, pb.arrays(), m, beta_np,
-                                        timing_model, eps, cost_model,
-                                        w_area, w_power)
+                return _suite_objective(np, th, *s.args, **settings)
         theta_np, feasible, f_final = _round_links_with_repair(
-            theta_np, lo, hi, fixed_np, cost_model, area_budget,
+            theta_np, s.lo, s.hi, s.fixed, cost_model, area_budget,
             power_budget, obj_np, area_envelope=area_envelope)
         history.append(np.asarray(f_final))
         vtrace.append(np.asarray(budget_violation(
-            np, machine_arrays_from_theta(np, theta_np, fixed_np),
+            np, machine_arrays_from_theta(np, theta_np, s.fixed),
             cost_model, area_budget, power_budget, area_envelope)))
 
-    return _finalize(mb, fixed_np, theta0, theta_np, history, steps, w_area,
-                     w_power, cost_model, mode, suffix, area_budget,
-                     power_budget, vtrace, feasible, f_final,
-                     area_envelope=area_envelope, multipliers=multipliers,
-                     constraint_names=constraint_names)
+    return _codesign_result(
+        s, theta_np, history, f_final, steps, cost_model, w_area, w_power,
+        violation_trace=vtrace, feasible=feasible, mode=mode, suffix=suffix,
+        area_budget=area_budget, power_budget=power_budget,
+        area_envelope=area_envelope, multipliers=multipliers,
+        constraint_names=constraint_names)
 
 
-def _lagrangian_descent(jax, jnp, backend, theta0, lo_j, hi_j, objective,
-                        violation, violations_vec, steps, lr, outer_iters,
-                        mu0, mu_growth):
+def _lagrangian_descent(jax, jnp, s, rargs, settings, steps, lr,
+                        outer_iters, mu0, mu_growth):
     """Augmented-Lagrangian outer loop (inner loops share the one descent).
 
-    One multiplier PER constraint (``violations_vec`` columns: scalar
-    area, scalar power, then each envelope field), so a binding HBM
-    envelope grows its own dual weight without inflating the pressure on
-    an easily-satisfied total-area budget.  The violation trace (the max
-    over constraints) is damped BY CONSTRUCTION: an outer iterate is
-    accepted per variant only when its worst violation does not exceed the
-    best seen so far; rejected variants keep their previous theta and get
-    a sharply increased penalty weight for the next round.
+    One multiplier PER constraint (``_violations`` columns: scalar area,
+    scalar power, then each envelope field), so a binding HBM envelope
+    grows its own dual weight without inflating the pressure on an
+    easily-satisfied total-area budget.  The violation trace (the max over
+    constraints) is damped BY CONSTRUCTION: an outer iterate is accepted
+    per variant only when its worst violation does not exceed the best
+    seen so far; rejected variants keep their previous theta and get a
+    sharply increased penalty weight for the next round.  ``rargs`` are
+    the budget retraction's arguments: the box, the fixed constants and
+    the budgets.
     """
-    v = theta0.shape[0]
+    cost_model = settings["cost_model"]
+    objective = functools.partial(_suite_objective, jnp, **settings)
+    augmented = functools.partial(_augmented_objective, jnp, **settings)
+    v = s.theta0.shape[0]
     steps_inner = max(1, steps // max(outer_iters, 1))
-    theta = jnp.clip(backend.asarray(theta0), lo_j, hi_j)
-    n_constraints = int(violations_vec(theta).shape[1])
+    theta = jnp.clip(s.theta0, s.lo, s.hi)
+    n_constraints = int(
+        _violations(jnp, theta, *rargs, cost_model=cost_model).shape[1])
     lam = jnp.zeros((v, n_constraints))
     mu = jnp.full((v,), float(mu0))
     lr_v = lr
-    v_best = violation(theta)
-    history = [np.asarray(objective(theta))]
+    v_best = _worst_violation(jnp, theta, *rargs, cost_model=cost_model)
+    history = [np.asarray(objective(theta, *s.args))]
     vtrace = [np.asarray(v_best)]
 
-    # Multipliers enter as TRACED arguments (not fresh closures), and the
-    # jit cache is shared across outer rounds: the congruence graph
-    # compiles once for the whole Lagrangian run.
-    def augmented(th, lam_c, mu_c):
-        g = violations_vec(th)  # (V, C) relative violations, already relu'd
-        pen = 0.5 / mu_c * jnp.sum(
-            jnp.maximum(lam_c + mu_c[:, None] * g, 0.0) ** 2 - lam_c ** 2,
-            axis=1)
-        return objective(th) + pen
-
-    jit_cache = {}
+    # Multipliers, like the suite and the budgets, enter as TRACED
+    # arguments: one compiled inner loop serves every round.
     for _ in range(outer_iters):
         cand, _, _, _, lr_v = backtracking_descent(
-            jax, jnp, theta, augmented, steps_inner, lr_v,
-            retract=lambda th: jnp.clip(th, lo_j, hi_j),
-            obj_args=(lam, mu), cache=jit_cache)
-        v_new = violation(cand)
+            jax, jnp, theta, augmented, steps_inner, lr_v, retract=jnp.clip,
+            obj_args=(*s.args, lam, mu, *rargs[3:]),
+            retract_args=(s.lo, s.hi))
+        v_new = _worst_violation(jnp, cand, *rargs, cost_model=cost_model)
         ok = v_new <= v_best + 1e-12
         theta = jnp.where(ok[:, None], cand, theta)
         v_best = jnp.minimum(v_new, v_best)
-        lam = jnp.maximum(lam + mu[:, None] * violations_vec(theta), 0.0)
+        lam = jnp.maximum(lam + mu[:, None] * _violations(
+            jnp, theta, *rargs, cost_model=cost_model), 0.0)
         mu = jnp.where(ok, mu * mu_growth, mu * (mu_growth ** 2))
-        history.append(np.asarray(objective(theta)))
+        history.append(np.asarray(objective(theta, *s.args)))
         vtrace.append(np.asarray(v_best))
     return theta, history, vtrace, np.asarray(lam)
 
@@ -984,61 +999,42 @@ def joint_codesign(
                          "have ('alternate', 'softmax')")
     if area_budget is not None or power_budget is not None:
         _validate_budgets(area_budget, power_budget)
-    backend = K.get_backend("jax")
-    jax, jnp = backend._jax, backend._jnp
-
+    jax, jnp, x64 = _jax_x64()
     flat, gids, groups = _flatten_groups(profile_groups)
     n_groups = len(groups)
-    pb, mb = _as_batches(flat, machines)
-    fixed_np = mb.arrays()
     # Beta is a per-APPLICATION target: every sharding variant of a group
     # chases the same target (derived from the group's member 0 by default),
     # and an explicit beta has group length, not flattened length.
-    first_rows = np.array([int(np.nonzero(gids == g)[0][0])
-                           for g in range(n_groups)])
-    if beta is None:
-        beta_np = resolve_beta(pb, mb, None, beta_ref)[first_rows][gids]
-    else:
-        beta_np = np.broadcast_to(
+    if beta is not None:
+        beta = np.broadcast_to(
             np.asarray(beta, dtype=np.float64), (n_groups,))[gids]
-    theta0, lo, hi = theta_box(mb, span)
-    n_rates = theta0.shape[1]
-    a_total, v = len(pb), len(mb)
+    s = descent_suite(flat, machines, beta=beta, beta_ref=beta_ref,
+                      span=span)
+    if beta is None:
+        first_rows = np.array([int(np.nonzero(gids == g)[0][0])
+                               for g in range(n_groups)])
+        s = dataclasses.replace(s, beta=s.beta[first_rows][gids])
+    a_total, v = len(s.profiles), len(s.machines)
     # Per-group one-hot membership matrix for segment softmax: (A, G).
     member = np.zeros((a_total, n_groups))
     member[np.arange(a_total), gids] = 1.0
     constrained = area_budget is not None or power_budget is not None
+    settings = dict(timing_model=timing_model, eps=eps,
+                    cost_model=cost_model, w_area=w_area, w_power=w_power)
+    objective = functools.partial(_suite_objective, jnp, **settings)
+    retract = functools.partial(_budget_retraction, jnp,
+                                cost_model=cost_model)
+    rargs = (s.lo, s.hi, s.fixed) + _budget_args(area_budget, power_budget,
+                                                 None)
 
-    with backend._x64():
-        p_arrays = backend.profile_arrays(pb.arrays())
-        fixed = backend.machine_arrays(fixed_np)
-        beta_j = backend.asarray(beta_np)
-        lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
-        member_j = backend.asarray(member)
+    def aggregate(theta):
+        return np.asarray(_suite_aggregate(
+            jnp, theta, *s.args, timing_model=timing_model, eps=eps))
 
-        def retract_theta(th):
-            if constrained:
-                out, _ = project_to_budgets(
-                    jnp, th, lo_j, hi_j, fixed, cost_model, area_budget,
-                    power_budget)
-                return out
-            return jnp.clip(th, lo_j, hi_j)
-
-        def objective_with(th, weights):
-            m = machine_arrays_from_theta(jnp, th, fixed)
-            return _objective_terms(jnp, p_arrays, m, beta_j, timing_model,
-                                    eps, cost_model, w_area, w_power,
-                                    app_weights=weights)
-
-        def aggregate_np(th):
-            m = machine_arrays_from_theta(jnp, th, fixed)
-            out = K.congruence_kernel(jnp, p_arrays, m, beta_j, timing_model,
-                                      eps, clamp=False)
-            return np.asarray(out.aggregate)
-
-        theta = retract_theta(backend.asarray(theta0))
-        w_hard = _hard_weights(aggregate_np(theta), gids)
-        obj_seed = np.asarray(objective_with(theta, backend.asarray(w_hard)))
+    with x64():
+        theta = _compiled(retract)(s.theta0, *rargs)
+        w_hard = _hard_weights(aggregate(theta), gids)
+        obj_seed = np.asarray(objective(theta, *s.args, w_hard))
         history: List[np.ndarray] = [obj_seed]
         steps_round = max(1, steps // max(rounds + 1, 1))
         lr_v = lr
@@ -1047,86 +1043,55 @@ def joint_codesign(
         # transiently regress; tracking the incumbent makes the reported
         # result monotone vs the seed by construction.
         best_theta, best_f = theta, jnp.asarray(obj_seed)
-
-        def track_best(theta, f_hard, best_theta, best_f):
-            """Keep the incumbent under the (already computed) hard-selection
-            objective of this round's boundary."""
-            f = jnp.asarray(f_hard)
-            better = f < best_f
-            return (jnp.where(better[:, None], theta, best_theta),
-                    jnp.minimum(f, best_f))
-
+        soft = functools.partial(_softmax_objective, jnp, **settings)
+        soft_retract = functools.partial(_params_retraction, jnp,
+                                         cost_model=cost_model)
+        phi = jnp.zeros((v, a_total))
+        temps = np.geomspace(temp0, max(temp_min, 1e-6), max(rounds, 1))
+        n_rates = s.theta0.shape[1]
         # Round-varying state (selection weights, softmax temperature)
-        # enters as traced arguments with a shared jit cache, so each mode
-        # compiles its objective once for the whole run.
-        weighted_cache: dict = {}
-
-        if mode == "alternate":
-            for _ in range(rounds):
+        # enters as traced arguments, so each mode's loop compiles once.
+        # Alternation runs ``rounds`` rounds, softmax one per temperature.
+        for temp in (temps[:rounds] if mode == "alternate" else temps):
+            if mode == "alternate":
                 theta, _, hist, _, lr_v = backtracking_descent(
-                    jax, jnp, theta, objective_with,
-                    steps_round, lr_v, retract=retract_theta,
-                    obj_args=(backend.asarray(w_hard),),
-                    cache=weighted_cache)
+                    jax, jnp, theta, objective, steps_round, lr_v,
+                    retract=retract, obj_args=(*s.args, w_hard),
+                    retract_args=rargs)
                 history.extend(hist[1:])
-                w_hard = _hard_weights(aggregate_np(theta), gids)
-                f_bound = np.asarray(
-                    objective_with(theta, backend.asarray(w_hard)))
-                history.append(f_bound)
-                best_theta, best_f = track_best(theta, f_bound,
-                                                best_theta, best_f)
-        else:
-            phi = jnp.zeros((v, a_total))
-            temps = np.geomspace(temp0, max(temp_min, 1e-6), max(rounds, 1))
-
-            def retract_params(params):
-                return jnp.concatenate(
-                    [retract_theta(params[:, :n_rates]), params[:, n_rates:]],
-                    axis=1)
-
-            def objective_soft(params, temp):
-                th = params[:, :n_rates]
-                logits = params[:, n_rates:].T          # (A, V)
-                e = jnp.exp(logits / temp)
-                denom = member_j @ (member_j.T @ e)     # (A, V) per-group
-                weights = e / denom / n_groups
-                return objective_with(th, weights)
-
-            soft_cache: dict = {}
-            for temp in temps:
-                params = jnp.concatenate([theta, phi], axis=1)
+            else:
                 params, _, _, _, lr_v = backtracking_descent(
-                    jax, jnp, params, objective_soft, steps_round, lr_v,
-                    retract=retract_params,
-                    obj_args=(backend.asarray(float(temp)),),
-                    cache=soft_cache)
-                theta = params[:, :n_rates]
-                phi = params[:, n_rates:]
-                w_hard = _hard_weights(aggregate_np(theta), gids)
-                f_bound = np.asarray(
-                    objective_with(theta, backend.asarray(w_hard)))
-                history.append(f_bound)
-                best_theta, best_f = track_best(theta, f_bound,
-                                                best_theta, best_f)
+                    jax, jnp, jnp.concatenate([theta, phi], axis=1), soft,
+                    steps_round, lr_v, retract=soft_retract,
+                    obj_args=(*s.args, member, np.asarray(temp)),
+                    retract_args=rargs)
+                theta, phi = params[:, :n_rates], params[:, n_rates:]
+            # The round boundary: re-select, and keep the incumbent under
+            # the hard-selection objective.
+            w_hard = _hard_weights(aggregate(theta), gids)
+            f_bound = np.asarray(objective(theta, *s.args, w_hard))
+            history.append(f_bound)
+            better = f_bound < best_f
+            best_theta = jnp.where(better[:, None], theta, best_theta)
+            best_f = jnp.minimum(f_bound, best_f)
 
         # Final polish: machine-only descent under the incumbent's hard
         # selection, starting FROM the incumbent (backtracking guarantees
         # it never regresses past it).
         theta = best_theta
-        w_hard = _hard_weights(aggregate_np(theta), gids)
+        w_hard = _hard_weights(aggregate(theta), gids)
         theta, _, hist, _, _ = backtracking_descent(
-            jax, jnp, theta, objective_with,
-            steps_round, lr_v, retract=retract_theta,
-            obj_args=(backend.asarray(w_hard),), cache=weighted_cache)
+            jax, jnp, theta, objective, steps_round, lr_v, retract=retract,
+            obj_args=(*s.args, w_hard), retract_args=rargs)
         history.extend(hist[1:])
-        theta_np = backend.to_numpy(theta)
+        theta_np = np.asarray(theta)
         # Re-select once more at the final machine so the reported
         # objective, the selection and the trajectory tail all agree (the
         # polish may have shifted which member wins; argmin re-selection
         # only ever lowers the objective).
-        agg_final = aggregate_np(theta)
+        agg_final = aggregate(theta)
         w_hard = _hard_weights(agg_final, gids)
-        f_cur = np.asarray(objective_with(theta, backend.asarray(w_hard)))
+        f_cur = np.asarray(objective(theta, *s.args, w_hard))
         history.append(f_cur)
 
     # Hard per-(variant, group) picks by profile name.
@@ -1135,21 +1100,19 @@ def joint_codesign(
         picks = []
         for g in range(n_groups):
             rows = np.nonzero(gids == g)[0]
-            picks.append(pb.names[rows[np.argmin(agg_final[rows, vi])]])
+            picks.append(
+                s.profiles.names[rows[np.argmin(agg_final[rows, vi])]])
         selection_names.append(picks)
 
-    final_m = machine_arrays_from_theta(np, theta_np, fixed_np)
-    feasible = (budget_feasible(np, final_m, cost_model, area_budget,
-                                power_budget)
-                if constrained else np.ones(v, dtype=bool))
-    vtrace = ([np.asarray(budget_violation(np, final_m, cost_model,
-                                           area_budget, power_budget))]
-              if constrained else None)
-    res = _finalize(
-        mb, fixed_np, theta0, theta_np, history, steps, w_area, w_power,
-        cost_model, f"joint-{mode}", "+joint", area_budget, power_budget,
-        vtrace, feasible, np.asarray(f_cur), selection_names=selection_names)
-    if not constrained:
-        res.feasible = None
-        res.area_budget = res.power_budget = None
-    return res
+    final_m = machine_arrays_from_theta(np, theta_np, s.fixed)
+    feasible = vtrace = None
+    if constrained:
+        feasible = budget_feasible(np, final_m, cost_model, area_budget,
+                                   power_budget)
+        vtrace = [np.asarray(budget_violation(np, final_m, cost_model,
+                                              area_budget, power_budget))]
+    return _codesign_result(
+        s, theta_np, history, f_cur, steps, cost_model, w_area, w_power,
+        violation_trace=vtrace, feasible=feasible, mode=f"joint-{mode}",
+        suffix="+joint", area_budget=area_budget, power_budget=power_budget,
+        selection_names=selection_names)

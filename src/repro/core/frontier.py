@@ -47,25 +47,26 @@ frontier traces via the ``seed_codesign`` warm starts.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core import kernels_xp as K
 from repro.core.codesign import (
-    _as_batches,
-    _objective_terms,
+    OPT_FIELDS,
+    _jax_x64,
+    _suite_objective,
     backtracking_descent,
+    descent_suite,
     machine_arrays_from_theta,
     params_of_theta,
-    resolve_beta,
-    theta_box,
 )
-from repro.core.codesign import OPT_FIELDS
 from repro.core.constrained import (
     FEASIBLE_RTOL,
+    _budget_args,
+    _budget_retraction,
     budget_feasible,
-    project_to_budgets,
     validate_area_envelope,
 )
 from repro.core.costmodel import DEFAULT_COST_MODEL, CostModel
@@ -404,54 +405,39 @@ def frontier_codesign(
         raise ValueError(f"power_budget must be positive, got {power_budget!r}")
     if refine_steps is None:
         refine_steps = max(steps // 5, 1)
-    backend = K.get_backend("jax")
-    jax, jnp = backend._jax, backend._jnp
+    jax, jnp, x64 = _jax_x64()
+    s = descent_suite(profiles, machines, beta=beta, beta_ref=beta_ref,
+                      span=span)
+    objective = functools.partial(
+        _suite_objective, jnp, timing_model=timing_model, eps=eps,
+        cost_model=cost_model, w_area=w_area, w_power=w_power)
+    # The active budget is a TRACED argument: one compiled loop serves
+    # every budget in the schedule (the continuation's compile economy).
+    retract = functools.partial(_budget_retraction, jnp,
+                                cost_model=cost_model, method=projection)
 
-    pb, mb = _as_batches(profiles, machines)
-    fixed_np = mb.arrays()
-    beta_np = resolve_beta(pb, mb, beta, beta_ref)
-    theta0, lo, hi = theta_box(mb, span)
-
-    with backend._x64():
-        p_arrays = backend.profile_arrays(pb.arrays())
-        fixed = backend.machine_arrays(fixed_np)
-        beta_j = backend.asarray(beta_np)
-        lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
-
-        def objective(theta):
-            m = machine_arrays_from_theta(jnp, theta, fixed)
-            return _objective_terms(jnp, p_arrays, m, beta_j, timing_model,
-                                    eps, cost_model, w_area, w_power)
-
-        def retract(theta, budget):
-            # ``budget`` is TRACED: one compiled projection serves every
-            # budget in the schedule (the continuation's compile economy).
-            out, _ = project_to_budgets(
-                jnp, theta, lo_j, hi_j, fixed, cost_model, budget,
-                power_budget, area_envelope=area_envelope, method=projection)
-            return out
-
-        cache: dict = {}
+    with x64():
         # A caller-provided warm_theta (e.g. the serving cache's nearest
         # already-solved budget) replaces the cold seeds: the loosest
         # budget then only refines, exactly like an interior budget would.
         resumed = warm_start and warm_theta is not None
-        theta = backend.asarray(warm_theta if resumed else theta0)
+        theta = warm_theta if resumed else s.theta0
         lr_v = (warm_lr if resumed and warm_lr is not None else lr)
         raw: Dict[float, np.ndarray] = {}
         raw_obj: Dict[float, np.ndarray] = {}
         for j, b in enumerate(reversed(asc)):          # loosest -> tightest
             warm = warm_start and (j > 0 or resumed)
             n_steps = refine_steps if warm else steps
-            start = theta if warm_start else backend.asarray(theta0)
+            start = theta if warm_start else s.theta0
             start_lr = lr_v if warm_start else lr
             theta_b, f_b, _, _, lr_out = backtracking_descent(
                 jax, jnp, start, objective, n_steps, start_lr,
-                retract=retract, retract_args=(backend.asarray(float(b)),),
-                cache=cache)
+                retract=retract, obj_args=s.args,
+                retract_args=(s.lo, s.hi, s.fixed) + _budget_args(
+                    b, power_budget, area_envelope))
             if warm_start:
                 theta, lr_v = theta_b, lr_out
-            raw[b] = backend.to_numpy(theta_b)
+            raw[b] = np.asarray(theta_b)
             raw_obj[b] = np.asarray(f_b)
 
     # Monotone propagation, tightest -> loosest: a tighter budget's winner
@@ -468,15 +454,15 @@ def frontier_codesign(
     carry = None
     for i, b in enumerate(asc):
         th_i, f_i = raw[b], raw_obj[b]
-        m_i = machine_arrays_from_theta(np, th_i, fixed_np)
+        m_i = machine_arrays_from_theta(np, th_i, s.fixed)
         feas_i = budget_feasible(np, m_i, cost_model, b, power_budget,
                                  area_envelope=area_envelope)
         k = int(np.argmin(np.where(feas_i, f_i, np.inf))
                 if bool(feas_i.any()) else np.argmin(f_i))
         cand = {
             "obj": float(f_i[k]),
-            "params": params_of_theta(th_i[k], fixed_np, k),
-            "name": mb.names[k],
+            "params": params_of_theta(th_i[k], s.fixed, k),
+            "name": s.machines.names[k],
             "seed": k,
             "feasible": bool(feas_i[k]),
             "area": float(np.asarray(cost_model.area(m_i))[k]),
@@ -496,26 +482,22 @@ def frontier_codesign(
         power_arr[i] = cand["power"]
 
     # First-order implicit sensitivities at each frontier point: the
-    # budget rows act as "variants" (per-row fixed arrays + per-row area
-    # budget), one KKT solve on the converged designs -- see
-    # repro.core.implicit.  Propagated rows have a slack area constraint,
-    # so their shadow price is 0, matching the flat frontier segment.
+    # budget rows act as "variants" (each row its winning seed's suite row
+    # + a per-row area budget), one KKT solve on the converged designs --
+    # see repro.core.implicit.  Propagated rows have a slack area
+    # constraint, so their shadow price is 0, matching the flat frontier
+    # segment.
     dj_db = prices = constraint_names = None
     if sensitivities and bool(feasible_arr.any()):
         from repro.core.implicit import _first_order_report
 
-        row_fixed = K.MachineArrays(**{
-            f: np.array([p[f] for p in best_params], dtype=np.float64)
-            for f in K.MachineArrays._fields})
         theta_rows = np.log(np.stack(
             [[p[f] for f in OPT_FIELDS] for p in best_params]))
         rep = _first_order_report(
-            pb, best_names, row_fixed, theta_rows, lo[seed_idx],
-            hi[seed_idx], area_budget=np.asarray(asc),
+            s.take(seed_idx), theta_rows, area_budget=np.asarray(asc),
             power_budget=power_budget, area_envelope=area_envelope,
-            cost_model=cost_model, beta_np=beta_np,
-            timing_model=timing_model, eps=eps, w_area=w_area,
-            w_power=w_power)
+            cost_model=cost_model, timing_model=timing_model, eps=eps,
+            w_area=w_area, w_power=w_power)
         prices = np.where(feasible_arr[:, None], rep.multipliers, np.nan)
         dj_db = -prices[:, 0]            # area is always column 0 here
         constraint_names = rep.constraint_names
@@ -529,7 +511,7 @@ def frontier_codesign(
         power=power_arr,
         feasible=feasible_arr,
         per_seed_objective=per_seed,
-        seed_names=list(mb.names),
+        seed_names=list(s.machines.names),
         steps=steps,
         refine_steps=refine_steps,
         warm_start=warm_start,

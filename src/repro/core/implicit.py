@@ -15,9 +15,10 @@ everywhere), we apply the implicit function theorem at the KKT point:
   plus the envelope-theorem sensitivities ``dJ*/d(budget) = -lambda`` and
   ``dJ*/d(cost-model weights)``.
 * ``implicit_jstar_fn`` -- a differentiable ``J*(budgets)`` whose forward
-  pass is a rolled ``lax.fori_loop`` descent (trace size independent of
-  ``steps``) and whose backward pass is a custom VJP solving the linearized
-  KKT system directly on the small per-variant theta dimension.
+  pass is the shared compiled descent (``codesign._descent_loop``, one
+  ``lax.scan``: trace size independent of ``steps``) and whose backward
+  pass is a custom VJP solving the linearized KKT system directly on the
+  small per-variant theta dimension.
 * ``unrolled_jstar_fn`` -- the penalty-smoothed unrolled-descent baseline
   the benchmarks compare against (trace grows with ``steps``).
 * ``bilevel_codesign`` -- outer gradient descent on the split of one total
@@ -31,6 +32,7 @@ scalar area, scalar power, then envelope fields sorted by name (see
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -39,22 +41,26 @@ from . import kernels_xp as K
 from .codesign import (
     OPT_FIELDS,
     CodesignResult,
-    _as_batches,
-    _objective_terms,
+    DescentSuite,
+    _compiled,
+    _descent_loop,
+    _jax_x64,
+    _suite_objective,
     backtracking_descent,
+    descent_suite,
     machine_arrays_from_theta,
-    resolve_beta,
-    theta_box,
 )
 from .constrained import (
     _area_posynomial,
+    _budget_args,
+    _budget_retraction,
     _power_posynomial,
+    _set_column,
     constrained_codesign,
     constraint_labels,
-    project_to_budgets,
     validate_area_envelope,
 )
-from .costmodel import DEFAULT_COST_MODEL, RATE_FIELDS, CostModel
+from .costmodel import DEFAULT_COST_MODEL, RATE_FIELDS
 from .spec import resolve_spec
 
 __all__ = [
@@ -71,12 +77,6 @@ __all__ = [
 ACTIVE_RTOL = 1e-5
 #: Absolute log-space slack for "coordinate is pinned at the span box".
 BOX_ATOL = 1e-7
-
-#: theta column per envelope field (``ici_bw_total`` rides on the
-#: per-link ``ici_bw`` column; the link count is a fixed constant here).
-_ENV_COL = {f: j for j, f in
-            enumerate(("peak_flops", "hbm_bw", "ici_bw", "inter_pod_bw"))}
-_ENV_COL["ici_bw_total"] = _ENV_COL.pop("ici_bw")
 
 
 # --------------------------------------------------------------------------- #
@@ -99,42 +99,30 @@ def _constraint_system(xp, theta, fixed, cost_model, area_budget,
     th = theta[:, :d]
     values, grads, budgets = [], [], []
     ones = xp.ones((v,))
-    if area_budget is not None:
-        coeff, expo, offset = _area_posynomial(xp, cost_model, fixed)
-        terms = coeff * xp.exp(expo[None, :] * th)
-        values.append(xp.sum(terms, axis=1) + offset)
-        grads.append(terms * expo[None, :])
-        budgets.append(ones * area_budget)
-    if power_budget is not None:
-        coeff, expo, offset = _power_posynomial(xp, cost_model, fixed)
-        terms = coeff * xp.exp(expo[None, :] * th)
-        values.append(xp.sum(terms, axis=1) + offset)
-        grads.append(terms * expo[None, :])
-        budgets.append(ones * power_budget)
+    for budget, posynomial in ((area_budget, _area_posynomial),
+                               (power_budget, _power_posynomial)):
+        if budget is not None:
+            coeff, expo, offset = posynomial(xp, cost_model, fixed)
+            terms = coeff * xp.exp(expo[None, :] * th)
+            values.append(xp.sum(terms, axis=1) + offset)
+            grads.append(terms * expo[None, :])
+            budgets.append(ones * budget)
     if area_envelope:
         ref = cost_model.reference
         for field in sorted(area_envelope):
-            col = _ENV_COL[field]
+            # ``ici_bw_total`` rides on the per-link ``ici_bw`` column; the
+            # link count is a fixed constant here.
+            col = RATE_FIELDS.index(field)
             scale = (fixed.ici_links / ref.ici_bw_total
                      if field == "ici_bw_total"
                      else 1.0 / getattr(ref, field))
             val = scale * xp.exp(th[:, col])
-            g = xp.zeros((v, d))
-            g = _one_hot_col(xp, g, col, val)
             values.append(val)
-            grads.append(g)
+            grads.append(_set_column(xp, xp.zeros((v, d)), col, val))
             budgets.append(ones * area_envelope[field])
     return (xp.stack(values, axis=1),
             xp.stack(grads, axis=1),
             xp.stack(budgets, axis=1))
-
-
-def _one_hot_col(xp, g, col, val):
-    if xp is np:
-        g = g.copy()
-        g[:, col] = val
-        return g
-    return g.at[:, col].set(val)
 
 
 def _free_mask(xp, theta, lo, hi, atol):
@@ -293,46 +281,38 @@ class SensitivityReport:
 # --------------------------------------------------------------------------- #
 
 
-def _first_order_report(pb, names, fixed_np, theta_np, lo, hi, *,
-                        area_budget, power_budget, area_envelope,
-                        cost_model, beta_np, timing_model, eps,
-                        w_area, w_power, active_rtol=ACTIVE_RTOL,
+def _first_order_report(s: DescentSuite, theta_np, *, area_budget,
+                        power_budget, area_envelope, cost_model,
+                        timing_model, eps, w_area, w_power,
+                        active_rtol=ACTIVE_RTOL,
                         box_atol=BOX_ATOL) -> SensitivityReport:
-    """Assemble a ``SensitivityReport`` from raw arrays (internal: the
-    public entry points and ``frontier_codesign`` both funnel here)."""
-    backend = K.get_backend("jax")
-    jax, jnp = backend._jax, backend._jnp
+    """Assemble a ``SensitivityReport`` at ``theta_np`` over the suite
+    ``s`` (internal: the public entry points and ``frontier_codesign``
+    both funnel here)."""
+    _, jnp, x64 = _jax_x64()
     d = len(OPT_FIELDS)
     theta_np = np.asarray(theta_np, dtype=np.float64)[:, :d]
-
-    with backend._x64():
-        p_arrays = backend.profile_arrays(pb.arrays())
-        fixed = backend.machine_arrays(fixed_np)
-        beta_j = backend.asarray(beta_np)
-
-        def sum_obj(theta):
-            m = machine_arrays_from_theta(jnp, theta, fixed)
-            return jnp.sum(_objective_terms(jnp, p_arrays, m, beta_j,
-                                            timing_model, eps, cost_model,
-                                            w_area, w_power))
-
-        gj = backend.to_numpy(jax.grad(sum_obj)(backend.asarray(theta_np)))
+    settings = dict(timing_model=timing_model, eps=eps,
+                    cost_model=cost_model, w_area=w_area, w_power=w_power)
+    objective = functools.partial(_suite_objective, jnp, **settings)
+    with x64():
+        gj = np.asarray(_compiled(objective, grad=True)(theta_np, *s.args))
 
     values, grads, budgets = _constraint_system(
-        np, theta_np, fixed_np, cost_model, area_budget, power_budget,
+        np, theta_np, s.fixed, cost_model, area_budget, power_budget,
         area_envelope)
     active = values >= budgets * (1.0 - active_rtol)
-    free = _free_mask(np, theta_np, lo, hi, box_atol)
+    free = _free_mask(np, theta_np, s.lo, s.hi, box_atol)
     lam, residual = _nnls_multipliers(gj, grads, active, free)
 
-    m_np = machine_arrays_from_theta(np, theta_np, fixed_np)
+    m_np = machine_arrays_from_theta(np, theta_np, s.fixed)
     area = np.asarray(cost_model.area(m_np))
     power = np.asarray(cost_model.power(m_np))
     with np.errstate(divide="ignore", invalid="ignore"):
-        obj = _objective_terms(np, pb.arrays(), m_np, beta_np, timing_model,
-                               eps, cost_model, w_area, w_power)
+        obj = _suite_objective(np, theta_np, *s.args, **settings)
 
     labels = constraint_labels(area_budget, power_budget, area_envelope)
+    names = s.machines.names
     lam_area = (lam[:, labels.index("area")]
                 if "area" in labels else np.zeros(len(names)))
     lam_power = (lam[:, labels.index("power")]
@@ -394,39 +374,23 @@ def polish_theta(profiles, machines, theta, *, area_budget=None,
     actually is stationary.  ``area_budget`` may be per-variant ``(V,)``.
     """
     area_envelope = validate_area_envelope(area_envelope)
-    backend = K.get_backend("jax")
-    jax, jnp = backend._jax, backend._jnp
-    pb, mb = _as_batches(profiles, machines)
-    fixed_np = mb.arrays()
-    beta_np = resolve_beta(pb, mb, beta, beta_ref)
-    _, lo, hi = theta_box(mb, span)
-    d = len(OPT_FIELDS)
-    theta = np.asarray(theta, dtype=np.float64)[:, :d]
-
-    with backend._x64():
-        p_arrays = backend.profile_arrays(pb.arrays())
-        fixed = backend.machine_arrays(fixed_np)
-        beta_j = backend.asarray(beta_np)
-        lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
-        b_area = (None if area_budget is None
-                  else backend.asarray(np.asarray(area_budget)))
-
-        def objective(th):
-            m = machine_arrays_from_theta(jnp, th, fixed)
-            return _objective_terms(jnp, p_arrays, m, beta_j, timing_model,
-                                    eps, cost_model, w_area, w_power)
-
-        def retract(th):
-            out, _ = project_to_budgets(jnp, th, lo_j, hi_j, fixed,
-                                        cost_model, b_area, power_budget,
-                                        area_envelope=area_envelope,
-                                        method=projection)
-            return out
-
-        seed = retract(backend.asarray(theta))
+    jax, jnp, x64 = _jax_x64()
+    s = descent_suite(profiles, machines, beta=beta, beta_ref=beta_ref,
+                      span=span)
+    theta = np.asarray(theta, dtype=np.float64)[:, :len(OPT_FIELDS)]
+    objective = functools.partial(
+        _suite_objective, jnp, timing_model=timing_model, eps=eps,
+        cost_model=cost_model, w_area=w_area, w_power=w_power)
+    retract = functools.partial(_budget_retraction, jnp,
+                                cost_model=cost_model, method=projection)
+    rargs = (s.lo, s.hi, s.fixed) + _budget_args(area_budget, power_budget,
+                                                 area_envelope)
+    with x64():
+        seed = _compiled(retract)(theta, *rargs)
         th, f_cur, _, _, _ = backtracking_descent(
-            jax, jnp, seed, objective, steps, lr, retract=retract)
-        return backend.to_numpy(th), np.asarray(f_cur)
+            jax, jnp, seed, objective, steps, lr, retract=retract,
+            obj_args=s.args, retract_args=rargs)
+        return np.asarray(th), np.asarray(f_cur)
 
 
 def implicit_sensitivities(profiles, machines, theta=None, *,
@@ -450,24 +414,21 @@ def implicit_sensitivities(profiles, machines, theta=None, *,
     if area_budget is None and power_budget is None and not area_envelope:
         raise ValueError("implicit_sensitivities needs at least one of "
                          "area_budget, power_budget, area_envelope")
-    pb, mb = _as_batches(profiles, machines)
-    fixed_np = mb.arrays()
-    beta_np = resolve_beta(pb, mb, beta, beta_ref)
-    theta0, lo, hi = theta_box(mb, span)
-    theta = theta0 if theta is None else np.asarray(theta, np.float64)
+    s = descent_suite(profiles, machines, beta=beta, beta_ref=beta_ref,
+                      span=span)
+    theta = s.theta0 if theta is None else np.asarray(theta, np.float64)
     if polish_steps:
         theta, _ = polish_theta(
-            profiles, mb, theta, area_budget=area_budget,
+            s.profiles, s.machines, theta, area_budget=area_budget,
             power_budget=power_budget, area_envelope=area_envelope,
             steps=polish_steps, lr=lr, span=span, projection=projection,
             beta=beta, beta_ref=beta_ref, timing_model=timing_model,
             eps=eps, cost_model=cost_model, w_area=w_area, w_power=w_power)
     return _first_order_report(
-        pb, mb.names, fixed_np, theta, lo, hi, area_budget=area_budget,
-        power_budget=power_budget, area_envelope=area_envelope,
-        cost_model=cost_model, beta_np=beta_np, timing_model=timing_model,
-        eps=eps, w_area=w_area, w_power=w_power, active_rtol=active_rtol,
-        box_atol=box_atol)
+        s, theta, area_budget=area_budget, power_budget=power_budget,
+        area_envelope=area_envelope, cost_model=cost_model,
+        timing_model=timing_model, eps=eps, w_area=w_area, w_power=w_power,
+        active_rtol=active_rtol, box_atol=box_atol)
 
 
 def sensitivities_of(result: CodesignResult, profiles, *, span=16.0,
@@ -499,30 +460,30 @@ def sensitivities_of(result: CodesignResult, profiles, *, span=16.0,
     finals = batch(result.final_params)
     theta = np.log(np.stack(
         [[p[f] for f in OPT_FIELDS] for p in result.final_params]))
-    pb, _ = _as_batches(profiles, seeds)
-    beta_np = resolve_beta(pb, seeds, beta, beta_ref)
-    _, lo, hi = theta_box(seeds, span)
+    s = descent_suite(profiles, seeds, beta=beta, beta_ref=beta_ref,
+                      span=span)
     if polish_steps:
         if not np.allclose(seeds.ici_links, finals.ici_links):
             raise ValueError("polish is not supported for link-optimized "
                              "results (the integral link count is frozen)")
         theta, _ = polish_theta(
-            profiles, seeds, theta, area_budget=result.area_budget,
+            s.profiles, seeds, theta, area_budget=result.area_budget,
             power_budget=result.power_budget,
             area_envelope=result.area_envelope, steps=polish_steps,
             span=span, beta=beta, beta_ref=beta_ref,
             timing_model=timing_model, eps=eps, cost_model=cost_model,
             w_area=result.w_area, w_power=result.w_power, **overrides)
+    # The seeds' box and beta, the final designs' (rounded) link counts.
     return _first_order_report(
-        pb, result.names, finals.arrays(), theta, lo, hi,
+        dataclasses.replace(s, fixed=finals.arrays()), theta,
         area_budget=result.area_budget, power_budget=result.power_budget,
         area_envelope=result.area_envelope, cost_model=cost_model,
-        beta_np=beta_np, timing_model=timing_model, eps=eps,
-        w_area=result.w_area, w_power=result.w_power)
+        timing_model=timing_model, eps=eps, w_area=result.w_area,
+        w_power=result.w_power)
 
 
 # --------------------------------------------------------------------------- #
-# Differentiable J*(budgets): rolled forward solve + KKT custom VJP
+# Differentiable J*(budgets): the shared compiled descent + KKT custom VJP
 # --------------------------------------------------------------------------- #
 
 
@@ -536,73 +497,47 @@ def implicit_jstar_fn(profiles, machines, *, steps=80, lr=0.1, span=16.0,
 
     ``budgets`` is a length-2 array ``[area_budget, power_budget]``.  The
     forward pass runs ``steps`` backtracking projected-descent iterations
-    inside one ``lax.fori_loop`` (the traced graph does NOT grow with
-    ``steps`` -- pinned by the structure regression test); the backward
-    pass ignores the solver entirely and returns the envelope-theorem
-    cotangent ``b_bar = -sum_v y_bar_v * lambda_v`` with multipliers from
-    a direct ridge solve of the linearized KKT system (``C <= 6``
-    constraints, ``D = 4`` theta coordinates per variant).
+    through the loop every co-design mode shares (``_descent_loop``, one
+    ``lax.scan``: the traced graph does NOT grow with ``steps`` -- pinned
+    by the structure regression test); the backward pass ignores the
+    solver entirely and returns the envelope-theorem cotangent
+    ``b_bar = -sum_v y_bar_v * lambda_v`` with multipliers from a direct
+    ridge solve of the linearized KKT system (``C <= 6`` constraints,
+    ``D = 4`` theta coordinates per variant).
     """
     area_envelope = validate_area_envelope(area_envelope)
-    backend = K.get_backend("jax")
-    jax, jnp = backend._jax, backend._jnp
-    pb, mb = _as_batches(profiles, machines)
-    fixed_np = mb.arrays()
-    beta_np = resolve_beta(pb, mb, beta, beta_ref)
-    theta0, lo, hi = theta_box(mb, span)
-
-    with backend._x64():
-        p_arrays = backend.profile_arrays(pb.arrays())
-        fixed = backend.machine_arrays(fixed_np)
-        beta_j = backend.asarray(beta_np)
-        theta0_j = backend.asarray(theta0)
-        lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
-
-    def per_variant_obj(theta):
-        m = machine_arrays_from_theta(jnp, theta, fixed)
-        return _objective_terms(jnp, p_arrays, m, beta_j, timing_model,
-                                eps, cost_model, w_area, w_power)
-
-    grad_obj = jax.grad(lambda th: jnp.sum(per_variant_obj(th)))
+    jax, jnp, x64 = _jax_x64()
+    s = descent_suite(profiles, machines, beta=beta, beta_ref=beta_ref,
+                      span=span)
+    objective = functools.partial(
+        _suite_objective, jnp, timing_model=timing_model, eps=eps,
+        cost_model=cost_model, w_area=w_area, w_power=w_power)
+    retract = functools.partial(_budget_retraction, jnp,
+                                cost_model=cost_model, method=projection)
+    descend = _descent_loop(jax, jnp, objective, retract, None, steps)
 
     def solve(b):
-        def retract(th):
-            out, _ = project_to_budgets(jnp, th, lo_j, hi_j, fixed,
-                                        cost_model, b[0], b[1],
-                                        area_envelope=area_envelope,
-                                        method=projection)
-            return out
-
-        def body(_, state):
-            th, f, lrs = state
-            cand = retract(th - lrs[:, None] * grad_obj(th))
-            f_new = per_variant_obj(cand)
-            ok = f_new < f
-            return (jnp.where(ok[:, None], cand, th),
-                    jnp.where(ok, f_new, f),
-                    jnp.where(ok, lrs * 1.2, lrs * 0.5))
-
-        seed = retract(theta0_j)
-        init = (seed, per_variant_obj(seed),
-                jnp.full((theta0_j.shape[0],), lr))
-        th, _, _ = jax.lax.fori_loop(0, steps, body, init)
-        return th
+        lr_v = jnp.full((s.theta0.shape[0],), lr)
+        theta, _, _, _, _ = descend(
+            s.theta0, lr_v, s.args,
+            (s.lo, s.hi, s.fixed, b[0], b[1], area_envelope))
+        return theta
 
     @jax.custom_vjp
     def jstar(b):
-        return per_variant_obj(solve(b))
+        return objective(solve(b), *s.args)
 
     def fwd(b):
         th = solve(b)
-        return per_variant_obj(th), (th, b)
+        return objective(th, *s.args), (th, b)
 
     def bwd(res, ybar):
         th, b = res
-        gj = grad_obj(th)
+        gj = _compiled(objective, grad=True)(th, *s.args)
         values, grads, budgets = _constraint_system(
-            jnp, th, fixed, cost_model, b[0], b[1], area_envelope)
+            jnp, th, s.fixed, cost_model, b[0], b[1], area_envelope)
         active = values >= budgets * (1.0 - active_rtol)
-        free = _free_mask(jnp, th, lo_j, hi_j, box_atol)
+        free = _free_mask(jnp, th, s.lo, s.hi, box_atol)
         lam = _ridge_multipliers(jnp, gj, grads, active, free)
         # dJ*_v/db_i = -lambda_{v,i}; the scalar budgets are columns 0, 1.
         bbar = -jnp.sum(ybar[:, None] * lam[:, :2], axis=0)
@@ -611,10 +546,23 @@ def implicit_jstar_fn(profiles, machines, *, steps=80, lr=0.1, span=16.0,
     jstar.defvjp(fwd, bwd)
 
     def fn(budgets):
-        with backend._x64():
+        with x64():
             return jstar(jnp.asarray(budgets, dtype=jnp.float64))
 
     return fn
+
+
+def _penalized_objective(xp, theta, b, p, fixed, beta, *, penalty,
+                         **settings):
+    """J plus ``penalty * (relu(area/b[0] - 1)^2 + relu(power/b[1] - 1)^2)``:
+    the unrolled baseline's smoothing of the budget projections
+    (``settings`` are ``_suite_objective``'s)."""
+    cost_model = settings["cost_model"]
+    m = machine_arrays_from_theta(xp, theta, fixed)
+    viol_a = xp.maximum(cost_model.area(m) / b[0] - 1.0, 0.0)
+    viol_p = xp.maximum(cost_model.power(m) / b[1] - 1.0, 0.0)
+    return (_suite_objective(xp, theta, p, fixed, beta, **settings)
+            + penalty * (viol_a ** 2 + viol_p ** 2))
 
 
 def unrolled_jstar_fn(profiles, machines, *, steps=40, lr=0.05, span=16.0,
@@ -633,41 +581,22 @@ def unrolled_jstar_fn(profiles, machines, *, steps=40, lr=0.05, span=16.0,
     price.  Used by ``benchmarks/run.py sensitivity`` and the structure
     regression test as the thing the implicit VJP avoids.
     """
-    backend = K.get_backend("jax")
-    jax, jnp = backend._jax, backend._jnp
-    pb, mb = _as_batches(profiles, machines)
-    fixed_np = mb.arrays()
-    beta_np = resolve_beta(pb, mb, beta, beta_ref)
-    theta0, lo, hi = theta_box(mb, span)
-
-    with backend._x64():
-        p_arrays = backend.profile_arrays(pb.arrays())
-        fixed = backend.machine_arrays(fixed_np)
-        beta_j = backend.asarray(beta_np)
-        theta0_j = backend.asarray(theta0)
-        lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
-
-    def per_variant_obj(theta):
-        m = machine_arrays_from_theta(jnp, theta, fixed)
-        return _objective_terms(jnp, p_arrays, m, beta_j, timing_model,
-                                eps, cost_model, w_area, w_power)
-
-    def penalized(theta, b):
-        m = machine_arrays_from_theta(jnp, theta, fixed)
-        viol_a = jnp.maximum(cost_model.area(m) / b[0] - 1.0, 0.0)
-        viol_p = jnp.maximum(cost_model.power(m) / b[1] - 1.0, 0.0)
-        return (per_variant_obj(theta)
-                + penalty * (viol_a ** 2 + viol_p ** 2))
-
-    grad_pen = jax.grad(lambda th, b: jnp.sum(penalized(th, b)))
+    jax, jnp, x64 = _jax_x64()
+    s = descent_suite(profiles, machines, beta=beta, beta_ref=beta_ref,
+                      span=span)
+    penalized = functools.partial(
+        _penalized_objective, jnp, penalty=penalty,
+        timing_model=timing_model, eps=eps, cost_model=cost_model,
+        w_area=w_area, w_power=w_power)
+    grad_pen = jax.grad(lambda th, *a: jnp.sum(penalized(th, *a)))
 
     def fn(budgets):
-        with backend._x64():
+        with x64():
             b = jnp.asarray(budgets, dtype=jnp.float64)
-            th = theta0_j
+            th = s.theta0
             for _ in range(steps):        # deliberately unrolled
-                th = jnp.clip(th - lr * grad_pen(th, b), lo_j, hi_j)
-            return penalized(th, b)
+                th = jnp.clip(th - lr * grad_pen(th, b, *s.args), s.lo, s.hi)
+            return penalized(th, b, *s.args)
 
     return fn
 
@@ -782,8 +711,7 @@ def bilevel_codesign(profiles, machines, *, spec=None, **explicit
                          f"{1 - _SPLIT_MIN}], got {split0!r}")
     outer_steps, outer_lr = int(cfg["outer_steps"]), float(cfg["outer_lr"])
 
-    backend = K.get_backend("jax")
-    jax, jnp = backend._jax, backend._jnp
+    jax, jnp, x64 = _jax_x64()
     inner_kw = dict(steps=cfg["steps"], lr=cfg["lr"], span=cfg["span"],
                     projection=cfg["projection"], beta=cfg["beta"],
                     timing_model=cfg["timing_model"],
@@ -796,7 +724,7 @@ def bilevel_codesign(profiles, machines, *, spec=None, **explicit
         b = jnp.stack([s * total, (1.0 - s) * total])
         return jnp.min(jstar(b))
 
-    with backend._x64():
+    with x64():
         val_grad = jax.jit(jax.value_and_grad(outer))
         s = split0
         f, g = (float(x) for x in val_grad(s))

@@ -38,6 +38,7 @@ serve through ``repro.serving`` unchanged.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Mapping, Optional, Sequence
 
 import numpy as np
@@ -45,24 +46,26 @@ import numpy as np
 from repro.core import kernels_xp as K
 from repro.core.codesign import (
     OPT_FIELDS,
-    _as_batches,
-    _objective_terms,
+    _compiled,
+    _jax_x64,
+    _suite_aggregate,
+    _suite_objective,
     backtracking_descent,
+    descent_suite,
     machine_arrays_from_theta,
     params_of_theta,
-    resolve_beta,
-    theta_box,
 )
 from repro.core.constrained import (
     FEASIBLE_RTOL,
     PROJECT_ITERS,
-    _iterate,
+    _bisect,
+    _budget_args,
     budget_feasible,
     project_to_budgets,
     validate_area_envelope,
 )
 from repro.core.costmodel import DEFAULT_COST_MODEL, CostModel
-from repro.core.sweep import MachineBatch
+from repro.core.sweep import MachineBatch, _as_machine_batch
 
 #: Packing assignment modes (mirrors ``joint_codesign``).
 PACK_MODES = ("alternate", "softmax")
@@ -119,12 +122,10 @@ def fleet_objective(
     copies of the best single-machine design (the acceptance pin in
     tests/test_packing.py).
     """
-    pb, mb = _as_batches(profiles, machines)
-    beta_np = resolve_beta(pb, mb, beta, beta_ref)
-    p, m = pb.arrays(), mb.arrays()
-    out = K.congruence_kernel(np, p, m, beta_np, timing_model, eps,
-                              clamp=False)
-    agg = np.asarray(out.aggregate)
+    s = descent_suite(profiles, machines, beta=beta, beta_ref=beta_ref)
+    m = s.fixed
+    agg = np.asarray(K.congruence_kernel(np, s.p, m, s.beta, timing_model,
+                                         eps, clamp=False).aggregate)
     fit = float(agg.min(axis=1).mean())
     return (fit + w_area * float(np.sum(cost_model.area(m)))
             + w_power * float(np.sum(cost_model.power(m))))
@@ -163,17 +164,33 @@ def _fleet_shift(xp, th, lo, fixed, cost_model: CostModel, area_budget,
         return good
 
     zero = xp.zeros(())
-    t_floor = xp.max(th - lo)
-    ok0 = ok(zero)
+    t_hi = _bisect(xp, ok, xp.max(th - lo), iters)
+    return at(xp.where(ok(zero), zero, t_hi))
 
-    def bisect_step(_, bracket):
-        t_lo, t_hi = bracket
-        mid = 0.5 * (t_lo + t_hi)
-        okm = ok(mid)
-        return (xp.where(okm, t_lo, mid), xp.where(okm, mid, t_hi))
 
-    _, t_hi = _iterate(xp, bisect_step, (zero, t_floor), iters)
-    return at(xp.where(ok0, zero, t_hi))
+def _fleet_retraction(xp, th_flat, lo, hi, fixed, area_budget,
+                      power_budget, area_envelope, *, cost_model: CostModel):
+    """Retract the flattened ``(1, M*D)`` fleet: each machine onto its box
+    ∩ envelope first (a clip with no envelope), then the fleet-total shift
+    when a total budget is set -- which only lowers rates, keeping the
+    per-machine feasibility just won.  Every array and budget is an
+    argument."""
+    th, _ = project_to_budgets(xp, th_flat.reshape(lo.shape), lo, hi, fixed,
+                               cost_model, None, None,
+                               area_envelope=area_envelope)
+    if area_budget is not None or power_budget is not None:
+        th = _fleet_shift(xp, th, lo, fixed, cost_model, area_budget,
+                          power_budget)
+    return th.reshape(1, -1)
+
+
+def _fleet_objective(xp, th_flat, p, fixed, beta, weights, **settings):
+    """The fleet's J as one ``(1,)`` row for the shared descent: summing
+    the per-machine terms folds the assignment-weighted fit (rows of
+    ``weights`` sum to 1/A) and the fleet silicon into one scalar."""
+    theta = th_flat.reshape(fixed.peak_flops.shape[0], -1)
+    return xp.sum(_suite_objective(xp, theta, p, fixed, beta, weights,
+                                   **settings))[None]
 
 
 def _fleet_feasible(m: K.MachineArrays, cost_model: CostModel,
@@ -439,127 +456,86 @@ def pack_codesign(
         raise ValueError("pass either area_budget (one fleet budget) or "
                          "budgets (a frontier schedule), not both")
 
-    backend = K.get_backend("jax")
-    jax, jnp = backend._jax, backend._jnp
-
-    pb, seed_mb = _as_batches(profiles, machines)
-    if len(seed_mb) == 0:
+    jax, jnp, x64 = _jax_x64()
+    seeds = _as_machine_batch(machines)
+    if len(seeds) == 0:
         raise ValueError("pack_codesign needs at least one seed machine")
-    idx = np.arange(num_machines) % len(seed_mb)
-    fleet_mb = seed_mb.take(idx)
-    fleet_mb = MachineBatch(
-        names=[f"pack{i}-{n}" for i, n in enumerate(fleet_mb.names)],
-        **{f: getattr(fleet_mb, f) for f in
-           ("peak_flops", "hbm_bw", "ici_bw", "ici_links", "inter_pod_bw",
-            "scale_compute", "scale_memory", "scale_interconnect")})
-    fixed_np = fleet_mb.arrays()
-    beta_np = resolve_beta(pb, seed_mb, beta, beta_ref)
-    theta0, lo, hi = theta_box(fleet_mb, span)
-    n_rates = theta0.shape[1]
-    n_apps, n_mach = len(pb), num_machines
+    # Beta derives from the seeds; the fleet's instances cycle the seeds'
+    # rows of the suite.
+    s = descent_suite(profiles, seeds, beta=beta, beta_ref=beta_ref,
+                      span=span)
+    s = s.take(np.arange(num_machines) % len(s.machines))
+    fleet_names = [f"pack{i}-{n}" for i, n in enumerate(s.machines.names)]
+    n_rates = s.theta0.shape[1]
+    n_apps, n_mach = len(s.profiles), num_machines
     # The fleet-total budget couples every machine, so the whole fleet
     # descends as ONE (1, M*D) row: scalar objective, global acceptance.
-    theta0_flat = theta0.reshape(1, -1)
+    theta0_flat = s.theta0.reshape(1, -1)
     swept_budget = schedule is not None
     constrained = area_budget is not None or power_budget is not None
+    settings = dict(timing_model=timing_model, eps=eps,
+                    cost_model=cost_model, w_area=w_area, w_power=w_power)
+    objective = functools.partial(_fleet_objective, jnp, **settings)
+    retract = functools.partial(_fleet_retraction, jnp,
+                                cost_model=cost_model)
 
-    with backend._x64():
-        p_arrays = backend.profile_arrays(pb.arrays())
-        fixed = backend.machine_arrays(fixed_np)
-        beta_j = backend.asarray(beta_np)
-        lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
+    def assign(th_flat, temp=None):
+        """Hard (or, at ``temp``, softmax) assignment weights at a fleet."""
+        agg = np.asarray(_suite_aggregate(
+            jnp, th_flat.reshape(n_mach, n_rates), *s.args,
+            timing_model=timing_model, eps=eps))
+        return _pack_weights(agg) if temp is None else _soft_weights(agg, temp)
 
-        def retract_flat(th_flat, *budget_arg):
-            th = th_flat.reshape(n_mach, n_rates)
-            # Per-machine box ∩ envelope first (reduces to a clip with no
-            # envelope), then the fleet-total shift -- which only lowers
-            # rates, preserving the per-machine feasibility just won.
-            th, _ = project_to_budgets(
-                jnp, th, lo_j, hi_j, fixed, cost_model, None, None,
-                area_envelope=envelope)
-            if budget_arg:
-                th = _fleet_shift(jnp, th, lo_j, fixed, cost_model,
-                                  budget_arg[0], power_budget)
-            elif constrained:
-                th = _fleet_shift(jnp, th, lo_j, fixed, cost_model,
-                                  area_budget, power_budget)
-            return th.reshape(1, -1)
+    steps_round = max(1, steps // max(rounds + 1, 1))
 
-        def objective_with(th_flat, weights):
-            m = machine_arrays_from_theta(
-                jnp, th_flat.reshape(n_mach, n_rates), fixed)
-            # Summing the per-machine terms folds the assignment-weighted
-            # fit (rows of ``weights`` sum to 1/A) and the fleet silicon
-            # into one scalar J, shape (1,) for the shared descent.
-            terms = _objective_terms(jnp, p_arrays, m, beta_j, timing_model,
-                                     eps, cost_model, w_area, w_power,
-                                     app_weights=weights)
-            return jnp.sum(terms)[None]
+    def solve(theta_start, w_start, lr_start, area_b):
+        """One full alternation (rounds + polish) at a fixed budget.
 
-        def aggregate_np(th_flat):
-            m = machine_arrays_from_theta(
-                jnp, th_flat.reshape(n_mach, n_rates), fixed)
-            out = K.congruence_kernel(jnp, p_arrays, m, beta_j, timing_model,
-                                      eps, clamp=False)
-            return np.asarray(out.aggregate)
-
-        cache: dict = {}
-        steps_round = max(1, steps // max(rounds + 1, 1))
-
-        def solve(theta_start, w_start, lr_start, rargs):
-            """One full alternation (rounds + polish) at a fixed budget.
-
-            Returns ``(theta, w_hard, f_final, history, lr)`` with the
-            incumbent guarantee: ``f_final`` never exceeds the seed J.
-            """
-            theta = retract_flat(theta_start, *rargs)
-            w_hard = (_pack_weights(aggregate_np(theta)) if w_start is None
-                      else w_start)
-            f_seed = np.asarray(objective_with(theta,
-                                               backend.asarray(w_hard)))
-            history: List[np.ndarray] = [f_seed]
-            best_theta, best_f = theta, jnp.asarray(f_seed)
-            lr_v = lr_start
-            temps = np.geomspace(temp0, max(temp_min, 1e-6), max(rounds, 1))
-            for ri in range(rounds):
-                w_round = (w_hard if mode == "alternate"
-                           else _soft_weights(aggregate_np(theta),
-                                              float(temps[ri])))
-                theta, _, hist, _, lr_v = backtracking_descent(
-                    jax, jnp, theta, objective_with, steps_round, lr_v,
-                    retract=retract_flat,
-                    obj_args=(backend.asarray(w_round),),
-                    retract_args=rargs, cache=cache)
-                if mode == "alternate":
-                    history.extend(hist[1:])
-                w_hard = _pack_weights(aggregate_np(theta))
-                f_bound = np.asarray(objective_with(
-                    theta, backend.asarray(w_hard)))
-                history.append(f_bound)
-                better = jnp.asarray(f_bound) < best_f
-                best_theta = jnp.where(better[:, None], theta, best_theta)
-                best_f = jnp.minimum(jnp.asarray(f_bound), best_f)
-            # Polish from the incumbent under its hard assignment.
-            theta = best_theta
-            w_hard = _pack_weights(aggregate_np(theta))
+        Returns ``(theta, w_hard, f_final, history, lr)`` with the
+        incumbent guarantee: ``f_final`` never exceeds the seed J.
+        """
+        rargs = (s.lo, s.hi, s.fixed) + _budget_args(area_b, power_budget,
+                                                     envelope)
+        theta = _compiled(retract)(theta_start, *rargs)
+        w_hard = assign(theta) if w_start is None else w_start
+        f_seed = np.asarray(objective(theta, *s.args, w_hard))
+        history: List[np.ndarray] = [f_seed]
+        best_theta, best_f = theta, jnp.asarray(f_seed)
+        lr_v = lr_start
+        temps = np.geomspace(temp0, max(temp_min, 1e-6), max(rounds, 1))
+        for ri in range(rounds):
+            w_round = (w_hard if mode == "alternate"
+                       else assign(theta, float(temps[ri])))
             theta, _, hist, _, lr_v = backtracking_descent(
-                jax, jnp, theta, objective_with, steps_round, lr_v,
-                retract=retract_flat,
-                obj_args=(backend.asarray(w_hard),),
-                retract_args=rargs, cache=cache)
-            history.extend(hist[1:])
-            w_hard = _pack_weights(aggregate_np(theta))
-            f_final = np.asarray(objective_with(theta,
-                                                backend.asarray(w_hard)))
-            history.append(f_final)
-            return theta, w_hard, f_final, history, lr_v
+                jax, jnp, theta, objective, steps_round, lr_v,
+                retract=retract, obj_args=(*s.args, w_round),
+                retract_args=rargs)
+            if mode == "alternate":
+                history.extend(hist[1:])
+            w_hard = assign(theta)
+            f_bound = np.asarray(objective(theta, *s.args, w_hard))
+            history.append(f_bound)
+            better = jnp.asarray(f_bound) < best_f
+            best_theta = jnp.where(better[:, None], theta, best_theta)
+            best_f = jnp.minimum(jnp.asarray(f_bound), best_f)
+        # Polish from the incumbent under its hard assignment.
+        theta = best_theta
+        w_hard = assign(theta)
+        theta, _, hist, _, lr_v = backtracking_descent(
+            jax, jnp, theta, objective, steps_round, lr_v,
+            retract=retract, obj_args=(*s.args, w_hard),
+            retract_args=rargs)
+        history.extend(hist[1:])
+        w_hard = assign(theta)
+        f_final = np.asarray(objective(theta, *s.args, w_hard))
+        history.append(f_final)
+        return theta, w_hard, f_final, history, lr_v
 
+    with x64():
         if schedule is None:
-            rargs = ((backend.asarray(float(area_budget)),)
-                     if area_budget is not None else ())
             theta, w_hard, f_final, history, _ = solve(
-                backend.asarray(theta0_flat), None, lr, rargs)
-            theta_np = backend.to_numpy(theta)
+                theta0_flat, None, lr, area_budget)
+            theta_np = np.asarray(theta)
             obj_seed = float(history[0][0])
             obj_final = float(f_final[0])
             frontier = None
@@ -567,17 +543,16 @@ def pack_codesign(
             # Loosest -> tightest continuation: the budget is a traced
             # scalar, so every schedule point reuses one compiled descent.
             solved: Dict[float, dict] = {}
-            theta_w, w_w, lr_w = backend.asarray(theta0_flat), None, lr
+            theta_w, w_w, lr_w = theta0_flat, None, lr
             obj_seed = None
             for b in sorted(schedule, reverse=True):
-                rargs = (backend.asarray(float(b)),)
                 theta_w, w_w, f_b, hist_b, lr_w = solve(
-                    theta_w, w_w, lr_w, rargs)
+                    theta_w, w_w, lr_w, b)
                 if obj_seed is None:
                     obj_seed = float(hist_b[0][0])
-                th_b = backend.to_numpy(theta_w)
+                th_b = np.asarray(theta_w)
                 m_b = machine_arrays_from_theta(
-                    np, th_b.reshape(n_mach, n_rates), fixed_np)
+                    np, th_b.reshape(n_mach, n_rates), s.fixed)
                 solved[b] = dict(
                     theta=th_b, w=w_w, obj=float(f_b[0]), history=hist_b,
                     area=float(np.sum(cost_model.area(m_b))),
@@ -610,9 +585,10 @@ def pack_codesign(
                                      for b in sorted(schedule)]))
             area_budget = tightest
 
-    final_m = machine_arrays_from_theta(np, theta_np.reshape(n_mach, n_rates),
-                                        fixed_np)
-    agg_final = _final_aggregate(pb, final_m, beta_np, timing_model, eps)
+    theta_rows = theta_np.reshape(n_mach, n_rates)
+    final_m = machine_arrays_from_theta(np, theta_rows, s.fixed)
+    agg_final = _suite_aggregate(np, theta_rows, *s.args,
+                                 timing_model=timing_model, eps=eps)
     assignment = np.argmin(agg_final, axis=1)
     per_app = agg_final[np.arange(n_apps), assignment]
     area_total = float(np.sum(cost_model.area(final_m)))
@@ -620,27 +596,24 @@ def pack_codesign(
     feasible = (_fleet_feasible(final_m, cost_model, area_budget,
                                 power_budget, envelope)
                 if (constrained or swept_budget or envelope) else None)
-    theta_rows = theta_np.reshape(n_mach, n_rates)
+    final_params = [params_of_theta(theta_rows[i], s.fixed, i)
+                    for i in range(n_mach)]
     final_machines = MachineBatch(
-        names=list(fleet_mb.names),
-        **{f: np.array([params_of_theta(theta_rows[i], fixed_np, i)[f]
-                        for i in range(n_mach)])
+        names=list(fleet_names),
+        **{f: np.array([row[f] for row in final_params])
            for f in OPT_FIELDS},
-        ici_links=np.asarray(fixed_np.ici_links, dtype=np.float64),
-        scale_compute=np.asarray(fixed_np.scale_compute, dtype=np.float64),
-        scale_memory=np.asarray(fixed_np.scale_memory, dtype=np.float64),
-        scale_interconnect=np.asarray(fixed_np.scale_interconnect,
-                                      dtype=np.float64))
+        ici_links=s.fixed.ici_links, scale_compute=s.fixed.scale_compute,
+        scale_memory=s.fixed.scale_memory,
+        scale_interconnect=s.fixed.scale_interconnect)
 
     res = PackingResult(
-        app_names=list(pb.names),
-        machine_names=list(fleet_mb.names),
+        app_names=list(s.profiles.names),
+        machine_names=list(fleet_names),
         assignment=assignment,
         machines=final_machines,
-        seed_params=[params_of_theta(theta0[i], fixed_np, i)
+        seed_params=[params_of_theta(s.theta0[i], s.fixed, i)
                      for i in range(n_mach)],
-        final_params=[params_of_theta(theta_rows[i], fixed_np, i)
-                      for i in range(n_mach)],
+        final_params=final_params,
         objective_seed=obj_seed,
         objective_final=obj_final,
         trajectory=np.concatenate([np.atleast_1d(h) for h in history]),
@@ -665,10 +638,3 @@ def pack_codesign(
         res.frontier_feasible = frontier["feasible"]
     return res
 
-
-def _final_aggregate(pb, m: K.MachineArrays, beta_np, timing_model: str,
-                     eps: float) -> np.ndarray:
-    """(A, M) aggregate matrix at the final fleet (NumPy, reporting path)."""
-    out = K.congruence_kernel(np, pb.arrays(), m, beta_np, timing_model, eps,
-                              clamp=False)
-    return np.asarray(out.aggregate)

@@ -92,20 +92,15 @@ def test_objective_gradient_matches_finite_differences():
     from conftest import gradcheck
 
     from repro.core.codesign import (
-        _as_batches,
         _objective_terms,
+        descent_suite,
         machine_arrays_from_theta,
-        resolve_beta,
-        theta_box,
     )
     from repro.core.costmodel import DEFAULT_COST_MODEL
     from repro.core.kernels_xp import IDEAL_EPS, get_backend
 
-    profiles = synthetic_suite()
-    pb, mb = _as_batches(profiles, MachineBatch.from_models(VARIANTS))
-    fixed_np = mb.arrays()
-    beta_np = resolve_beta(pb, mb, None, 0)
-    theta0, _, _ = theta_box(mb, 16.0)
+    s = descent_suite(synthetic_suite(), MachineBatch.from_models(VARIANTS))
+    pb, fixed_np, beta_np, theta0 = s.profiles, s.fixed, s.beta, s.theta0
     backend = get_backend("jax")
     jax, jnp = backend._jax, backend._jnp
 
@@ -202,7 +197,8 @@ def eager_descent(jax, jnp, theta0, obj_fn, steps, lr, retract, aux_fn=None,
     lr_v = jnp.broadcast_to(jnp.asarray(lr, dtype=theta.dtype),
                             (theta.shape[0],))
     history = [np.asarray(f_cur)]
-    aux = [] if aux_j is None else [np.asarray(aux_j(theta))]
+    aux = ([] if aux_j is None
+           else [np.asarray(aux_j(theta, *retract_args))])
     for _ in range(steps):
         g = grad_j(theta, *obj_args)
         cand = retract_j(theta - lr_v[:, None] * g, *retract_args)
@@ -213,21 +209,19 @@ def eager_descent(jax, jnp, theta0, obj_fn, steps, lr, retract, aux_fn=None,
         lr_v = jnp.where(ok, lr_v * 1.2, lr_v * 0.5)
         history.append(np.asarray(f_cur))
         if aux_j is not None:
-            aux.append(np.asarray(aux_j(theta)))
+            aux.append(np.asarray(aux_j(theta, *retract_args)))
     return theta, f_cur, history, aux, lr_v
 
 
 def descent_problem(mode):
     """The inputs ``grad_codesign`` descends (``"clip"``: the span box, a
     scalar ``lr``), or a projected budget descent (``"projected"``: the
-    budget a traced retraction argument, the violation as ``aux_fn``, a
-    ``(V,)`` ``lr``)."""
+    budget a traced retraction argument, the violation under it as
+    ``aux_fn``, a ``(V,)`` ``lr``)."""
     from repro.core.codesign import (
-        _as_batches,
         _suite_objective,
+        descent_suite,
         machine_arrays_from_theta,
-        resolve_beta,
-        theta_box,
     )
     from repro.core.constrained import budget_violation, project_to_budgets
     from repro.core.costmodel import DEFAULT_COST_MODEL
@@ -235,14 +229,14 @@ def descent_problem(mode):
 
     backend = get_backend("jax")
     jax, jnp = backend._jax, backend._jnp
-    pb, mb = _as_batches(random_profiles(5, seed=61),
-                         MachineBatch.from_models(VARIANTS))
-    theta0, lo, hi = theta_box(mb, 16.0)
+    s = descent_suite(random_profiles(5, seed=61),
+                      MachineBatch.from_models(VARIANTS))
+    theta0, lo, hi = s.theta0, s.lo, s.hi
     with backend._x64():
-        fixed = backend.machine_arrays(mb.arrays())
+        fixed = backend.machine_arrays(s.fixed)
         lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
-        suite = (backend.profile_arrays(pb.arrays()), fixed,
-                 backend.asarray(resolve_beta(pb, mb, None, 0)))
+        suite = (backend.profile_arrays(s.p), fixed,
+                 backend.asarray(s.beta))
         objective = functools.partial(
             _suite_objective, jnp, timing_model="serial", eps=IDEAL_EPS,
             cost_model=DEFAULT_COST_MODEL, w_area=0.1, w_power=0.05)
@@ -256,12 +250,12 @@ def descent_problem(mode):
                                         DEFAULT_COST_MODEL, budget, None)
             return out
 
-        def violation(theta):
+        def violation(theta, budget):
             m = machine_arrays_from_theta(jnp, theta, fixed)
-            return budget_violation(jnp, m, DEFAULT_COST_MODEL, 1.5, None)
+            return budget_violation(jnp, m, DEFAULT_COST_MODEL, budget, None)
 
         return dict(theta0=backend.asarray(theta0), obj_fn=objective,
-                    lr=backend.asarray(np.linspace(0.05, 0.2, len(mb))),
+                    lr=backend.asarray(np.linspace(0.05, 0.2, len(lo))),
                     retract=project, aux_fn=violation, obj_args=suite,
                     retract_args=(backend.asarray(1.5),))
 
@@ -306,11 +300,9 @@ def test_grad_codesign_matches_the_step_loop():
     objective it descended before the suite became an argument: a closure
     over the suite and the clip box."""
     from repro.core.codesign import (
-        _as_batches,
         _objective_terms,
+        descent_suite,
         machine_arrays_from_theta,
-        resolve_beta,
-        theta_box,
     )
     from repro.core.costmodel import DEFAULT_COST_MODEL
     from repro.core.kernels_xp import IDEAL_EPS, get_backend
@@ -320,13 +312,12 @@ def test_grad_codesign_matches_the_step_loop():
     res = grad_codesign(profiles, seeds, steps=30)
     backend = get_backend("jax")
     jax, jnp = backend._jax, backend._jnp
-    pb, mb = _as_batches(profiles, seeds)
-    theta0, lo, hi = theta_box(mb, 16.0)
+    s = descent_suite(profiles, seeds)
     with backend._x64():
-        p_arrays = backend.profile_arrays(pb.arrays())
-        fixed = backend.machine_arrays(mb.arrays())
-        beta_j = backend.asarray(resolve_beta(pb, mb, None, 0))
-        lo_j, hi_j = backend.asarray(lo), backend.asarray(hi)
+        p_arrays = backend.profile_arrays(s.p)
+        fixed = backend.machine_arrays(s.fixed)
+        beta_j = backend.asarray(s.beta)
+        lo_j, hi_j = backend.asarray(s.lo), backend.asarray(s.hi)
 
         def per_variant(theta):
             m = machine_arrays_from_theta(jnp, theta, fixed)
@@ -334,7 +325,7 @@ def test_grad_codesign_matches_the_step_loop():
                                     IDEAL_EPS, DEFAULT_COST_MODEL, 0.1, 0.05)
 
         _, f_cur, history, _, _ = eager_descent(
-            jax, jnp, backend.asarray(theta0), per_variant, 30, 0.1,
+            jax, jnp, backend.asarray(s.theta0), per_variant, 30, 0.1,
             retract=lambda th: jnp.clip(th, lo_j, hi_j))
     want = np.stack(history)
     np.testing.assert_array_equal(np.diff(res.trajectory[:11], axis=0) < 0,
@@ -344,21 +335,46 @@ def test_grad_codesign_matches_the_step_loop():
                                rtol=1e-12)
 
 
-def test_second_solve_on_a_new_suite_traces_nothing():
-    """The suite is an argument of the compiled descent, not a constant in
-    it: a solve on a new generated suite of the same size reuses the first
-    solve's program and traces nothing."""
+def _solve_in_mode(mode, suite, seeds):
+    """One small solve of co-design ``mode`` on ``suite``, and the
+    trajectory it reports."""
+    from repro.core.constrained import constrained_codesign, joint_codesign
+    from repro.core.frontier import frontier_codesign
+    from repro.core.packing import pack_codesign
+
+    if mode == "grad":
+        return grad_codesign(suite, seeds, steps=8).trajectory
+    if mode in ("projected", "lagrangian"):
+        return constrained_codesign(
+            suite, seeds, area_budget=0.8, power_budget=1.2, mode=mode,
+            steps=8, outer_iters=2).trajectory
+    if mode == "joint":
+        groups = [suite[i:i + 2] for i in range(0, len(suite), 2)]
+        return joint_codesign(groups, seeds, rounds=2, steps=6).trajectory
+    if mode == "frontier":
+        return frontier_codesign(suite, seeds, [0.6, 1.2], steps=4,
+                                 refine_steps=2).per_seed_objective
+    return pack_codesign(suite, seeds, num_machines=2, rounds=2, steps=4,
+                         area_budget=1.5).trajectory
+
+
+@pytest.mark.parametrize("mode", ["grad", "projected", "lagrangian", "joint",
+                                  "frontier", "pack"])
+def test_second_solve_on_a_new_suite_traces_nothing(mode):
+    """The suite is an argument of every mode's compiled programs, not a
+    constant in them: a solve on a new generated suite of the same size
+    reuses the first solve's programs and traces nothing."""
     from repro.core import spans
     from repro.core.model_zoo import resolve_suite
 
     seeds = MachineBatch.from_models(VARIANTS)
     first, second = (list(resolve_suite(f"gen:16:seed={s}:mode=halton"))
                      for s in (5, 6))
-    a = grad_codesign(first, seeds, steps=8)
+    a = _solve_in_mode(mode, first, seeds)
     before = spans.counters()["retrace"]
-    b = grad_codesign(second, seeds, steps=8)
+    b = _solve_in_mode(mode, second, seeds)
     assert spans.counters()["retrace"] == before
-    assert not np.allclose(a.trajectory, b.trajectory)
+    assert not np.allclose(a, b)
 
 
 # --------------------------------------------------------------------------- #

@@ -258,8 +258,9 @@ def test_custom_vjp_budget_gradient_matches_fd():
 
 def test_implicit_graph_size_is_steps_independent():
     """The memory/structure regression: the implicit map's traced graph
-    must be IDENTICAL at steps=10 and steps=200 (one fori_loop body +
-    one ridge solve), while the unrolled baseline's grows linearly."""
+    must be IDENTICAL at steps=10 and steps=200 (one ``lax.scan`` body of
+    the shared descent loop + one ridge solve), while the unrolled
+    baseline's grows linearly."""
     backend = get_backend("jax")
     jax, jnp = backend._jax, backend._jnp
 
@@ -287,6 +288,44 @@ def test_implicit_graph_size_is_steps_independent():
     unr30 = size_of(unrolled_jstar_fn(PROFILES, SEEDS, steps=30))
     assert unr30 > 1.5 * unr10  # grows with steps
     assert unr30 > 2 * imp200   # the graph the implicit VJP avoids
+
+
+def test_jstar_forward_is_the_shared_descent():
+    """The implicit map's forward value is ``backtracking_descent``'s: the
+    same objective, projection, seed, ``lr`` and step count give the same
+    J* per variant to 1e-12 relative."""
+    import functools
+
+    from repro.core.codesign import (
+        _suite_objective,
+        backtracking_descent,
+        descent_suite,
+    )
+    from repro.core.constrained import project_to_budgets
+    from repro.core.costmodel import DEFAULT_COST_MODEL
+    from repro.core.kernels_xp import IDEAL_EPS
+
+    backend = get_backend("jax")
+    jax, jnp = backend._jax, backend._jnp
+    steps, lr, budgets = 40, 0.1, (B_AREA, 0.30)
+    jstar = implicit_jstar_fn(PROFILES, SEEDS, steps=steps, lr=lr)
+    s = descent_suite(PROFILES, SEEDS)
+    objective = functools.partial(
+        _suite_objective, jnp, timing_model="serial", eps=IDEAL_EPS,
+        cost_model=DEFAULT_COST_MODEL, w_area=0.1, w_power=0.05)
+
+    def project(theta):
+        return project_to_budgets(jnp, theta, s.lo, s.hi, s.fixed,
+                                  DEFAULT_COST_MODEL, *budgets,
+                                  method="euclidean")[0]
+
+    with backend._x64():
+        value = np.asarray(jstar(jnp.asarray(budgets, dtype=jnp.float64)))
+        _, f_cur, history, _, _ = backtracking_descent(
+            jax, jnp, s.theta0, objective, steps, lr, retract=project,
+            obj_args=s.args)
+    assert np.any(np.stack(history)[-1] < np.stack(history)[0])
+    np.testing.assert_allclose(value, np.asarray(f_cur), rtol=1e-12, atol=0)
 
 
 # --------------------------------------------------------------------------- #

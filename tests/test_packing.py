@@ -115,12 +115,11 @@ def test_softmax_never_regresses_past_seed():
 
 def test_assignment_is_argmin_of_final_fleet():
     res = small_pack()
-    from repro.core.codesign import _as_batches, resolve_beta
+    from repro.core.codesign import descent_suite
     from repro.core import kernels_xp as K
 
-    pb, _ = _as_batches(resolve_suite("gen:6"), res.machines)
-    beta = resolve_beta(pb, MachineBatch.from_models(VARIANTS), BETA, 0)
-    out = K.congruence_kernel(np, pb.arrays(), res.machines.arrays(), beta,
+    s = descent_suite(resolve_suite("gen:6"), res.machines, beta=BETA)
+    out = K.congruence_kernel(np, s.p, res.machines.arrays(), s.beta,
                               "serial", K.IDEAL_EPS, clamp=False)
     agg = np.asarray(out.aggregate)
     np.testing.assert_array_equal(res.assignment, np.argmin(agg, axis=1))
