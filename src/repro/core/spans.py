@@ -29,9 +29,14 @@ a short span ``repro.jit.<counter>`` on the thread that caused it:
   compile    a backend compile, or a load from the persistent cache
   cache_hit  a load from the persistent compilation cache
 
+The program bumps one counter of its own through ``count``:
+
+  names_built  rows whose name string was formatted (an index-named
+               ``MachineBatch`` formats its names only when they are read)
+
 ``counters()`` returns the counts so far.  The numpy-only paths never
 import jax through this module: before jax is loaded ``span`` is a null
-context and the counters read zero.
+context and the jit counters read zero.
 """
 
 from __future__ import annotations
@@ -53,6 +58,7 @@ EVENTS = {
 
 _lock = threading.Lock()
 _counts: Dict[str, int] = {name: 0 for name in EVENTS.values()}
+_counts["names_built"] = 0
 _installed = False
 
 
@@ -103,9 +109,15 @@ def spanned(name: str):
     return wrap
 
 
+def count(counter: str, n: int) -> None:
+    """Add ``n`` to one of the program's own counters."""
+    with _lock:
+        _counts[counter] += n
+
+
 def counters() -> Dict[str, int]:
     """The retrace, compile and cache-hit counts since the listener was
-    installed (a copy)."""
+    installed, and the program's own counts (a copy)."""
     _loaded_jax()
     with _lock:
         return dict(_counts)

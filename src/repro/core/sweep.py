@@ -37,6 +37,7 @@ configurable ``repro.core.costmodel.CostModel`` (the PPA trade-off of §I).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import itertools
 import json
@@ -54,7 +55,7 @@ from repro.core.machine import (
     Subsystem,
     TPU_V5E,
 )
-from repro.core.spans import span, spanned
+from repro.core.spans import count, span, spanned
 
 # The machine-model constants a sweep may vary, in canonical order.
 SWEEP_PARAMS = (
@@ -116,15 +117,52 @@ class Dim:
 _HALTON_PRIMES = (2, 3, 5, 7, 11, 13, 17, 19, 23, 29, 31, 37)
 
 
-def _radical_inverse(index: np.ndarray, base: int) -> np.ndarray:
-    """Van der Corput radical inverse of ``index`` in ``base`` (vectorized)."""
-    idx = np.asarray(index, dtype=np.int64).copy()
-    inv = np.zeros(idx.shape, dtype=np.float64)
+#: Largest low-digit table ``_radical_table`` builds (entries per prime).
+_RADICAL_TABLE_MAX = 1 << 16
+
+
+@functools.lru_cache(maxsize=None)
+def _radical_table(base: int) -> Tuple[int, np.ndarray, float]:
+    """``(m, T, frac)`` for the table-driven radical inverse in ``base``.
+
+    ``m = base**k`` is the largest power of ``base`` up to
+    ``_RADICAL_TABLE_MAX``; ``T[r]`` is the digit loop's accumulator after
+    the ``k`` low digits of any index ``i`` with ``i % m == r``; ``frac``
+    is the loop's digit weight after those ``k`` divisions.  The loop
+    depends only on the digits it has consumed, so continuing it from
+    ``(T[i % m], frac)`` over ``i // m`` adds the same terms in the same
+    order as the plain loop: the result is bit-identical.
+    """
+    m, k = 1, 0
+    while m * base <= _RADICAL_TABLE_MAX:
+        m, k = m * base, k + 1
+    idx = np.arange(m, dtype=np.int64)
+    table = np.zeros(m, dtype=np.float64)
     frac = 1.0 / base
-    while np.any(idx > 0):
-        inv += frac * (idx % base)
+    for _ in range(k):
+        table += frac * (idx % base)
         idx //= base
         frac /= base
+    table.flags.writeable = False  # shared by every caller through the cache
+    return m, table, frac
+
+
+def _radical_inverse(index: np.ndarray, base: int) -> np.ndarray:
+    """Van der Corput radical inverse of each non-negative ``index`` in
+    ``base`` (vectorized): the low digits from ``_radical_table``, the
+    rest by the per-digit loop."""
+    m, table, frac = _radical_table(base)
+    idx = np.asarray(index, dtype=np.int64)
+    if idx.size and int(idx.max()) <= np.iinfo(np.int32).max:
+        idx = idx.astype(np.int32)
+    high, low = np.divmod(idx, m)
+    inv = table[low]
+    top = int(high.max()) if high.size else 0
+    while top > 0:
+        high, digit = np.divmod(high, base)
+        inv += frac * digit
+        frac /= base
+        top //= base
     return inv
 
 
@@ -138,12 +176,15 @@ def halton_at(indices, d: int, seed: int = 0) -> np.ndarray:
     """
     if d > len(_HALTON_PRIMES):
         raise ValueError(f"halton supports at most {len(_HALTON_PRIMES)} dims")
-    idx = np.asarray(indices, dtype=np.int64)
+    idx = np.asarray(indices, dtype=np.int64) + 1
     shifts = np.random.default_rng(seed).random(d)
     out = np.empty((idx.shape[0], d), dtype=np.float64)
     for j in range(d):
-        out[:, j] = (_radical_inverse(idx + 1, _HALTON_PRIMES[j])
-                     + shifts[j]) % 1.0
+        # ``(r + shift) % 1.0`` for r + shift in [0, 2): the subtraction
+        # is exact there, so this is the same float, without the fmod
+        col = _radical_inverse(idx, _HALTON_PRIMES[j]) + shifts[j]
+        np.subtract(col, 1.0, out=col, where=col >= 1.0)
+        out[:, j] = col
     return out
 
 
@@ -241,7 +282,8 @@ class ParamSpace:
     def _columns_to_batch_at(self, cols: Dict[str, np.ndarray], indices,
                              prefix: str) -> "MachineBatch":
         """Pack generated columns, naming rows by their GLOBAL indices --
-        so a regenerated shard carries the same names as the full batch."""
+        so a regenerated shard carries the same names as the full batch.
+        The names are formatted only if read (``MachineBatch.names``)."""
         idx = np.asarray(indices, dtype=np.int64)
         full = {}
         for name in SWEEP_PARAMS:
@@ -249,8 +291,7 @@ class ParamSpace:
                 full[name] = np.asarray(cols[name], dtype=np.float64)
             else:
                 full[name] = np.full(idx.shape[0], self._nominal_value(name))
-        return MachineBatch(
-            names=[f"{prefix}{i:05d}" for i in idx], **full)
+        return MachineBatch(name_prefix=prefix, name_index=idx, **full)
 
     def grid_axes(self, points: Union[int, Mapping[str, int]] = 3
                   ) -> Dict[str, np.ndarray]:
@@ -317,10 +358,21 @@ class ParamSpace:
 # --------------------------------------------------------------------------- #
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(init=False)
 class MachineBatch:
-    """``V`` machine variants as one float64 array per model constant."""
+    """``V`` machine variants as one float64 array per model constant.
 
+    Rows are named either by an explicit list (``names=``) or, for a
+    generated population, by a prefix and the rows' global indices
+    (``name_prefix=``, ``name_index=``): row ``j`` is then named
+    ``f"{name_prefix}{name_index[j]:05d}"``.  Index-named batches format
+    that list on the first read of ``names`` and keep it, so a shard whose
+    names nobody reads never builds one string; ``slice``, ``take``,
+    ``select`` and ``concat`` pass the prefix and indices on unformatted.
+    """
+
+    # ``names`` is the property below, declared here so that it stays a
+    # dataclass field (``fields``, ``==`` and ``repr`` read it)
     names: List[str]
     peak_flops: np.ndarray
     hbm_bw: np.ndarray
@@ -331,8 +383,43 @@ class MachineBatch:
     scale_memory: np.ndarray
     scale_interconnect: np.ndarray
 
+    def __init__(self, names: Optional[List[str]] = None, *,
+                 name_prefix: str = "",
+                 name_index: Optional[np.ndarray] = None,
+                 **fields: np.ndarray):
+        if (names is None) == (name_index is None):
+            raise TypeError("MachineBatch takes exactly one of names= "
+                            "and name_index=")
+        if set(fields) != set(SWEEP_PARAMS):
+            raise TypeError(f"MachineBatch takes the fields {SWEEP_PARAMS}, "
+                            f"got {sorted(fields)}")
+        self._names = names
+        self.name_prefix = name_prefix
+        self.name_index = name_index
+        for name in SWEEP_PARAMS:
+            setattr(self, name, fields[name])
+
+    @property
+    def names(self) -> List[str]:
+        if self._names is None:
+            self._names = [f"{self.name_prefix}{i:05d}"
+                           for i in self.name_index.tolist()]
+            count("names_built", len(self._names))
+        return self._names
+
+    def _renamed(self, rows, **sel: np.ndarray) -> "MachineBatch":
+        """A sub-batch of ``sel``'s columns, named by ``rows`` of this
+        batch (a slice or an index array) without formatting names that
+        were not formatted yet."""
+        if self._names is None:
+            return MachineBatch(name_prefix=self.name_prefix,
+                                name_index=self.name_index[rows], **sel)
+        if isinstance(rows, slice):
+            return MachineBatch(names=self._names[rows], **sel)
+        return MachineBatch(names=[self._names[i] for i in rows], **sel)
+
     def __len__(self) -> int:
-        return len(self.names)
+        return len(self.peak_flops)
 
     @property
     def ici_bw_total(self) -> np.ndarray:
@@ -362,29 +449,27 @@ class MachineBatch:
 
     @staticmethod
     def concat(*batches: "MachineBatch") -> "MachineBatch":
-        cat = lambda get: np.concatenate([get(b) for b in batches])
-        return MachineBatch(
-            names=[n for b in batches for n in b.names],
-            peak_flops=cat(lambda b: b.peak_flops),
-            hbm_bw=cat(lambda b: b.hbm_bw),
-            ici_bw=cat(lambda b: b.ici_bw),
-            ici_links=cat(lambda b: b.ici_links),
-            inter_pod_bw=cat(lambda b: b.inter_pod_bw),
-            scale_compute=cat(lambda b: b.scale_compute),
-            scale_memory=cat(lambda b: b.scale_memory),
-            scale_interconnect=cat(lambda b: b.scale_interconnect),
-        )
+        sel = {name: np.concatenate([getattr(b, name) for b in batches])
+               for name in SWEEP_PARAMS}
+        if (all(b._names is None for b in batches)
+                and len({b.name_prefix for b in batches}) == 1):
+            return MachineBatch(
+                name_prefix=batches[0].name_prefix,
+                name_index=np.concatenate([b.name_index for b in batches]),
+                **sel)
+        return MachineBatch(names=[n for b in batches for n in b.names],
+                            **sel)
 
     def slice(self, lo: int, hi: int) -> "MachineBatch":
         """Contiguous sub-batch ``[lo, hi)`` (one shard of a sharded sweep)."""
         sel = {name: getattr(self, name)[lo:hi] for name in SWEEP_PARAMS}
-        return MachineBatch(names=self.names[lo:hi], **sel)
+        return self._renamed(slice(lo, hi), **sel)
 
     def take(self, indices) -> "MachineBatch":
         """Arbitrary sub-batch by variant index (Pareto-survivor gathers)."""
         idx = np.asarray(indices, dtype=np.int64)
         sel = {name: getattr(self, name)[idx] for name in SWEEP_PARAMS}
-        return MachineBatch(names=[self.names[i] for i in idx], **sel)
+        return self._renamed(idx, **sel)
 
     def model(self, i: int) -> MachineModel:
         """Materialize variant ``i`` as a scalar ``MachineModel``."""
@@ -427,7 +512,7 @@ class MachineBatch:
     def select(self, i: int) -> "MachineBatch":
         """Single-variant sub-batch (used as the default-beta reference)."""
         sel = {name: getattr(self, name)[i:i + 1] for name in SWEEP_PARAMS}
-        return MachineBatch(names=[self.names[i]], **sel)
+        return self._renamed(slice(i, i + 1), **sel)
 
     def params_row(self, i: int) -> Dict[str, float]:
         return {name: float(getattr(self, name)[i]) for name in SWEEP_PARAMS}

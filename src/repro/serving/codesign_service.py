@@ -297,8 +297,9 @@ class CodesignService:
     @property
     def stats(self) -> collections.Counter:
         """The service's accounting (submissions, memo and population
-        cache hits, batching, job outcomes) plus the process's jit
-        counters ``retrace``, ``compile`` and ``cache_hit``."""
+        cache hits, batching, job outcomes) plus the process's counters
+        from ``spans.counters()`` (``retrace``, ``compile``, ``cache_hit``,
+        ``names_built``)."""
         with self._cond:
             out = collections.Counter(self._counts)
         out.update(spans.counters())

@@ -43,6 +43,20 @@ def test_counters_are_a_copy():
     assert spans.counters()["retrace"] >= 0
 
 
+def test_names_built_counts_only_the_rows_whose_names_are_read():
+    from repro.core.sweep import ParamSpace, PopulationStream
+
+    stream = PopulationStream(ParamSpace.default(), 5000, seed=3)
+    before = spans.counters()["names_built"]
+    shard = stream.batch(1000, 3000)
+    part = shard.slice(0, 700)
+    assert spans.counters()["names_built"] == before
+    assert len(part.names) == 700
+    assert spans.counters()["names_built"] == before + 700
+    assert part.names[0] == "sweep-01000"
+    assert spans.counters()["names_built"] == before + 700
+
+
 def test_span_and_spanned_work_before_jax_is_imported():
     """The numpy-only paths use spans without loading jax."""
     code = textwrap.dedent("""
@@ -56,7 +70,7 @@ def test_span_and_spanned_work_before_jax_is_imported():
         with spans.span("sweep"):
             assert f(1) == 2
         assert spans.counters() == {"retrace": 0, "compile": 0,
-                                    "cache_hit": 0}
+                                    "cache_hit": 0, "names_built": 0}
         assert f.__name__ == "f"
         assert "jax" not in sys.modules
         print("ok")

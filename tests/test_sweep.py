@@ -265,6 +265,40 @@ def test_halton_is_low_discrepancy_and_deterministic():
     assert not np.array_equal(pts, halton(256, 5, seed=1))
 
 
+def _plain_halton(indices, d, seed):
+    """The seeded Halton rows by the plain per-digit radical-inverse loop."""
+    idx = np.asarray(indices, dtype=np.int64) + 1
+    shifts = np.random.default_rng(seed).random(d)
+    out = np.empty((idx.size, d), dtype=np.float64)
+    for j, base in enumerate((2, 3, 5, 7, 11, 13, 17, 19, 23, 29, 31, 37)[:d]):
+        n = idx.copy()
+        inv = np.zeros(n.shape, dtype=np.float64)
+        frac = 1.0 / base
+        while np.any(n > 0):
+            inv += frac * (n % base)
+            n //= base
+            frac /= base
+        out[:, j] = (inv + shifts[j]) % 1.0
+    return out
+
+
+@pytest.mark.parametrize("offset", [0, 65536 * 15, 2**31 - 10,
+                                    3_000_000_000])
+def test_halton_at_is_bit_identical_to_the_digit_loop(offset):
+    """The table-driven radical inverse adds the same terms in the same
+    order as the per-digit loop, for every prime, on both sides of the
+    int32/int64 switch; 70,000 rows cross every prime's table size."""
+    from repro.core.sweep import halton_at
+
+    rng = np.random.default_rng(offset)
+    idx = np.concatenate([offset + np.arange(70_000),
+                          rng.integers(0, offset + 70_000, 500)])
+    got = halton_at(idx, 12, seed=offset + 1)
+    want = _plain_halton(idx, 12, seed=offset + 1)
+    assert got.shape == (idx.size, 12)
+    np.testing.assert_array_equal(got.view(np.int64), want.view(np.int64))
+
+
 def test_param_space_sample_bounds():
     space = ParamSpace.default(span=4.0, max_links=8)
     batch = space.sample(128, seed=2)
@@ -738,6 +772,54 @@ def test_population_stream_matches_materialized(mode):
     _assert_batch_equal(stream.take(idx), full.take(idx))
 
 
+def test_index_named_batch_behaves_like_its_name_list(tmp_path):
+    """A generated batch carries a prefix and global indices instead of
+    names; every sub-batch keeps them unformatted, and the names read
+    back are exactly the eager ``f"sweep-{i:05d}"`` list."""
+    from repro.core import spans
+    from repro.core.sweep import load_population, save_population
+
+    lazy = ParamSpace.default().sample_at(np.arange(100, 160), seed=4)
+    eager = [f"sweep-{i:05d}" for i in range(100, 160)]
+    named = MachineBatch.from_models(VARIANTS)
+    built = spans.counters()["names_built"]
+    rows = [3, 0, 59, 7]
+    parts = {
+        "slice": (lazy.slice(5, 20), eager[5:20]),
+        "take": (lazy.take(rows), [eager[i] for i in rows]),
+        "select": (lazy.select(7), [eager[7]]),
+        "concat": (MachineBatch.concat(lazy.slice(0, 10), lazy.slice(30, 40)),
+                   eager[:10] + eager[30:40]),
+    }
+    mixed = MachineBatch.concat(named, lazy.slice(0, 3))
+    assert mixed.names == [m.name for m in VARIANTS] + eager[:3]
+    assert spans.counters()["names_built"] == built + 3
+    for kind, (batch, want) in parts.items():
+        assert batch.name_index is not None, kind
+        assert len(batch) == len(want), kind
+        assert batch.names == want, kind
+    assert isinstance(lazy.names, list) and lazy.names == eager
+    assert list(iter(lazy.names)) == eager
+    assert lazy.names.index("sweep-00150") == 50
+    assert lazy.model(2).name == "sweep-00102"
+    # a slice of a formatted batch reads the formatted list
+    assert lazy.slice(1, 3).names == eager[1:3]
+    save_population(str(tmp_path / "pop"), lazy.take(rows))
+    back = load_population(str(tmp_path / "pop")).materialize()
+    assert back.names == [eager[i] for i in rows]
+    np.testing.assert_array_equal(back.peak_flops, lazy.take(rows).peak_flops)
+
+
+def test_machine_batch_takes_names_or_an_index():
+    cols = {name: np.ones(2) for name in sweep_module.SWEEP_PARAMS}
+    with pytest.raises(TypeError):
+        MachineBatch(**cols)
+    with pytest.raises(TypeError):
+        MachineBatch(names=["a", "b"], name_index=np.arange(2), **cols)
+    with pytest.raises(TypeError):
+        MachineBatch(names=["a", "b"], peak_flops=np.ones(2))
+
+
 def test_save_load_population_roundtrip(tmp_path):
     from repro.core.sweep import (PopulationStream, _population,
                                   load_population, save_population)
@@ -784,6 +866,28 @@ def test_streamed_shard_sweep_byte_identical(backend):
         single.machines.names[i] for i in single.pareto_front()]
     for app in single.apps:
         assert streamed.best_fit(app) == single.best_fit(app)
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_stream_batch_sweep_matches_materialized(backend):
+    """A population of ``PopulationStream.batch`` rows, names unformatted,
+    swept as a batch gives the fronts and best fits of the streamed and
+    the materialized sweeps."""
+    from repro.core.sweep import PopulationStream, shard_sweep
+
+    profiles = random_profiles(4, seed=11)
+    stream = PopulationStream(ParamSpace.default(), 300, seed=13)
+    population = stream.batch(0, len(stream))
+    assert population.name_index is not None
+    kw = dict(n=300, seed=13, backend=backend, num_shards=3)
+    streamed = shard_sweep(profiles, stream=True, **kw)
+    materialized = shard_sweep(profiles, **kw)
+    from_batch = shard_sweep(profiles, population=population, **kw)
+    for res in (streamed, from_batch):
+        assert res.pareto_names() == materialized.pareto_names()
+        assert res.best_fit_map == materialized.best_fit_map
+        for app in materialized.apps:
+            assert res.best_fit(app) == materialized.best_fit(app)
 
 
 def test_mmap_population_sweep_matches_generated(tmp_path):
